@@ -30,6 +30,7 @@ def main():
     p.add_argument('--vocab', type=int, default=30522)
     p.add_argument('--bf16', action='store_true')
     args = p.parse_args()
+    mx.telemetry.compile.use_default_cache()
 
     cfg = dict(vocab_size=args.vocab, hidden=args.hidden,
                layers=args.layers, heads=args.heads,

@@ -31,6 +31,7 @@ def main():
     import jax
     import jax.numpy as jnp
     import mxnet_tpu as mx
+    mx.telemetry.compile.use_default_cache()
     from mxnet_tpu.models import BertForPretraining
     from mxnet_tpu.models.bert import bert_pipeline_funcs
     from mxnet_tpu.parallel import PipelineTrainStep, make_mesh

@@ -27,6 +27,7 @@ def main():
     p.add_argument('--steps', type=int, default=20)
     p.add_argument('--vocab', type=int, default=50257)
     args = p.parse_args()
+    mx.telemetry.compile.use_default_cache()
 
     mx.random.seed(0)
     model = GPTModel(vocab_size=args.vocab, hidden=args.hidden,

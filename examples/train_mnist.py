@@ -29,6 +29,7 @@ def main():
     p.add_argument('--batch-size', type=int, default=128)
     p.add_argument('--lr', type=float, default=1e-3)
     args = p.parse_args()
+    mx.telemetry.compile.use_default_cache()
 
     net = nn.HybridSequential()
     net.add(nn.Dense(256, activation='relu'),

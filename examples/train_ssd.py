@@ -39,6 +39,7 @@ def main():
                    help='input resolution (512 = the reference config)')
     p.add_argument('--lr', type=float, default=1e-3)
     args = p.parse_args()
+    mx.telemetry.compile.use_default_cache()
 
     num_classes = 3
     net = SSD(num_classes=num_classes, image_size=args.size,

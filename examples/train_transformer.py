@@ -39,6 +39,7 @@ def main():
     p.add_argument('--big', action='store_true',
                    help='transformer-big dims (1024/16/4096, 6+6 layers)')
     args = p.parse_args()
+    mx.telemetry.compile.use_default_cache()
 
     if args.big:
         cfg = dict(hidden=1024, enc_layers=6, dec_layers=6, heads=16,

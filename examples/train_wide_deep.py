@@ -73,6 +73,7 @@ def main():
     p.add_argument('--tp', type=int, default=1,
                    help='model-axis extent for MXTPU_SPARSE_TABLE_AXIS')
     args = p.parse_args()
+    mx.telemetry.compile.use_default_cache()
 
     mx.random.seed(0)
     model = WideDeep(args.vocab, args.dim)

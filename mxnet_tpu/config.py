@@ -482,7 +482,8 @@ register('MXTPU_COMPILE_CACHE_DIR', str, '',
          'min-compile-time gates dropped to zero so every program is '
          'eligible). Warm processes reuse cold-process binaries: '
          'hit/miss/saved-seconds land in mxnet_tpu_compile_persistent_'
-         'cache_* counters and the compile ledger. Empty (default): '
+         'cache_* counters and the compile ledger. Loses to '
+         'JAX_COMPILATION_CACHE_DIR when that is set. Empty (default): '
          "jax's own defaults (cache off unless configured elsewhere).")
 
 # -- inference serving (mxnet_tpu.serving) ---------------------------------

@@ -41,7 +41,10 @@ class Context:
             # TPU machine mx.gpu(0) runs on TPU so reference scripts work.
             devs = _accelerator_devices()
             if not devs:
-                devs = jax.devices()
+                raise MXNetError(
+                    f"{self}: no accelerator — jax reports only "
+                    f"{jax.default_backend()!r} devices; use mx.cpu() to "
+                    f"run there")
         if self.device_id >= len(devs):
             raise MXNetError(
                 f"{self}: device_id {self.device_id} out of range ({len(devs)} available)")

@@ -104,12 +104,11 @@ class NDArray:
     def context(self) -> Context:
         if self._ctx is not None:
             return self._ctx
-        try:
-            dev = self._data.devices().pop() if hasattr(self._data, 'devices') else None
-        except Exception:
-            dev = None
-        if dev is None:
-            return Context('cpu', 0)
+        if isinstance(self._data, jax.core.Tracer):
+            # mid-trace there is no buffer to ask: the array will live
+            # wherever the program runs, i.e. the context in force
+            return Context.default_ctx()
+        dev = min(self._data.devices(), key=lambda d: d.id)
         if dev.platform != 'cpu':
             accel = [d for d in jax.devices() if d.platform != 'cpu']
             idx = accel.index(dev) if dev in accel else 0

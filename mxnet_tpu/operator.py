@@ -13,8 +13,7 @@ Inside a hybridized/jitted trace a Python custom op cannot run natively on
 the TPU; it is bridged with ``jax.pure_callback`` + ``jax.custom_vjp`` so the
 traced program calls back into Python — the TPU analog of the reference's
 custom-op worker thread crossing the engine boundary. Note: this requires a
-runtime with host-callback support (CPU and standard TPU PjRt have it; some
-tunneled backends do not — use eager mode there).
+runtime with host-callback support (CPU and standard TPU PjRt have it).
 """
 from __future__ import annotations
 

@@ -3,8 +3,9 @@
 The reference exposes interleaved-matmul ops over a packed (T, N, 3*H*D)
 projection tensor. We keep that API for parity, plus a fused
 `multi_head_attention` that is the TPU-preferred entry: one call that can be
-swapped between the XLA path and a Pallas flash-attention kernel
-(mxnet_tpu.ops.pallas_attention) by size heuristic.
+routed to the XLA path or the Pallas flash-attention kernel
+(mxnet_tpu.ops.pallas_attention) from the platform, the mask kind and the
+kernel's static legality check.
 """
 from __future__ import annotations
 
@@ -13,7 +14,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..base import register_op, state as _flags
+from ..base import MXNetError, register_op, state as _flags
 from .. import random as _random
 
 __all__ = []
@@ -105,12 +106,10 @@ def _as_key_padding_mask(mask, N, Tk):
     return None
 
 
-_pallas_fallback_warned = [False]
-
-# trace-time routing telemetry: [pallas_hits, xla_hits]. Incremented when
-# multi_head_attention picks a path (once per trace, not per step — jit
-# caches the traced program). Lets benches/tests assert the flagship
-# config really routes through the flash kernel.
+# trace-time routing telemetry. Incremented when multi_head_attention
+# picks a path (once per trace, not per step — jit caches the traced
+# program). Lets benches/tests assert the flagship config really routes
+# through the flash kernel.
 route_counts = {'pallas': 0, 'xla': 0, 'ring': 0}
 
 # active sequence-parallel config: (mesh, axis) or None
@@ -140,6 +139,84 @@ class sequence_parallel:
         _seq_parallel.pop()
 
 
+# active mesh placement of the batch: (mesh, batch_axes, head_axes)
+_mesh_placement = []
+
+
+class mesh_placement:
+    """Context manager telling ``multi_head_attention`` how the arrays it
+    is traced on are laid out over ``mesh``: batch dim sharded over
+    ``batch_axes``, heads over ``head_axes`` (either may be empty).
+    ``ShardedTrainStep`` enters it while tracing its step.
+
+    The partitioner cannot split the flash kernel — an opaque custom
+    call — and jax 0.9 refuses to lower one whose operands are sharded
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map"). Inside this context the Pallas route
+    does that: it maps the kernel over those axes with ``shard_map``,
+    so each chip runs it on its own (B/dp)·(H/tp) slices, with no
+    collective."""
+
+    def __init__(self, mesh, batch_axes=(), head_axes=()):
+        self._cfg = (mesh, tuple(batch_axes), tuple(head_axes))
+
+    def __enter__(self):
+        _mesh_placement.append(self._cfg)
+        return self
+
+    def __exit__(self, *exc):
+        _mesh_placement.pop()
+
+
+def _axes_size(mesh, axes):
+    return math.prod(mesh.shape[a] for a in axes)
+
+
+def _flash_route(q, k, v, kpm, causal, dropout_p, seed):
+    """The Pallas route: the flash kernel, mapped over the mesh when a
+    ``mesh_placement`` is active."""
+    from jax import lax, shard_map
+    from jax.sharding import PartitionSpec as P
+    from .pallas_attention import flash_attention
+    mesh, batch_axes, head_axes = \
+        _mesh_placement[-1] if _mesh_placement else (None, (), ())
+    N, H = q.shape[0], q.shape[1]
+    n_b = _axes_size(mesh, batch_axes)
+    if N % n_b:
+        raise MXNetError(
+            f"multi_head_attention: batch {N} does not divide over mesh "
+            f"axes {batch_axes} (size {n_b}), so the flash kernel cannot "
+            f"be mapped per chip")
+    if H % _axes_size(mesh, head_axes):
+        head_axes = ()      # heads stay whole on every chip of those axes
+    n_h = _axes_size(mesh, head_axes)
+    if n_b * n_h == 1:
+        return flash_attention(q, k, v, key_mask=kpm, causal=causal,
+                               dropout_p=dropout_p, dropout_seed=seed)
+    n_loc, h_loc = N // n_b, H // n_h
+
+    def local(q_, k_, v_, kpm_, seed_):
+        # global id of this shard's first (batch, head) slice, so the
+        # in-kernel dropout draws the bits the unsharded call would
+        base = jnp.zeros((), jnp.uint32)
+        if batch_axes:
+            base += lax.axis_index(batch_axes).astype(jnp.uint32) \
+                * jnp.uint32(n_loc * H)
+        if head_axes:
+            base += lax.axis_index(head_axes).astype(jnp.uint32) \
+                * jnp.uint32(h_loc)
+        return flash_attention(q_, k_, v_, key_mask=kpm_, causal=causal,
+                               dropout_p=dropout_p, dropout_seed=seed_,
+                               bh_base=base,
+                               bh_split=(h_loc, H) if n_h > 1 else None)
+
+    b_spec = batch_axes or None
+    qkv = P(b_spec, head_axes or None, None, None)
+    return shard_map(local, mesh=mesh,
+                     in_specs=(qkv, qkv, qkv, P(b_spec, None), P()),
+                     out_specs=qkv, check_vma=False)(q, k, v, kpm, seed)
+
+
 @_reg
 def multi_head_attention(query, key, value, mask=None, num_heads=1,
                          dropout_p=0.0, causal=False, use_pallas='auto',
@@ -150,12 +227,14 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
     masks are keep/drop (truthy = keep); floating masks are ADDITIVE
     (0.0 = keep, large-negative = drop), added to the pre-softmax scores.
 
-    use_pallas: 'auto' routes through the Pallas flash kernel whenever an
-    accelerator backend is active and the mask (if any) is a key-padding
-    mask — this covers the flagship BERT@512-with-padding-mask config.
-    Arbitrary (per-query) masks fall back to the XLA path. Under 'auto' a
-    Pallas trace failure degrades to the XLA path with a one-time warning;
-    use_pallas=True raises.
+    use_pallas: 'auto' takes the Pallas flash kernel when the backend is
+    a TPU, the mask (if any) is a key-padding mask and the shape passes
+    the kernel's static legality check (pallas_attention.flash_legal) —
+    this covers the flagship BERT@512-with-padding-mask config — and the
+    XLA path otherwise (per-query masks, CPU, illegal shapes). The choice
+    is made from those three observations only and counted in
+    ``route_counts``; a kernel that then fails to build or compile
+    raises. True forces the kernel, False forces XLA.
 
     dropout_p: attention-probability dropout, applied after softmax (the
     standard transformer recipe), active in autograd training mode (same
@@ -217,34 +296,26 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
             f"({reason}); the T x T score tensor will be materialized.",
             RuntimeWarning)
 
-    if use_pallas in ('auto', True):
-        from .pallas_attention import flash_attention, pallas_available
-        if (use_pallas is True or pallas_available()) and \
-                (mask is None or kpm is not None):
-            try:
-                if apply_dropout:
-                    key_ = dropout_key if dropout_key is not None \
-                        else _random.next_key()
-                    seed = jax.random.bits(key_, (1, 1), jnp.uint32)
-                    out = flash_attention(q, k, v, key_mask=kpm,
-                                          causal=causal,
-                                          dropout_p=dropout_p,
-                                          dropout_seed=seed)
-                else:
-                    out = flash_attention(q, k, v, key_mask=kpm,
-                                          causal=causal)
-                route_counts['pallas'] += 1
-                return out.transpose(0, 2, 1, 3).reshape(N, Tq, tot)
-            except Exception:
-                if use_pallas is True:
-                    raise
-                if not _pallas_fallback_warned[0]:
-                    _pallas_fallback_warned[0] = True
-                    import warnings
-                    warnings.warn(
-                        "Pallas flash attention failed to trace; falling "
-                        "back to the XLA attention path for this process.",
-                        RuntimeWarning)
+    if use_pallas == 'auto':
+        from .pallas_attention import flash_legal, pallas_available
+        use_pallas = pallas_available() \
+            and (mask is None or kpm is not None) \
+            and flash_legal(N * H, Tq, k.shape[2], D, q.dtype)
+    if use_pallas:
+        if mask is not None and kpm is None:
+            raise MXNetError(
+                "multi_head_attention(use_pallas=True): the flash kernel "
+                "takes key-padding masks only, got a per-query mask of "
+                f"shape {tuple(mask.shape)}")
+        seed = None
+        if apply_dropout:
+            key_ = dropout_key if dropout_key is not None \
+                else _random.next_key()
+            seed = jax.random.bits(key_, (1, 1), jnp.uint32)
+        out = _flash_route(q, k, v, kpm, causal,
+                           dropout_p if apply_dropout else 0.0, seed)
+        route_counts['pallas'] += 1
+        return out.transpose(0, 2, 1, 3).reshape(N, Tq, tot)
 
     route_counts['xla'] += 1
     scale = 1.0 / math.sqrt(D)

@@ -69,8 +69,11 @@ KERNEL_FA = 'flash_attention'
 DB_BASENAME = 'mxtpu_autotune.json'
 DB_VERSION = 1
 
-# Mosaic scoped-VMEM stack limit is 16 MB; _block_sizes has always
-# budgeted 14 MB to leave headroom for the compiler's own spills.
+# What vmem_bytes() may estimate for one kernel instance before a
+# candidate is pruned. An estimate, not the compiler's accounting: the
+# default blocks (estimated at 11.0 MiB forward, 5.5 MiB backward)
+# compile on a v5e under libtpu 0.0.34's default scoped-VMEM limit
+# (chip run, PR 23); where the real limit sits above that: not measured.
 VMEM_BUDGET = 14 * 2 ** 20
 
 _LANE = 128
@@ -256,10 +259,8 @@ def shape_sig(BH, Tq, Tk, D, dtype, kind):
 
 
 def device_kind():
-    try:
-        return jax.devices()[0].device_kind.replace(' ', '_')
-    except Exception:
-        return 'unknown'
+    """The tuning DB's device key, as jax reports it."""
+    return jax.devices()[0].device_kind.replace(' ', '_')
 
 
 def db_path(dir_=None):

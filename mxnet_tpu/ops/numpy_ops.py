@@ -931,14 +931,7 @@ def _npi_multinomial(n=1, pvals=None, size=None):
     pv = jnp.asarray(pvals)
     shp = () if size is None else tuple(size)
     pb = jnp.broadcast_to(pv, shp + pv.shape)
-    if hasattr(jax.random, 'multinomial'):
-        counts = jax.random.multinomial(key, float(n), pb)
-    else:
-        # jax < 0.4.31: n categorical draws histogrammed per batch row
-        draws = jax.random.categorical(key, jnp.log(pb),
-                                       shape=(int(n),) + pb.shape[:-1])
-        counts = jax.nn.one_hot(draws, pb.shape[-1],
-                                dtype=jnp.int32).sum(axis=0)
+    counts = jax.random.multinomial(key, float(n), pb)
     return counts.astype(jnp.int64)
 
 

@@ -30,11 +30,14 @@ softmax→dropout→matmul recipe.
 Mosaic layout constraints honoured throughout: every block's trailing two
 dims are (multiple-of-8, multiple-of-128) or equal to the array dims —
 the key-mask rides as (BH, 1, Tk) with (G, 1, bk) blocks and the LSE as
-(BH, Tq, 1) with (G, bq, 1) blocks (round 3's compile failure was a
-(1, bk) 2-D mask block).
+(BH, Tq, 1) with (G, bq, 1) blocks (a (1, bk) 2-D mask block is refused).
+The two scalars the kernels read (dropout seed, global batch·head base)
+ride in SMEM.
 
-`flash_attention(..., interpret=True)` runs the identical kernels through
-the Pallas interpreter so CPU tests exercise the real kernel code.
+Kernel mode is explicit: ``interpret=True`` runs the identical kernels
+through the Pallas interpreter (CPU tests exercise the real kernel code),
+``interpret=False`` compiles them with Mosaic, and ``None`` asks
+:func:`default_interpret`, which decides from the platform alone.
 """
 from __future__ import annotations
 
@@ -46,34 +49,27 @@ import jax.numpy as jnp
 import numpy as onp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
+# every grid here is (batch·head groups, outer seq blocks, inner seq
+# blocks) with the scratch accumulators carried over the innermost axis
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=('parallel', 'parallel', 'arbitrary'))
+
 
 def pallas_available() -> bool:
-    if not _HAS_PLTPU:
-        return False
-    try:
-        return any(d.platform == 'tpu' for d in jax.devices())
-    except Exception:
-        return False
+    """Mosaic compiles these kernels for one platform: the TPU."""
+    return jax.default_backend() == 'tpu'
 
 
-def _compiler_params():
-    if pltpu is None:
-        return {}
-    try:
-        return {'compiler_params': pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary'))}
-    except Exception:  # pragma: no cover - older pallas API
-        return {}
+def default_interpret() -> bool:
+    """Kernel mode when the caller names none. The CPU backend has no
+    Mosaic, so Pallas kernels run through the interpreter there; on any
+    other platform they compile. Decided from the platform and nothing
+    else — a failed query raises, it does not pick a mode."""
+    return jax.default_backend() == 'cpu'
 
 
 def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd'):
@@ -83,9 +79,13 @@ def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd'):
 
     kind='bwd' sizes the backward kernels, whose per-cell stack holds
     ~6 live (bq, bk) f32 temporaries (s, p, dp, ds, keep, pv) vs the
-    forward's ~3 — at (512, 512) blocks that alone is 6MB and the dk/dv
-    kernel blows Mosaic's 16MB scoped-VMEM stack limit, so backward
-    defaults to 256-wide blocks.
+    forward's ~3, so backward defaults to 256-wide blocks where the
+    forward takes 512. Established on a v5e with libtpu 0.0.34 (chip
+    run, PR 23): these defaults — forward (4, 512, 512), backward
+    (4, 256, 256) — compile under Mosaic's default scoped-VMEM limit,
+    with no ``vmem_limit_bytes``, at BERT-base (BH=384, T=512, D=64,
+    bf16, padding mask, dropout) and at GPT-2's causal T=1024 (BH=96).
+    Wider backward blocks on this installation: not measured.
 
     The defaults computed here are only the LAST rung of the ISSUE 18
     precedence ladder, applied by ops/autotune.resolve: explicit env
@@ -112,6 +112,22 @@ def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd'):
 # portable counter-based dropout bits
 # ---------------------------------------------------------------------------
 
+def _global_bh(meta_ref, local_bh, bh_split):
+    """Global batch·head id (uint32) of this call's slice ``local_bh``.
+
+    A call that holds the whole (B, H) problem numbers its slices
+    0..BH-1 and ``meta_ref[0, 1]`` is 0. A call mapped over a mesh
+    (ops/attention.py) holds one shard: ``meta_ref[0, 1]`` is the global
+    id of its first slice, and ``bh_split=(H_local, H)`` re-strides the
+    local (batch, head) numbering into the global one when the heads are
+    sharded too — so a sharded run draws the same dropout bits as the
+    unsharded one."""
+    if bh_split is not None:
+        h_loc, h_all = bh_split
+        local_bh = lax.div(local_bh, h_loc) * h_all + lax.rem(local_bh, h_loc)
+    return meta_ref[0, 1] + local_bh.astype(jnp.uint32)
+
+
 def _dropout_keep(seed, bh, q_base, k_base, bq, bk, rate):
     """(bq, bk) float32 keep/(1-rate) multiplier for one attention block.
 
@@ -127,7 +143,7 @@ def _dropout_keep(seed, bh, q_base, k_base, bq, bk, rate):
     rows every 2^32/stride queries)."""
     rows = q_base + lax.broadcasted_iota(jnp.uint32, (bq, bk), 0)
     cols = k_base + lax.broadcasted_iota(jnp.uint32, (bq, bk), 1)
-    return _counter_keep(seed, bh.astype(jnp.uint32), rows, cols, rate)
+    return _counter_keep(seed, bh, rows, cols, rate)
 
 
 def _counter_keep(seed, bh, rows, cols, rate):
@@ -168,12 +184,13 @@ def _masked_scores(q, k, kmask_row, qb, kb, bq, bk, scale, causal, k_len):
 # forward kernel
 # ---------------------------------------------------------------------------
 
-def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, seed_ref,
+def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
                    o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                   scale, causal, G, bq, bk, k_len, dropout_p):
+                   scale, causal, G, bq, bk, k_len, dropout_p, bh_split):
     """One (head-group, q-block, k-block) cell. Refs are VMEM blocks:
     q (G, bq, D), k/v (G, bk, D), kmask (G, 1, bk) additive f32,
-    seed (1, 1) uint32, o (G, bq, D), lse (G, bq, 1);
+    o (G, bq, D), lse (G, bq, 1); meta (1, 2) uint32 in SMEM
+    [dropout seed, global batch·head base];
     scratch acc (G, bq, D) f32, m/l (G, bq, 128) f32."""
     qb = pl.program_id(1)
     kb = pl.program_id(2)
@@ -196,8 +213,8 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, seed_ref,
         alpha = jnp.exp(m_prev - m_new)                  # (bq, 1)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         if dropout_p > 0.0:
-            bh = pl.program_id(0) * G + g
-            keep = _dropout_keep(seed_ref[0, 0], jnp.uint32(bh),
+            bh = _global_bh(meta_ref, pl.program_id(0) * G + g, bh_split)
+            keep = _dropout_keep(meta_ref[0, 0], bh,
                                  jnp.uint32(qb * bq), jnp.uint32(kb * bk),
                                  bq, bk, dropout_p)
             pv = p * keep
@@ -218,9 +235,11 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, seed_ref,
             lse_ref[g] = m_ref[g, :, :1] + jnp.log(safe_l)
 
 
-def _fa_forward(q, k, v, kmask, seed, causal, dropout_p, interpret):
+def _fa_forward(q, k, v, kmask, meta, causal, dropout_p, interpret,
+                bh_split):
     """q/k/v: (BH, T, D) flattened over batch*heads.
-    kmask: (BH, Tk) additive f32 or None. seed: (1, 1) uint32.
+    kmask: (BH, Tk) additive f32 or None. meta: (1, 2) uint32
+    [dropout seed, global batch·head base].
     Returns (out, lse), both sliced back to (BH, Tq[, D]) — the backward
     re-pads them for its own (possibly different) tiling."""
     BH, Tq, D = q.shape
@@ -244,7 +263,7 @@ def _fa_forward(q, k, v, kmask, seed, causal, dropout_p, interpret):
 
     kernel = functools.partial(
         _fa_fwd_kernel, scale=scale, causal=causal, G=G, bq=bq, bk=bk,
-        k_len=Tk, dropout_p=float(dropout_p))
+        k_len=Tk, dropout_p=float(dropout_p), bh_split=bh_split)
     out, lse = pl.pallas_call(
         kernel,
         grid=(BH // G, nq, nk),
@@ -253,7 +272,7 @@ def _fa_forward(q, k, v, kmask, seed, causal, dropout_p, interpret):
             pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((G, 1, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((1, 1), lambda b, i, j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
@@ -267,8 +286,8 @@ def _fa_forward(q, k, v, kmask, seed, causal, dropout_p, interpret):
                         pltpu.VMEM((G, bq, 128), jnp.float32),
                         pltpu.VMEM((G, bq, 128), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(),
-    )(q, k, v, km3, seed)
+        compiler_params=_COMPILER_PARAMS,
+    )(q, k, v, km3, meta)
     lse = lse[..., 0]
     if pq:
         out = out[:, :Tq]
@@ -280,9 +299,9 @@ def _fa_forward(q, k, v, kmask, seed, causal, dropout_p, interpret):
 # backward kernels
 # ---------------------------------------------------------------------------
 
-def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, seed_ref, do_ref,
+def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                   lse_ref, delta_ref, dq_ref, dq_acc, *,
-                  scale, causal, G, bq, bk, k_len, dropout_p):
+                  scale, causal, G, bq, bk, k_len, dropout_p, bh_split):
     """dq for one q-block, accumulated over k-blocks (grid (BH/G, nq, nk))."""
     qb = pl.program_id(1)
     kb = pl.program_id(2)
@@ -301,8 +320,8 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, seed_ref, do_ref,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)       # (bq, bk)
         if dropout_p > 0.0:
-            bh = pl.program_id(0) * G + g
-            keep = _dropout_keep(seed_ref[0, 0], jnp.uint32(bh),
+            bh = _global_bh(meta_ref, pl.program_id(0) * G + g, bh_split)
+            keep = _dropout_keep(meta_ref[0, 0], bh,
                                  jnp.uint32(qb * bq), jnp.uint32(kb * bk),
                                  bq, bk, dropout_p)
             dp = dp * keep
@@ -316,9 +335,9 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, seed_ref, do_ref,
         dq_ref[:] = dq_acc[:]
 
 
-def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, seed_ref, do_ref,
+def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                   scale, causal, G, bq, bk, k_len, dropout_p):
+                   scale, causal, G, bq, bk, k_len, dropout_p, bh_split):
     """dk/dv for one k-block, accumulated over q-blocks
     (grid (BH/G, nk, nq): k-block is program 1, q-block is program 2)."""
     kb = pl.program_id(1)
@@ -336,8 +355,8 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, seed_ref, do_ref,
         p = jnp.exp(s - lse_ref[g])                   # (bq, bk)
         do32 = do_ref[g].astype(jnp.float32)          # (bq, D)
         if dropout_p > 0.0:
-            bh = pl.program_id(0) * G + g
-            keep = _dropout_keep(seed_ref[0, 0], jnp.uint32(bh),
+            bh = _global_bh(meta_ref, pl.program_id(0) * G + g, bh_split)
+            keep = _dropout_keep(meta_ref[0, 0], bh,
                                  jnp.uint32(qb * bq), jnp.uint32(kb * bk),
                                  bq, bk, dropout_p)
             pv = p * keep
@@ -364,8 +383,8 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, seed_ref, do_ref,
         dv_ref[:] = dv_acc[:]
 
 
-def _fa_backward(q, k, v, kmask, seed, causal, dropout_p, interpret,
-                 out, lse, do):
+def _fa_backward(q, k, v, kmask, meta, causal, dropout_p, interpret,
+                 bh_split, out, lse, do):
     """Pallas backward: recompute probability blocks from the saved LSE.
     Returns (dq, dk, dv) in the input dtypes."""
     BH, Tq, D = q.shape
@@ -398,12 +417,12 @@ def _fa_backward(q, k, v, kmask, seed, causal, dropout_p, interpret,
     lse3 = lse.reshape(BH, nq * bq, 1)
 
     kw = dict(scale=scale, causal=causal, G=G, bq=bq, bk=bk, k_len=Tk,
-              dropout_p=float(dropout_p))
+              dropout_p=float(dropout_p), bh_split=bh_split)
     qspec_i = pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0))
     kspec_j = pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0))
     col1_i = pl.BlockSpec((G, bq, 1), lambda b, i, j: (b, i, 0))
     mspec_j = pl.BlockSpec((G, 1, bk), lambda b, i, j: (b, 0, j))
-    sspec = pl.BlockSpec((1, 1), lambda b, i, j: (0, 0))
+    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     dq = pl.pallas_call(
         functools.partial(_fa_dq_kernel, **kw),
@@ -414,8 +433,8 @@ def _fa_backward(q, k, v, kmask, seed, causal, dropout_p, interpret,
         out_shape=jax.ShapeDtypeStruct((BH, nq * bq, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((G, bq, D), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(),
-    )(q, k, v, km3, seed, do, lse3, delta)
+        compiler_params=_COMPILER_PARAMS,
+    )(q, k, v, km3, meta, do, lse3, delta)
 
     # dk/dv grid permutes (q-block, k-block): q innermost
     qspec_2 = pl.BlockSpec((G, bq, D), lambda b, j, i: (b, i, 0))
@@ -434,8 +453,8 @@ def _fa_backward(q, k, v, kmask, seed, causal, dropout_p, interpret,
         scratch_shapes=[pltpu.VMEM((G, bk, D), jnp.float32),
                         pltpu.VMEM((G, bk, D), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(),
-    )(q, k, v, km3, seed, do, lse3, delta)
+        compiler_params=_COMPILER_PARAMS,
+    )(q, k, v, km3, meta, do, lse3, delta)
 
     dq = dq[:, :Tq].astype(q.dtype)
     dk = dk[:, :Tk].astype(k.dtype)
@@ -447,46 +466,63 @@ def _fa_backward(q, k, v, kmask, seed, causal, dropout_p, interpret,
 # custom-vjp wrapper
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash(q, k, v, kmask, seed, causal, dropout_p, interpret):
-    out, _ = _fa_forward(q, k, v, kmask, seed, causal, dropout_p, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, kmask, meta, causal, dropout_p, interpret, bh_split):
+    out, _ = _fa_forward(q, k, v, kmask, meta, causal, dropout_p, interpret,
+                         bh_split)
     return out
 
 
-def _flash_fwd(q, k, v, kmask, seed, causal, dropout_p, interpret):
-    out, lse = _fa_forward(q, k, v, kmask, seed, causal, dropout_p,
-                           interpret)
-    return out, (q, k, v, kmask, seed, out, lse)
+def _flash_fwd(q, k, v, kmask, meta, causal, dropout_p, interpret, bh_split):
+    out, lse = _fa_forward(q, k, v, kmask, meta, causal, dropout_p,
+                           interpret, bh_split)
+    return out, (q, k, v, kmask, meta, out, lse)
 
 
-def _flash_bwd(causal, dropout_p, interpret, res, do):
-    q, k, v, kmask, seed, out, lse = res
-    dq, dk, dv = _fa_backward(q, k, v, kmask, seed, causal, dropout_p,
-                              interpret, out, lse, do)
+def _flash_bwd(causal, dropout_p, interpret, bh_split, res, do):
+    q, k, v, kmask, meta, out, lse = res
+    dq, dk, dv = _fa_backward(q, k, v, kmask, meta, causal, dropout_p,
+                              interpret, bh_split, out, lse, do)
     dmask = None if kmask is None else jnp.zeros_like(kmask)
-    dseed = onp.zeros((1, 1), jax.dtypes.float0)
-    return dq, dk, dv, dmask, dseed
+    dmeta = onp.zeros(meta.shape, jax.dtypes.float0)
+    return dq, dk, dv, dmask, dmeta
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def flash_legal(BH, Tq, Tk, D, dtype) -> bool:
+    """Can the forward AND backward kernels be built for this shape at
+    the block sizes they would use? The static Mosaic rules of
+    ops/autotune.check_candidate, applied to what :func:`_block_sizes`
+    resolves — the verdict ``multi_head_attention`` routes on."""
+    from . import autotune
+    return all(
+        autotune.check_candidate(
+            BH, Tq, Tk, D, jnp.dtype(dtype), kind,
+            *_block_sizes(BH, Tq, Tk, D, dtype, kind))[0]
+        for kind in ('fwd', 'bwd'))
+
+
 def flash_attention(q, k, v, key_mask=None, causal=False, dropout_p=0.0,
-                    dropout_seed=None, block_k=None, interpret=False):
+                    dropout_seed=None, interpret=None, bh_base=None,
+                    bh_split=None):
     """Flash attention. q/k/v: (B, H, T, D). key_mask: optional (B, Tk)
     additive f32 mask (0 = keep, large-negative = drop) or boolean
     (True = keep). dropout_p: in-kernel attention-probability dropout;
     dropout_seed: uint32 scalar/array seeding the kernel PRNG (required
     when dropout_p > 0). Returns (B, H, Tq, D).
 
-    On TPU this is a Pallas kernel (VMEM online softmax, Pallas backward);
-    on CPU backends the same kernels run through the Pallas interpreter
-    (tests exercise the real kernel code)."""
-    if not interpret:
-        try:
-            interpret = jax.default_backend() == 'cpu'
-        except Exception:
-            interpret = True
+    interpret: True runs the kernels through the Pallas interpreter,
+    False compiles them with Mosaic (and fails where there is no TPU),
+    None takes :func:`default_interpret`.
+
+    bh_base / bh_split: for a call that holds one shard of a larger
+    (batch, heads) problem — the global batch·head id of its first
+    slice (uint32 scalar, may be traced) and the static
+    (H_local, H_global) pair — see :func:`_global_bh`."""
+    if interpret is None:
+        interpret = default_interpret()
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     qf = q.reshape(B * H, Tq, D)
@@ -509,9 +545,13 @@ def flash_attention(q, k, v, key_mask=None, causal=False, dropout_p=0.0,
     dropout_p = float(dropout_p)
     if dropout_p > 0.0 and dropout_seed is None:
         raise ValueError("dropout_p > 0 requires dropout_seed")
-    if dropout_seed is None:
-        seed = jnp.zeros((1, 1), jnp.uint32)
-    else:
-        seed = jnp.asarray(dropout_seed, jnp.uint32).reshape(1, 1)
-    out = _flash(qf, kf, vf, km, seed, causal, dropout_p, interpret)
+    seed = jnp.zeros((), jnp.uint32) if dropout_seed is None \
+        else jnp.asarray(dropout_seed, jnp.uint32).reshape(())
+    base = jnp.zeros((), jnp.uint32) if bh_base is None \
+        else jnp.asarray(bh_base, jnp.uint32).reshape(())
+    meta = jnp.stack([seed, base]).reshape(1, 2)
+    if bh_split is not None:
+        bh_split = (int(bh_split[0]), int(bh_split[1]))
+    out = _flash(qf, kf, vf, km, meta, causal, dropout_p, bool(interpret),
+                 bh_split)
     return out.reshape(B, H, Tq, D)

@@ -13,8 +13,11 @@ hand-fused transformer ops in src/operator/contrib/transformer.cc).
 Grid (M/bm, N/bn); K (the contraction dim — BERT hidden 768) rides
 whole in each block's lane dim, so every block is trailing-tile legal
 by the block==array-dim rule and no cross-step accumulator is needed.
-fp32 accumulation via preferred_element_type, exact (erf) GELU to match
-ops/nn.py activation(act_type='gelu') bit-for-bit semantics.
+fp32 accumulation via preferred_element_type; exact (erf-form) GELU to
+match ops/nn.py activation(act_type='gelu'). The Pallas TPU lowering has
+no ``erf`` primitive, so the kernel evaluates erf with the
+Abramowitz-Stegun 7.1.26 rational form (|error| <= 1.5e-7, built from
+exp, multiply, add and divide only).
 
 Backward is the standard dense+GELU gradient in plain jnp (custom_vjp):
 it recomputes the pre-activation from the saved (x, W, b) instead of
@@ -40,9 +43,21 @@ from .pallas_attention import pallas_available  # shared TPU probe
 _INV_SQRT2 = 1.0 / math.sqrt(2.0)
 
 
+def _erf_f32(x):
+    # Abramowitz & Stegun 7.1.26: erf(|x|) = 1 - poly(t) exp(-x^2),
+    # t = 1 / (1 + p |x|); odd extension for x < 0
+    ax = jnp.abs(x)
+    t = 1.0 / (1.0 + 0.3275911 * ax)
+    poly = t * (0.254829592 + t * (-0.284496736 + t * (
+        1.421413741 + t * (-1.453152027 + t * 1.061405429))))
+    y = 1.0 - poly * jnp.exp(-ax * ax)
+    return jnp.where(x < 0, -y, y)
+
+
 def _gelu_f32(s):
-    # exact GELU, f32: matches jax.nn.gelu(approximate=False)
-    return 0.5 * s * (1.0 + jax.lax.erf(s * _INV_SQRT2))
+    # exact-form GELU, f32: agrees with jax.nn.gelu(approximate=False)
+    # to the erf approximation's 1.5e-7
+    return 0.5 * s * (1.0 + _erf_f32(s * _INV_SQRT2))
 
 
 def _ffn_kernel(x_ref, w_ref, b_ref, o_ref):

@@ -17,7 +17,6 @@ from __future__ import annotations
 import re
 import threading
 
-import jax
 from jax import lax
 
 _tls = threading.local()
@@ -78,46 +77,24 @@ def axis_index(axis_name):
 
 
 def axis_size(axis_name):
-    return lax.axis_size(axis_name) if hasattr(lax, 'axis_size') else \
-        lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
 # ---------------------------------------------------------------------------
 # ZeRO-3 gather scheduling helpers
 # ---------------------------------------------------------------------------
 
-@jax.custom_vjp
-def _opt_barrier(xs):
-    return lax.optimization_barrier(xs)
-
-
-def _opt_barrier_fwd(xs):
-    return lax.optimization_barrier(xs), None
-
-
-def _opt_barrier_bwd(_, cts):
-    # identity cotangents: the barrier orders the forward schedule; the
-    # backward regathers replay through jax.checkpoint with the same
-    # forward-side barriers, so no extra fence is needed here
-    return (tuple(cts),)
-
-
-_opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
-
-
 def ordered_barrier(*arrays):
     """Identity on ``arrays`` that makes every output depend on every
-    input in the compiled schedule (``lax.optimization_barrier``), with
-    a differentiation rule (the raw barrier has none in this jax).
+    input in the compiled schedule (``lax.optimization_barrier``; its
+    transpose fences the cotangents the same way).
 
     ZeRO-3 uses it to chain per-layer all-gathers: feeding layer k+1's
     sharded params through a barrier together with one leaf of layer
     k's GATHERED params makes gather(k+1) wait for gather(k) — but not
     for layer k's matmuls — so the gathers issue one layer ahead of the
     compute that consumes them."""
-    if len(arrays) == 1:
-        return (_opt_barrier((arrays[0],))[0],)
-    return _opt_barrier(tuple(arrays))
+    return lax.optimization_barrier(tuple(arrays))
 
 
 def _natural_key(s):

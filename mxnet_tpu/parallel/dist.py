@@ -1560,6 +1560,16 @@ def replica_delete(host, port, ns, step, timeout=None):
         timeout=timeout)
 
 
+def _local_tpu_chips():
+    """TPU chips this host exposes, counted from the device files libtpu
+    opens (/dev/accel* on older hosts, numbered /dev/vfio groups on the
+    v5e machines) — without touching jax, because a launcher that has
+    touched jax holds the chips its children need."""
+    import glob
+    return len(glob.glob('/dev/accel[0-9]*')) \
+        or len(glob.glob('/dev/vfio/[0-9]*'))
+
+
 def launch_local(script, n=2, env=None, coordinator='localhost:29500',
                  raw_command=False):
     """Spawn n local worker processes (the `--launcher local` analog of
@@ -1567,12 +1577,29 @@ def launch_local(script, n=2, env=None, coordinator='localhost:29500',
     protocol lives in one place). Returns their exit codes.
 
     raw_command=True runs `script` verbatim; otherwise it is a python
-    script argv run under the current interpreter."""
+    script argv run under the current interpreter.
+
+    On a host with TPU chips the workers must be pinned to the CPU
+    (``JAX_PLATFORMS=cpu`` in the environment or in ``env``): a TPU
+    process opens every local chip, so n of them cannot share a host.
+    One process drives all local chips — run the script once, without
+    the launcher, and its mesh spans whatever ``jax.devices()`` reports."""
+    base = dict(os.environ)
+    base.update(env or {})
+    chips = _local_tpu_chips()
+    if n > 1 and chips \
+            and base.get('JAX_PLATFORMS', '').split(',')[0] != 'cpu':
+        raise MXNetError(
+            f"launch_local: this host exposes {chips} TPU "
+            f"chip(s) and a TPU process opens all of them, so {n} local "
+            f"workers would collide on the device. One process drives "
+            f"all local chips: run the script directly (its mesh spans "
+            f"jax.devices()), or set JAX_PLATFORMS=cpu to rehearse the "
+            f"multi-process protocol on the CPU.")
     procs = []
     cmd = list(script) if raw_command else [sys.executable] + list(script)
     for i in range(n):
-        e = dict(os.environ)
-        e.update(env or {})
+        e = dict(base)
         e['MXNET_TPU_COORDINATOR'] = coordinator
         e['MXNET_TPU_NUM_PROCS'] = str(n)
         e['MXNET_TPU_PROC_ID'] = str(i)

@@ -29,11 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:
-    from jax import shard_map
-except ImportError:    # jax < 0.6 ships it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ['pipeline_forward', 'pipeline_loss_fn', 'stack_stage_params',
@@ -121,12 +117,8 @@ def pipeline_forward(stage_fn, stage_params, x_microbatches, mesh,
     pp_spec = P(pp_axis)
     in_specs = (jax.tree_util.tree_map(lambda _: pp_spec, stage_params),
                 P())
-    try:
-        mapped = shard_map(spmd, mesh=mesh, in_specs=in_specs,
-                           out_specs=P(), check_vma=False)
-    except TypeError:   # jax < 0.7 spells the unchecked mode check_rep
-        mapped = shard_map(spmd, mesh=mesh, in_specs=in_specs,
-                           out_specs=P(), check_rep=False)
+    mapped = shard_map(spmd, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(), check_vma=False)
     return mapped(stage_params, x_microbatches)
 
 

@@ -17,20 +17,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:    # jax < 0.6 ships it under experimental
-    from jax.experimental.shard_map import shard_map
-
-try:
-    _pcast = lax.pcast
-except AttributeError:
-    # jax < 0.7 has no varying-axis type system: replicated constants are
-    # accepted as scan carries directly, so the cast is the identity
-    def _pcast(x, axis_name, to=None):
-        return x
 
 
 def _block_attn(q, k, v, scale, causal, q_offset, kv_offset, kmask=None,
@@ -134,8 +122,8 @@ def ring_attention(q, k, v, mesh: Mesh, sp_axis: str = 'sp', causal=False,
         acc_l = jnp.zeros(q_blk.shape[:3] + (1,), jnp.float32)
         # initial accumulators are constants; mark them as varying over the
         # ring axis so the scan carry type matches the per-shard outputs
-        acc_out, acc_m, acc_l = _pcast((acc_out, acc_m, acc_l), sp_axis,
-                                       to='varying')
+        acc_out, acc_m, acc_l = lax.pcast((acc_out, acc_m, acc_l), sp_axis,
+                                          to='varying')
 
         perm = [(i, (i + 1) % n) for i in range(n)]
 

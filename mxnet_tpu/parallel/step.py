@@ -67,7 +67,7 @@ from ..resilience import faults as _faults
 from ..telemetry import trace as _trace, flight as _flight, \
     memory as _memory, compile as _compile
 from .. import random as _random
-from ..ops import rowsparse as _rowsparse
+from ..ops import attention as _attention, rowsparse as _rowsparse
 from . import compression as _compression
 from .collectives import group_params_by_layer, ordered_barrier
 from .mesh import default_mesh
@@ -516,6 +516,15 @@ class ShardedTrainStep:
             n for n, p in trainable
             if jnp.dtype(p.data()._data.dtype).itemsize < 4
             and jnp.issubdtype(p.data()._data.dtype, jnp.floating))
+        # mesh axes the param specs shard weights over (tp): where the
+        # attention heads divide over them, the flash kernel maps over
+        # them as well as over the batch axes (ops/attention.py)
+        spec_axes = {a for spec in self._spec_map.values() for e in spec
+                     for a in (e if isinstance(e, tuple) else (e,))}
+        model_axes = tuple(
+            a for a in self.mesh.axis_names
+            if a in spec_axes and a not in self._dp_axes
+            and self.mesh.shape[a] > 1)
         block = self.block
         loss_fn = self.loss_fn
         opt_update = self._opt_update
@@ -547,6 +556,8 @@ class ShardedTrainStep:
             _flags.is_training = True
             try:
                 with _random.key_provider(_random.TraceKeyProvider(key)), \
+                        _attention.mesh_placement(
+                            self.mesh, self._dp_axes, model_axes), \
                         (cap if cap is not None else nullcontext()):
                     out = block.forward(*[NDArray(x) for x in inputs])
                     outs = out if isinstance(out, (list, tuple)) else (out,)
@@ -1649,10 +1660,21 @@ class ShardedTrainStep:
             return None
         from ..telemetry import attribution as _attribution
         try:
-            compiled = self._compiled.lower(*self._cost_args).compile()
+            compiled = self.compiled_program()
         except Exception:
             return None
         return _attribution.xla_cost(compiled)
+
+    def compiled_program(self):
+        """The step program as the backend compiled it (``as_text()`` is
+        the optimized HLO chip_smoke.py reads for the Mosaic custom
+        calls and the collectives around them; ``memory_analysis()`` is
+        XLA's own byte plan). Compiled once more from the stored avals —
+        a persistent-cache hit when the cache is on; raises before the
+        first step."""
+        if self._compiled is None or self._cost_args is None:
+            raise MXNetError("compiled_program(): the step has not run yet")
+        return self._compiled.lower(*self._cost_args).compile()
 
     def memory_pools(self):
         """This step's live persistent arrays as named residency pools
@@ -1767,8 +1789,7 @@ class ShardedTrainStep:
         if self._compiled is None or self._cost_args is None:
             return None
         try:
-            compiled = self._compiled.lower(*self._cost_args).compile()
-            ma = compiled.memory_analysis()
+            ma = self.compiled_program().memory_analysis()
         except Exception:
             return None
         out = {}

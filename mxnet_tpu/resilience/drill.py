@@ -1444,7 +1444,7 @@ def run_serving_drill(workdir, requests=90, kill_rank=1, heartbeat=0.1,
             ([env['PYTHONPATH']] if env.get('PYTHONPATH') else [])),
         'JAX_PLATFORMS': 'cpu',
         'MXNET_TPU_TELEMETRY': '1',
-        'MXTPU_COMPILE_CACHE_DIR': cache_dir,
+        'JAX_COMPILATION_CACHE_DIR': cache_dir,
         'MXTPU_FLIGHT_DIR': workdir,
     })
     ms = dist.Membership(0, _SERVE_WORLD, port=side_port,
@@ -1559,10 +1559,14 @@ def run_serving_drill(workdir, requests=90, kill_rank=1, heartbeat=0.1,
 
         # weight push: new weights reach the survivor over the replica
         # transport and flip its predictions exactly
-        net = _serve_model()
-        probe = [1, 2, 3, 5, 7]
-        want = onp.asarray(net(nd.array(
-            onp.asarray([probe + [0] * 3], 'int32'))).asnumpy())[0, :5]
+        # the replicas run on the CPU backend, so the expectation is
+        # computed there too, whatever platform this process defaults to
+        import jax
+        with jax.default_device(jax.devices('cpu')[0]):
+            net = _serve_model()
+            probe = [1, 2, 3, 5, 7]
+            want = onp.asarray(net(nd.array(
+                onp.asarray([probe + [0] * 3], 'int32'))).asnumpy())[0, :5]
         push = serving.push_weights(
             net, step=7,
             replicas=[{'host': '127.0.0.1',

@@ -4,7 +4,9 @@ The reference compiles user CUDA C source with NVRTC (`CudaModule`/
 `CudaKernel`, ref: src/common/rtc.cc). The TPU equivalent is a user-written
 Pallas kernel compiled by Mosaic: `pallas_op` wraps a Pallas kernel function
 into an eager framework op over NDArrays, with the same "bring your own
-kernel" role. On CPU (tests) kernels run in Pallas interpret mode.
+kernel" role. Kernel mode follows ops.pallas_attention.default_interpret
+(the interpreter on the CPU backend, Mosaic elsewhere) unless the caller
+passes ``interpret=``.
 
 Example:
     def scale_add(x_ref, y_ref, o_ref):
@@ -21,12 +23,9 @@ import jax.numpy as jnp
 
 from .base import MXNetError
 from .ndarray.ndarray import NDArray, _wrap
+from .ops.pallas_attention import default_interpret
 
 __all__ = ['pallas_op', 'PallasKernel', 'CudaModule']
-
-
-def _default_interpret() -> bool:
-    return jax.devices()[0].platform != 'tpu'
 
 
 class PallasKernel:
@@ -70,7 +69,7 @@ class PallasKernel:
                 kwargs['out_specs'] = self.out_specs
             interpret = self.interpret
             if interpret is None:
-                interpret = _default_interpret()
+                interpret = default_interpret()
             call = pl.pallas_call(self.kernel, out_shape=out_shape,
                                   interpret=interpret, **kwargs)
             self._compiled[key] = jax.jit(call)

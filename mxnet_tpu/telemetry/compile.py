@@ -22,10 +22,13 @@ axis ("arg 3 `data`: shape (32, 128)→(32, 131)") in the
 RecompileWarning, the ``compile.recompiled`` flight note, and the
 ``mxnet_tpu_compile_churn_axes`` metric.
 
-Persistent cache: ``MXTPU_COMPILE_CACHE_DIR`` wires jax's compilation
-cache through config; hit/miss/saved-seconds are counted from jax's own
-cache events, with saved-seconds additionally estimated from the
-ledger's recorded compile time for the hit fingerprint.
+Persistent cache: one rule says where it lives —
+``JAX_COMPILATION_CACHE_DIR`` if set, else a fixed path in the
+checkout — and entry points opt in with :func:`use_default_cache`;
+``MXTPU_COMPILE_CACHE_DIR`` is the library-level knob and loses to the
+JAX variable. Hit/miss/saved-seconds are counted from jax's own cache
+events, with saved-seconds additionally estimated from the ledger's
+recorded compile time for the hit fingerprint.
 
 Disarmed (the default), every entry point is a single flag/dict check
 and allocates nothing.  Validate a ledger file with
@@ -51,6 +54,7 @@ __all__ = [
     'ledger', 'ledger_path', 'default_ledger_path',
     'in_flight', 'step_fields', 'snapshot_fields', 'health_fields',
     'persistent_cache_stats', 'enable_persistent_cache',
+    'use_default_cache',
     'validate_ledger_entry', 'validate_ledger',
     'LEDGER_SCHEMA',
 ]
@@ -68,7 +72,7 @@ _UNSET = object()
 
 _state = {'on': False}
 _lock = threading.RLock()
-_cfg = {'ring': None, 'ledger': _UNSET, 'cache_dir': _UNSET}
+_cfg = {'ring': None, 'ledger': _UNSET, 'cache_dir': _UNSET, 'base': None}
 
 _ring = collections.deque()              # ledger entries, oldest first
 _sites = {}          # site -> {'n', 'signature', 'fingerprint'}
@@ -133,8 +137,9 @@ def clear(ring=None, ledger=_UNSET, cache_dir=_UNSET):
         if cache_dir is not _UNSET:
             _cfg['cache_dir'] = cache_dir
             # keep _cache_state['applied'] — _ensure_persistent_cache
-            # compares it against the new dir to re-point (or, for '',
-            # UN-point) jax's cache config
+            # compares it against the new dir to re-point jax's cache
+            # config (for '': back to the process's own directory, or
+            # off if it has none)
     if cache_dir is not _UNSET:
         _ensure_persistent_cache()
 
@@ -171,15 +176,40 @@ def ledger():
 # persistent compile cache
 # ---------------------------------------------------------------------------
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def use_default_cache():
+    """Turn the persistent cache on for this process — the one call
+    chip_smoke.py, bench.py, tools/tune_bert_step.py and examples/ make.
+    It lives where ``JAX_COMPILATION_CACHE_DIR`` says if that is set
+    (jax reads the variable itself and nothing here points it anywhere
+    else), otherwise at ``<checkout>/.jax_compile_cache`` — a fixed
+    path, because the path is part of what a warm process must find
+    again. Returns the directory."""
+    with _lock:
+        _cfg['base'] = os.path.join(_CHECKOUT, '.jax_compile_cache')
+    return _ensure_persistent_cache()
+
+
 def _cache_dir():
-    if _cfg['cache_dir'] is not _UNSET:
-        return _cfg['cache_dir'] or ''
-    return _config_mod.get('MXTPU_COMPILE_CACHE_DIR') or ''
+    # an explicit in-process directory (the cold/warm A/B drills hand one
+    # to clear()/enable_persistent_cache()) holds until it is cleared;
+    # then JAX_COMPILATION_CACHE_DIR, which nothing else may beat; then
+    # what use_default_cache() chose; then the MXTPU_ knob
+    override = _cfg['cache_dir']
+    if override is not _UNSET and override:
+        return override
+    return os.environ.get('JAX_COMPILATION_CACHE_DIR') \
+        or _cfg['base'] \
+        or _config_mod.get('MXTPU_COMPILE_CACHE_DIR') or ''
 
 
 def enable_persistent_cache(path):
-    """Point jax's persistent compilation cache at `path` (overrides
-    MXTPU_COMPILE_CACHE_DIR for this process) and apply it now."""
+    """Point jax's persistent compilation cache at `path` for this
+    process (until ``clear(cache_dir='')`` hands it back to
+    :func:`_cache_dir`'s rule) and apply it now."""
     with _lock:
         _cfg['cache_dir'] = path
         _cache_state['applied'] = None
@@ -188,50 +218,27 @@ def enable_persistent_cache(path):
 
 def _ensure_persistent_cache():
     d = _cache_dir()
-    if _cache_state['applied'] == d:
+    if (_cache_state['applied'] or '') == d:
         return d
-    if not d:
-        # a dir WAS applied and is now unset (often a TemporaryDirectory
-        # that no longer exists): un-point jax or every later compile in
-        # the process warns trying to write cache entries into the grave
-        if _cache_state['applied']:
-            try:
-                import jax
-                jax.config.update('jax_compilation_cache_dir', None)
-                from jax.experimental.compilation_cache import (
-                    compilation_cache as _cc)
-                _cc.reset_cache()
-            except Exception:
-                pass
-            _cache_state['applied'] = None
-        return ''
-    try:
-        import jax
+    import jax
+    from jax.experimental.compilation_cache import (
+        compilation_cache as _cc)
+    if d:
         os.makedirs(d, exist_ok=True)
-        jax.config.update('jax_compilation_cache_dir', d)
         # drop jax's eligibility gates so every program (including the
         # tiny ones tests and cold-start smoke runs compile) is cached
-        for knob, val in (('jax_persistent_cache_min_entry_size_bytes', -1),
-                          ('jax_persistent_cache_min_compile_time_secs', 0.0)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:
-                pass
-        try:
-            # jax latches the cache's initialized/disabled state at the
-            # FIRST compile of the process — anything jitted before the
-            # dir was set (import-time helpers, init ops) leaves it
-            # permanently off without this re-init
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.reset_cache()
-        except Exception:
-            pass
-        _cache_state['applied'] = d
-    except Exception:
-        # jax absent or too old for the cache knobs: the plane still
-        # works, the cache just stays cold
-        _cache_state['applied'] = d
+        jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
+        jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+    if (jax.config.jax_compilation_cache_dir or '') != d:
+        # d == '' un-points a directory that was applied and is gone
+        # (a drill's TemporaryDirectory): left pointed, every later
+        # compile warns writing entries into the grave
+        jax.config.update('jax_compilation_cache_dir', d or None)
+    # jax latches the cache's initialized/disabled state at the FIRST
+    # compile of the process — anything jitted before the dir was set
+    # (import-time helpers, init ops) leaves it off without this re-init
+    _cc.reset_cache()
+    _cache_state['applied'] = d
     return d
 
 

@@ -149,6 +149,24 @@ def test_launch_local_two_workers(tmp_path):
     assert (tmp_path / 'rank1').read_text() == '2'
 
 
+def test_launch_local_refuses_to_share_tpu_chips(monkeypatch):
+    """On a host with TPU chips every TPU process opens all of them, so
+    n local workers cannot share it: launch_local refuses unless the
+    workers are pinned to the CPU, and starts nothing."""
+    import pytest
+    from mxnet_tpu.base import MXNetError
+    from mxnet_tpu.parallel import dist
+    monkeypatch.setattr(dist, '_local_tpu_chips', lambda: 4)
+    monkeypatch.setattr(dist.subprocess, 'Popen', lambda *a, **k: (
+        _ for _ in ()).throw(AssertionError('a worker was started')))
+    monkeypatch.delenv('JAX_PLATFORMS', raising=False)
+    with pytest.raises(MXNetError, match='One process drives all local'):
+        dist.launch_local(['train.py'], n=4)
+    monkeypatch.setenv('JAX_PLATFORMS', 'cpu')
+    with pytest.raises(AssertionError, match='a worker was started'):
+        dist.launch_local(['train.py'], n=4)     # pinned to CPU: allowed
+
+
 def test_launch_multiprocess_dp_training(tmp_path):
     """2-process x 4-device DP training: params broadcast from rank 0,
     gradient allreduce spans processes, both ranks converge identically

@@ -148,6 +148,21 @@ def test_context():
     assert_almost_equal(b, a)
 
 
+def test_accelerator_context_without_an_accelerator_raises():
+    """mx.tpu(0) / mx.gpu(0) name an accelerator: on a backend that has
+    none they raise instead of quietly resolving to the CPU, and an
+    array with no stated context reports the device jax put it on."""
+    import pytest
+    from mxnet_tpu.base import MXNetError
+    for ctx in (mx.tpu(0), mx.gpu(0)):
+        with pytest.raises(MXNetError, match='no accelerator'):
+            ctx.jax_device()
+        with pytest.raises(MXNetError, match='no accelerator'):
+            nd.ones((2,), ctx=ctx)
+    assert mx.cpu(0).jax_device().platform == 'cpu'
+    assert (nd.ones((2,)) + 1).context == mx.cpu(0)
+
+
 def test_one_hot_embedding_take():
     idx = nd.array([0, 2])
     oh = nd.one_hot(idx, depth=3)

@@ -478,6 +478,102 @@ def test_mha_dropout_routes_through_pallas():
     assert onp.abs(onp.asarray(out) - onp.asarray(base)).max() > 1e-3
 
 
+def test_mha_auto_route_propagates_a_kernel_failure(monkeypatch):
+    """'auto' chooses from the platform, the mask kind and the legality
+    verdict — and then stands by the choice: a kernel that fails raises,
+    it does not warn and hand the step to XLA attention."""
+    import warnings
+    import jax.numpy as jnp
+    import pytest
+    from mxnet_tpu.ops import attention as attn_ops, pallas_attention
+
+    def broken(*_a, **_k):
+        raise RuntimeError('Mosaic said no')
+    monkeypatch.setattr(pallas_attention, 'pallas_available', lambda: True)
+    monkeypatch.setattr(pallas_attention, 'flash_attention', broken)
+    q = jnp.asarray(_r(2, 16, 16))
+    before = dict(attn_ops.route_counts)
+    with warnings.catch_warnings():
+        warnings.simplefilter('error')
+        with pytest.raises(RuntimeError, match='Mosaic said no'):
+            attn_ops.multi_head_attention(q, q, q, num_heads=2)
+    assert attn_ops.route_counts == before        # nothing ran, nothing counted
+    # off the TPU the same call takes XLA, by the platform alone
+    monkeypatch.setattr(pallas_attention, 'pallas_available', lambda: False)
+    attn_ops.multi_head_attention(q, q, q, num_heads=2)
+    assert attn_ops.route_counts['xla'] == before['xla'] + 1
+    # and a shape the kernel cannot tile takes XLA on the TPU too
+    monkeypatch.setattr(pallas_attention, 'pallas_available', lambda: True)
+    odd = jnp.asarray(_r(2, 100, 16)).astype(jnp.bfloat16)
+    assert not pallas_attention.flash_legal(4, 100, 100, 8, odd.dtype)
+    attn_ops.multi_head_attention(odd, odd, odd, num_heads=2)
+    assert attn_ops.route_counts['xla'] == before['xla'] + 2
+
+
+def test_flash_attention_interpret_mode_is_explicit(monkeypatch):
+    """interpret=False means Mosaic, on any backend: nothing turns it
+    into True because the backend is a CPU. None asks the one helper,
+    which answers from the platform."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_attention as pa
+    seen = []
+
+    def spy(qf, kf, vf, km, meta, causal, dropout_p, interpret, bh_split):
+        seen.append(interpret)
+        return qf
+    monkeypatch.setattr(pa, '_flash', spy)
+    q = jnp.asarray(_r(1, 2, 16, 8))
+    pa.flash_attention(q, q, q, interpret=False)
+    pa.flash_attention(q, q, q, interpret=True)
+    pa.flash_attention(q, q, q)
+    assert seen == [False, True, True]            # the CPU helper: interpret
+    assert pa.default_interpret() is True and pa.pallas_available() is False
+    monkeypatch.setattr(pa.jax, 'default_backend', lambda: 'tpu')
+    assert pa.default_interpret() is False and pa.pallas_available() is True
+
+
+def test_flash_kernel_maps_over_the_mesh_bit_identically():
+    """Inside attention.mesh_placement the kernel runs per shard under
+    shard_map (a sharded Mosaic call does not lower otherwise): output and
+    gradients — in-kernel dropout included — are bit-identical to the
+    unsharded call, with the batch split over dp and the heads over tp."""
+    import jax
+    import jax.numpy as jnp
+    import pytest
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from mxnet_tpu.base import MXNetError
+    from mxnet_tpu.ops import attention as A
+    from mxnet_tpu.parallel import make_mesh
+    N, T, H, D = 4, 16, 4, 8
+    q, k, v = (jnp.asarray(_r(N, T, H * D)) for _ in range(3))
+    mask = (jnp.arange(T)[None, None, None, :] <
+            jnp.array([9, 16, 12, 16])[:, None, None, None])
+    key = jax.random.PRNGKey(3)
+
+    def loss(q, k, v, mask):
+        out = A.multi_head_attention(q, k, v, mask, num_heads=H,
+                                     dropout_p=0.3, use_pallas=True,
+                                     dropout_key=key)
+        return jnp.sum(jnp.tanh(out)), out
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+    (_, ref), g_ref = jax.jit(grad)(q, k, v, mask)
+    mesh = make_mesh((2, 2), ('dp', 'tp'))
+    sh = NamedSharding(mesh, P('dp'))
+
+    def mapped(q, k, v, mask):
+        with A.mesh_placement(mesh, ('dp',), ('tp',)):
+            return grad(q, k, v, mask)
+    (_, out), g = jax.jit(mapped, in_shardings=(sh,) * 4)(q, k, v, mask)
+    assert onp.array_equal(onp.asarray(out), onp.asarray(ref))
+    for a, b in zip(g, g_ref):
+        assert onp.array_equal(onp.asarray(a), onp.asarray(b))
+    # a batch that does not divide cannot be mapped: say so
+    with A.mesh_placement(make_mesh((8,), ('dp',)), ('dp',), ()):
+        with pytest.raises(MXNetError, match='does not divide'):
+            A.multi_head_attention(q, k, v, mask, num_heads=H,
+                                   use_pallas=True)
+
+
 def test_bert_masked_position_gather():
     """BertForPretraining(masked_positions=...) decodes only the masked
     positions and matches slicing the full-T logits (GluonNLP recipe)."""

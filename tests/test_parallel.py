@@ -158,10 +158,7 @@ def test_gradient_compression_math():
 def test_sync_batchnorm_in_shard_map():
     from mxnet_tpu.ops.nn import sync_batch_norm_op
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from mxnet_tpu.base import state as flags
     mesh = make_mesh((4,), ('dp',))
     rng = onp.random.RandomState(0)

@@ -174,14 +174,8 @@ def main():
     import jax
     if args.rbg:
         jax.config.update('jax_default_prng_impl', 'rbg')
-    cache = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         os.pardir, '.jax_compile_cache')
-    try:
-        jax.config.update('jax_compilation_cache_dir', cache)
-        jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
-        jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
-    except Exception:
-        pass
+    from bench import _peak_flops      # the one peak table, keyed by kind
+    peak = _peak_flops(jax.devices()[0]) * len(jax.devices())
 
     import numpy as onp
     import mxnet_tpu as mx
@@ -189,6 +183,8 @@ def main():
     from mxnet_tpu.models import BertForPretraining
     from mxnet_tpu.models.bert import bert_base_config, bert_pretrain_loss
     from mxnet_tpu.parallel import make_mesh, ShardedTrainStep
+    from mxnet_tpu.telemetry import compile as _compile
+    _compile.use_default_cache()
 
     cfg = bert_base_config()
     batch, seq = args.batch, args.seq
@@ -244,7 +240,7 @@ def main():
     flops = (6 * P_body * toks + 6 * P_head * batch * nmask
              + 6 * P_pool * batch
              + 12 * cfg['layers'] * cfg['hidden'] * seq * toks)
-    mfu = flops / dt / 197e12 * 100
+    mfu = flops / dt / peak * 100
     knobs = {k: v for k, v in os.environ.items() if 'MXTPU' in k}
     print(f"batch={batch} rbg={args.rbg} env={knobs}")
     print(f"step={dt * 1000:.1f}ms samples/sec={batch / dt:.1f} "
@@ -264,7 +260,7 @@ def main():
         comm_plan = getattr(step, '_comm_plan', None) or {}
         rep = attribution.report(
             flight.get().steps(), flops_per_step=flops,
-            peak_flops=197e12 * len(devices),
+            peak_flops=peak,
             collective_bytes={k: v[0] for k, v in comm_plan.items()})
         xla = step.cost_analysis()
         if xla:
