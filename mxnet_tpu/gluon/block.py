@@ -45,6 +45,7 @@ class _BlockScope:
 
     @staticmethod
     def create(prefix, params, hint):
+        """(full prefix, params, the prefix less the enclosing scope's)."""
         current = getattr(_BlockScope._current, 'value', None)
         if current is None:
             if prefix is None:
@@ -55,7 +56,7 @@ class _BlockScope:
                 params = ParameterDict(prefix)
             else:
                 params = ParameterDict(params.prefix, params)
-            return prefix, params
+            return prefix, params, prefix
         if prefix is None:
             count = current._counter.get(hint, 0)
             prefix = f"{hint}{count}_"
@@ -65,7 +66,7 @@ class _BlockScope:
             params = ParameterDict(parent.prefix + prefix, parent._shared)
         else:
             params = ParameterDict(params.prefix, params)
-        return current._block.prefix + prefix, params
+        return current._block.prefix + prefix, params, prefix
 
     def __enter__(self):
         if self._block._empty_prefix:
@@ -85,9 +86,13 @@ class Block:
 
     def __init__(self, prefix=None, params=None):
         self._empty_prefix = prefix == ''
-        self._prefix, self._params = _BlockScope.create(
+        self._prefix, self._params, local = _BlockScope.create(
             prefix, params, self._alias())
         self._name = self._prefix[:-1] if self._prefix.endswith('_') else self._prefix
+        # the name the parent knows this block by: its own less the
+        # parent's prefix ('bertlayer3', 'qkv'). A device trace names the
+        # ops of forward() under it (_trace_scope, mxnet_tpu/scopes.py).
+        self._local_name = local.rstrip('_') or self._alias()
         self._scope = _BlockScope(self)
         self._children = {}
         self._reg_params = {}
@@ -107,6 +112,14 @@ class Block:
 
     def name_scope(self):
         return self._scope
+
+    def _trace_scope(self):
+        """What forward() runs under: ``jax.named_scope(<local name>)``,
+        so that every op traced inside carries the nested block path in
+        its HLO ``op_name`` and a profile reads in the model's own words.
+        It acts while a program is traced, never while one runs, and is
+        part of no cache key."""
+        return jax.named_scope(self._local_name)
 
     @property
     def params(self):
@@ -167,7 +180,8 @@ class Block:
     def __call__(self, *args):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args)
+        with self._trace_scope():
+            out = self.forward(*args)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
@@ -575,7 +589,8 @@ class CachedOp:
             prev_training = state.is_training
             state.is_training = is_training
             try:
-                with _random.key_provider(_random.TraceKeyProvider(rng)):
+                with _random.key_provider(_random.TraceKeyProvider(rng)), \
+                        block._trace_scope():
                     out = block.forward(*wrapped)
             finally:
                 state.is_training = prev_training

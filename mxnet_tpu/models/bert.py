@@ -12,9 +12,12 @@ from __future__ import annotations
 
 import math
 
+import jax
+
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from .. import ndarray as nd
+from .. import scopes as _scopes
 from ..ops import attention as attn_ops
 from ..ndarray.ndarray import _invoke
 
@@ -45,9 +48,12 @@ class BertSelfAttention(HybridBlock):
     def forward(self, x, mask=None):
         # x: (N, T, C)
         qkv = self.qkv(x)
-        q, k, v = qkv.split(3, axis=-1)
-        out = _invoke(attn_ops.multi_head_attention, q, k, v, mask,
-                      num_heads=self._heads, dropout_p=self._attn_dropout)
+        # no block of its own: a plain scope names it in a device trace
+        with jax.named_scope(_scopes.ATTN_CORE):
+            q, k, v = qkv.split(3, axis=-1)
+            out = _invoke(attn_ops.multi_head_attention, q, k, v, mask,
+                          num_heads=self._heads,
+                          dropout_p=self._attn_dropout)
         return self.dropout(self.proj(out))
 
 
@@ -73,15 +79,20 @@ class BertLayer(HybridBlock):
 
     def forward(self, x, mask=None):
         attn = self.attention(x, mask)
-        x = self._add_ln(self.ln1, x, attn)
+        # ln1, ffn1 and ln2 go through fused ops and not through their
+        # blocks' forward, so each gets the plain scope a block would
+        with jax.named_scope(_scopes.LN1):
+            x = self._add_ln(self.ln1, x, attn)
         # FFN1 matmul + bias + GELU through one op so the fused Pallas
         # epilogue can take it when MXTPU_PALLAS_FFN=1 (ops/nn.py
         # dense_gelu; the XLA default is the same Dense+gelu math)
         from ..ops import nn as _nn_ops
-        h = _invoke(_nn_ops.dense_gelu, x, self.ffn1.weight.data(),
-                    self.ffn1.bias.data())
+        with jax.named_scope(_scopes.FFN1):
+            h = _invoke(_nn_ops.dense_gelu, x, self.ffn1.weight.data(),
+                        self.ffn1.bias.data())
         h = self.dropout(self.ffn2(h))
-        return self._add_ln(self.ln2, x, h)
+        with jax.named_scope(_scopes.LN2):
+            return self._add_ln(self.ln2, x, h)
 
 
 class BertModel(HybridBlock):
@@ -120,8 +131,9 @@ class BertModel(HybridBlock):
             ar = nd.arange(0, T, dtype='float32')
             mask = (ar.reshape(1, 1, 1, T) <
                     valid_length.reshape(-1, 1, 1, 1))
-        for layer in self.encoder:
-            x = layer(x, mask)
+        with self.encoder._trace_scope():    # iterated, never called
+            for layer in self.encoder:
+                x = layer(x, mask)
         pooled = self.pooler(nd.slice_axis(x, axis=1, begin=0, end=1)
                              .squeeze(axis=1))
         return x, pooled

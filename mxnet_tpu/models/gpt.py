@@ -12,9 +12,12 @@ them; here the causal variant is first-class.
 """
 from __future__ import annotations
 
+import jax
+
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from .. import ndarray as nd
+from .. import scopes as _scopes
 from ..ops import attention as attn_ops
 from ..ndarray.ndarray import _invoke
 from .bert import masked_cross_entropy
@@ -47,10 +50,12 @@ class GPTBlock(HybridBlock):
         # pre-norm residual blocks (GPT-2 recipe)
         h = self.ln1(x)
         qkv = self.qkv(h)
-        q, k, v = qkv.split(3, axis=-1)
-        attn = _invoke(attn_ops.multi_head_attention, q, k, v, None,
-                       num_heads=self._heads, dropout_p=self._attn_dropout,
-                       causal=True)
+        # no block of its own: a plain scope names it in a device trace
+        with jax.named_scope(_scopes.ATTN_CORE):
+            q, k, v = qkv.split(3, axis=-1)
+            attn = _invoke(attn_ops.multi_head_attention, q, k, v, None,
+                           num_heads=self._heads,
+                           dropout_p=self._attn_dropout, causal=True)
         x = x + self.dropout(self.proj(attn))
         h = nd.activation(self.ffn1(self.ln2(x)), act_type='gelu')
         return x + self.dropout(self.ffn2(h))
@@ -82,12 +87,15 @@ class GPTModel(HybridBlock):
         pos = nd.arange(0, T, dtype='int32').reshape(1, T)
         x = self.embed_dropout(self.word_embed(tokens)
                                + self.pos_embed(pos))
-        for blk in self.blocks:
-            x = blk(x)
+        with self.blocks._trace_scope():     # iterated, never called
+            for blk in self.blocks:
+                x = blk(x)
         x = self.ln_f(x)
         # weight-tied LM head: logits = x @ E^T (data() resolves to the
         # trace proxy inside a compiled step)
-        return nd.dot(x, self.word_embed.weight.data(), transpose_b=True)
+        with jax.named_scope(_scopes.LM_HEAD):
+            return nd.dot(x, self.word_embed.weight.data(),
+                          transpose_b=True)
 
 
 def gpt_lm_loss(logits, labels):

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from ..base import MXNetError, register_op, state as _flags
 from .. import random as _random
+from .. import scopes as _scopes
 
 __all__ = []
 
@@ -248,9 +249,17 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
     N, Tq, tot = query.shape
     H = num_heads
     D = tot // H
-    q = query.reshape(N, Tq, H, D).transpose(0, 2, 1, 3)
-    k = key.reshape(N, key.shape[1], H, D).transpose(0, 2, 1, 3)
-    v = value.reshape(N, value.shape[1], H, D).transpose(0, 2, 1, 3)
+
+    def merge_heads(out):
+        # (N, H, T, D) back to (N, T, H*D): with the split below, the
+        # layout copies round the kernel, a line of their own in a trace
+        with jax.named_scope(_scopes.ATTN_LAYOUT):
+            return out.transpose(0, 2, 1, 3).reshape(N, Tq, tot)
+
+    with jax.named_scope(_scopes.ATTN_LAYOUT):
+        q = query.reshape(N, Tq, H, D).transpose(0, 2, 1, 3)
+        k = key.reshape(N, key.shape[1], H, D).transpose(0, 2, 1, 3)
+        v = value.reshape(N, value.shape[1], H, D).transpose(0, 2, 1, 3)
 
     apply_dropout = dropout_p > 0.0 and (dropout_key is not None
                                          or _flags.is_training)
@@ -284,7 +293,7 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
                                  causal=causal, key_mask=kpm,
                                  **ring_kwargs)
             route_counts['ring'] += 1
-            return out.transpose(0, 2, 1, 3).reshape(N, Tq, tot)
+            return merge_heads(out)
         # inside the context but unroutable (cross attention, per-query
         # mask, indivisible T): fall through to the dense path — loudly,
         # because the user asked for ring attention
@@ -315,7 +324,7 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
         out = _flash_route(q, k, v, kpm, causal,
                            dropout_p if apply_dropout else 0.0, seed)
         route_counts['pallas'] += 1
-        return out.transpose(0, 2, 1, 3).reshape(N, Tq, tot)
+        return merge_heads(out)
 
     route_counts['xla'] += 1
     scale = 1.0 / math.sqrt(D)
@@ -338,4 +347,4 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
         att = jnp.where(keep, att / (1.0 - dropout_p),
                         jnp.zeros_like(att)).astype(q.dtype)
     out = jnp.einsum('nhqk,nhkd->nhqd', att, v)
-    return out.transpose(0, 2, 1, 3).reshape(N, Tq, tot)
+    return merge_heads(out)

@@ -51,6 +51,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import scopes as _scopes
+
 _NEG_INF = -1e30
 
 # every grid here is (batch·head groups, outer seq blocks, inner seq
@@ -287,6 +289,7 @@ def _fa_forward(q, k, v, kmask, meta, causal, dropout_p, interpret,
                         pltpu.VMEM((G, bq, 128), jnp.float32)],
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
+        name=_scopes.FLASH_FWD,
     )(q, k, v, km3, meta)
     lse = lse[..., 0]
     if pq:
@@ -434,6 +437,7 @@ def _fa_backward(q, k, v, kmask, meta, causal, dropout_p, interpret,
         scratch_shapes=[pltpu.VMEM((G, bq, D), jnp.float32)],
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
+        name=_scopes.FLASH_BWD_DQ,
     )(q, k, v, km3, meta, do, lse3, delta)
 
     # dk/dv grid permutes (q-block, k-block): q innermost
@@ -454,6 +458,7 @@ def _fa_backward(q, k, v, kmask, meta, causal, dropout_p, interpret,
                         pltpu.VMEM((G, bk, D), jnp.float32)],
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
+        name=_scopes.FLASH_BWD_DKV,
     )(q, k, v, km3, meta, do, lse3, delta)
 
     dq = dq[:, :Tq].astype(q.dtype)

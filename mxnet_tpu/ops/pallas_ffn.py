@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import scopes as _scopes
 from .pallas_attention import pallas_available  # shared TPU probe
 
 _INV_SQRT2 = 1.0 / math.sqrt(2.0)
@@ -97,6 +98,7 @@ def _fwd_impl(x, w, b, block_m, block_n, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
+        name=_scopes.FFN_GELU,
     )(x2, w, b2)
     return out.reshape(orig_shape[:-1] + (N,))
 

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import scopes as _scopes
 from .pallas_attention import pallas_available  # shared TPU probe
 
 
@@ -68,6 +69,7 @@ def _fwd_impl(x, res, gamma, beta, eps, block_rows, interpret):
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, C), x.dtype),
         interpret=interpret,
+        name=_scopes.ADD_LAYERNORM,
     )(x2, r2, g2, b2)
     return out.reshape(orig_shape)
 

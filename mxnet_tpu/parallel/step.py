@@ -67,6 +67,7 @@ from ..resilience import faults as _faults
 from ..telemetry import trace as _trace, flight as _flight, \
     memory as _memory, compile as _compile
 from .. import random as _random
+from .. import scopes as _scopes
 from ..ops import attention as _attention, rowsparse as _rowsparse
 from . import compression as _compression
 from .collectives import group_params_by_layer, ordered_barrier
@@ -559,9 +560,13 @@ class ShardedTrainStep:
                         _attention.mesh_placement(
                             self.mesh, self._dp_axes, model_axes), \
                         (cap if cap is not None else nullcontext()):
-                    out = block.forward(*[NDArray(x) for x in inputs])
+                    # the names a device trace reads (scopes.py): the
+                    # model's block path, then the loss
+                    with block._trace_scope():
+                        out = block.forward(*[NDArray(x) for x in inputs])
                     outs = out if isinstance(out, (list, tuple)) else (out,)
-                    loss = loss_fn(*outs, *[NDArray(l) for l in labels])
+                    with jax.named_scope(_scopes.LOSS):
+                        loss = loss_fn(*outs, *[NDArray(l) for l in labels])
             finally:
                 _flags.is_training = prev
                 for p in name_to_param.values():
@@ -571,7 +576,8 @@ class ShardedTrainStep:
             # poisoning the loss AND (via the chain rule) every gradient
             # regardless of the model's input dtypes — int-token models
             # like BERT included
-            loss_val = jnp.mean(loss._data) * fault_scale
+            with jax.named_scope(_scopes.LOSS):
+                loss_val = jnp.mean(loss._data) * fault_scale
             aux = {n: proxies[n]._data for n in f_names}
             if cap is not None:
                 return loss_val, (aux, cap.results())
@@ -789,13 +795,14 @@ class ShardedTrainStep:
                 token = None
                 for _gname, names in layer_groups:
                     vals = [t_params[n] for n in names]
-                    if token is not None:
-                        out = ordered_barrier(*(vals + [token]))
-                        vals = list(out[:-1])
-                    vals = [checkpoint_name(
-                        jax.lax.with_sharding_constraint(v, gather_ns[n]),
-                        'zero3_gather')
-                        for n, v in zip(names, vals)]
+                    with jax.named_scope(_scopes.GATHER):
+                        if token is not None:
+                            out = ordered_barrier(*(vals + [token]))
+                            vals = list(out[:-1])
+                        vals = [checkpoint_name(
+                            jax.lax.with_sharding_constraint(
+                                v, gather_ns[n]), 'zero3_gather')
+                            for n, v in zip(names, vals)]
                     for n, v in zip(names, vals):
                         gathered[n] = v
                     token = vals[0]
@@ -855,36 +862,48 @@ class ShardedTrainStep:
                 tangents = {n: jnp.zeros(
                     (sum(sparse_budgets[n]), shapes[n][1]), jnp.float32)
                     for n in s_names}
-                (loss_val, (aux, srec)), (grads, g_rows) = \
-                    jax.value_and_grad(
-                        loss_forward, argnums=(0, 6), has_aux=True)(
-                            t_params, f_params, inputs, labels, key,
-                            fault_scale, tangents)
+                with jax.named_scope(_scopes.FWD_BWD):
+                    (loss_val, (aux, srec)), (grads, g_rows) = \
+                        jax.value_and_grad(
+                            loss_forward, argnums=(0, 6), has_aux=True)(
+                                t_params, f_params, inputs, labels, key,
+                                fault_scale, tangents)
             else:
-                (loss_val, aux), grads = jax.value_and_grad(
-                    loss_forward, has_aux=True)(t_params, f_params,
-                                                inputs, labels, key,
-                                                fault_scale)
+                with jax.named_scope(_scopes.FWD_BWD):
+                    (loss_val, aux), grads = jax.value_and_grad(
+                        loss_forward, has_aux=True)(t_params, f_params,
+                                                    inputs, labels, key,
+                                                    fault_scale)
                 srec, g_rows = {}, {}
             new_params = {}
             new_master = {}
             new_state = {}
             new_residual = {}
             sparse_stats = {}
-            ok = jnp.isfinite(loss_val) if guard_on else None
+            # each parameter's stretch of the program is traced under
+            # three names (scopes.py): the gradient's way to where it is
+            # consumed, the non-finite check, the update
+            exchange = functools.partial(jax.named_scope, _scopes.EXCHANGE)
+            guard = functools.partial(jax.named_scope, _scopes.GUARD)
+            update = functools.partial(jax.named_scope, _scopes.UPDATE)
+            with guard():
+                ok = jnp.isfinite(loss_val) if guard_on else None
             for n in t_names:
                 srn = srec.get(n)
                 if srn is not None:
                     vocab, dim = shapes[n]
                     uids = srn['uids']
-                    rows = g_rows[n].astype(jnp.float32)
-                    if len(sparse_budgets[n]) > 1:
-                        # several lookups of the same table in one step:
-                        # segment-sum overlapping ids into one block
-                        uids, rows, n_live = _rowsparse.merge_row_blocks(
-                            uids, rows, vocab)
-                    else:
-                        n_live = srn['n_live']
+                    with exchange():
+                        rows = g_rows[n].astype(jnp.float32)
+                        if len(sparse_budgets[n]) > 1:
+                            # several lookups of the same table in one
+                            # step: segment-sum overlapping ids into one
+                            # block
+                            uids, rows, n_live = \
+                                _rowsparse.merge_row_blocks(
+                                    uids, rows, vocab)
+                        else:
+                            n_live = srn['n_live']
                     sparse_stats[n] = n_live
                     if not sparse_exact:
                         # lazy update (reference lazy_update=True /
@@ -920,36 +939,40 @@ class ShardedTrainStep:
                             # per-row scales (block = dim); the residual
                             # stays table-shaped and persistent — only
                             # live rows accumulate/flush error
-                            acc = rows + _rget(residual[n])
-                            dec = _compression.encode_decode(
-                                acc, ctype, cthreshold, dim)
-                            new_residual[n] = _rset(residual[n],
-                                                    acc - dec)
+                            with exchange():
+                                acc = rows + _rget(residual[n])
+                                dec = _compression.encode_decode(
+                                    acc, ctype, cthreshold, dim)
+                                new_residual[n] = _rset(residual[n],
+                                                        acc - dec)
                             rows = dec
                         if guard_on:
-                            ok = jnp.logical_and(
-                                ok, jnp.all(jnp.isfinite(rows)))
-                        if n in master_names:
-                            p32 = master[n]
-                        else:
-                            p32 = t_params[n].astype(jnp.float32)
-                        p_rows = _rget(p32)
-                        s_rows = tuple(_rget(s) if s.ndim else s
-                                       for s in opt_state[n])
-                        nr_, nsr_ = opt_update(p_rows, rows, s_rows, lr,
-                                               **opt_kwargs)
-                        np_ = _rset(p32, nr_)
-                        new_state[n] = tuple(
-                            _rset(s, sr) if s.ndim else sr
-                            for s, sr in zip(opt_state[n], nsr_))
-                        if fz is not None:
-                            new_params[n] = np_[:fz['size']].reshape(
-                                shapes[n]).astype(t_params[n].dtype)
-                            new_master[n] = np_
-                        else:
-                            new_params[n] = np_.astype(t_params[n].dtype)
+                            with guard():
+                                ok = jnp.logical_and(
+                                    ok, jnp.all(jnp.isfinite(rows)))
+                        with update():
                             if n in master_names:
+                                p32 = master[n]
+                            else:
+                                p32 = t_params[n].astype(jnp.float32)
+                            p_rows = _rget(p32)
+                            s_rows = tuple(_rget(s) if s.ndim else s
+                                           for s in opt_state[n])
+                            nr_, nsr_ = opt_update(p_rows, rows, s_rows,
+                                                   lr, **opt_kwargs)
+                            np_ = _rset(p32, nr_)
+                            new_state[n] = tuple(
+                                _rset(s, sr) if s.ndim else sr
+                                for s, sr in zip(opt_state[n], nsr_))
+                            if fz is not None:
+                                new_params[n] = np_[:fz['size']].reshape(
+                                    shapes[n]).astype(t_params[n].dtype)
                                 new_master[n] = np_
+                            else:
+                                new_params[n] = np_.astype(
+                                    t_params[n].dtype)
+                                if n in master_names:
+                                    new_master[n] = np_
                         continue
                     # exact mode: densify the deduped block into a
                     # table-shaped grad and run the regular dense path —
@@ -957,58 +980,67 @@ class ShardedTrainStep:
                     # parity oracle). The WIRE exchange still happened
                     # on row blocks (the tangent cotangent), only the
                     # local update is dense.
-                    g32 = jnp.zeros((vocab, dim), jnp.float32) \
-                        .at[uids].add(rows, mode='drop')
+                    with exchange():
+                        g32 = jnp.zeros((vocab, dim), jnp.float32) \
+                            .at[uids].add(rows, mode='drop')
                 else:
-                    g32 = grads[n].astype(jnp.float32)
+                    with exchange():
+                        g32 = grads[n].astype(jnp.float32)
                 fz = flat_meta.get(n)
                 zsh = shard_constraint.get(n)
-                if fz is not None:
-                    # ragged param (ZeRO-3 flatten+pad): the grad
-                    # flattens and zero-pads into the flat 1/dp layout
-                    g32 = jnp.pad(g32.reshape(-1), (0, fz['pad']))
-                    g32 = jax.lax.with_sharding_constraint(
-                        g32, zero_shardings[n])
-                elif zsh is not None:
-                    # reduce-scatter: the grad is only ever consumed in
-                    # this dp-sharded layout, so the partitioner combines
-                    # the backward psum + slice into one reduce-scatter
-                    g32 = jax.lax.with_sharding_constraint(g32, zsh)
-                if comp_on:
-                    # error-feedback quantized exchange epilogue: the
-                    # cross-host hop carries Q(g + r); the decoded value
-                    # feeds the update and the quantization error r' is
-                    # re-offered next step instead of lost (Lin et al.;
-                    # Karimireddy et al.). Elementwise on the sharded
-                    # grad — adds no collective of its own.
-                    acc = g32 + residual[n]
-                    g32 = _compression.encode_decode(
-                        acc, ctype, cthreshold, cblock)
-                    new_residual[n] = acc - g32
+                with exchange():
+                    if fz is not None:
+                        # ragged param (ZeRO-3 flatten+pad): the grad
+                        # flattens and zero-pads into the flat 1/dp layout
+                        g32 = jnp.pad(g32.reshape(-1), (0, fz['pad']))
+                        g32 = jax.lax.with_sharding_constraint(
+                            g32, zero_shardings[n])
+                    elif zsh is not None:
+                        # reduce-scatter: the grad is only ever consumed
+                        # in this dp-sharded layout, so the partitioner
+                        # combines the backward psum + slice into one
+                        # reduce-scatter
+                        g32 = jax.lax.with_sharding_constraint(g32, zsh)
+                    if comp_on:
+                        # error-feedback quantized exchange epilogue: the
+                        # cross-host hop carries Q(g + r); the decoded
+                        # value feeds the update and the quantization
+                        # error r' is re-offered next step instead of lost
+                        # (Lin et al.; Karimireddy et al.). Elementwise on
+                        # the sharded grad — adds no collective of its own.
+                        acc = g32 + residual[n]
+                        g32 = _compression.encode_decode(
+                            acc, ctype, cthreshold, cblock)
+                        new_residual[n] = acc - g32
                 if guard_on:
                     # isfinite over the SHARDED (and, under compression,
                     # DECODED) grad: each device reduces its slice and
                     # GSPMD psums the scalar — never a full-grad rebuild.
                     # encode_decode propagates non-finite inputs, so a
                     # poisoned gradient cannot hide behind the quantizer.
-                    ok = jnp.logical_and(ok, jnp.all(jnp.isfinite(g32)))
-                if n in master_names:
-                    p32 = master[n]
-                else:
-                    p32 = t_params[n].astype(jnp.float32)
-                    if zsh is not None:
-                        p32 = jax.lax.with_sharding_constraint(p32, zsh)
-                np_, ns_ = opt_update(p32, g32, opt_state[n], lr, **opt_kwargs)
-                if fz is not None:
-                    # updated flat master -> refresh the replicated
-                    # logical compute-dtype copy (slice off the pad)
-                    new_params[n] = np_[:fz['size']].reshape(
-                        shapes[n]).astype(t_params[n].dtype)
-                    new_master[n] = np_
-                else:
-                    new_params[n] = np_.astype(t_params[n].dtype)
+                    with guard():
+                        ok = jnp.logical_and(
+                            ok, jnp.all(jnp.isfinite(g32)))
+                with update():
                     if n in master_names:
+                        p32 = master[n]
+                    else:
+                        p32 = t_params[n].astype(jnp.float32)
+                        if zsh is not None:
+                            p32 = jax.lax.with_sharding_constraint(
+                                p32, zsh)
+                    np_, ns_ = opt_update(p32, g32, opt_state[n], lr,
+                                          **opt_kwargs)
+                    if fz is not None:
+                        # updated flat master -> refresh the replicated
+                        # logical compute-dtype copy (slice off the pad)
+                        new_params[n] = np_[:fz['size']].reshape(
+                            shapes[n]).astype(t_params[n].dtype)
                         new_master[n] = np_
+                    else:
+                        new_params[n] = np_.astype(t_params[n].dtype)
+                        if n in master_names:
+                            new_master[n] = np_
                 new_state[n] = ns_
             new_f = {n: aux.get(n, f_params[n]) for n in f_names}
             if guard_on:
@@ -1018,18 +1050,21 @@ class ShardedTrainStep:
                 # round-trip on the happy path. The residual writeback
                 # is gated too: a NaN residual must never outlive the
                 # skipped step that produced it.
-                new_params = {n: jnp.where(ok, new_params[n], t_params[n])
-                              for n in t_names}
-                new_master = {n: jnp.where(ok, new_master[n], master[n])
-                              for n in new_master}
-                new_state = {
-                    n: tuple(jnp.where(ok, ns_, os_) for ns_, os_ in
-                             zip(new_state[n], opt_state[n]))
-                    for n in t_names}
-                new_residual = {n: jnp.where(ok, nr, residual[n])
-                                for n, nr in new_residual.items()}
-                new_f = {n: jnp.where(ok, new_f[n], f_params[n])
-                         for n in f_names}
+                with guard():
+                    new_params = {
+                        n: jnp.where(ok, new_params[n], t_params[n])
+                        for n in t_names}
+                    new_master = {
+                        n: jnp.where(ok, new_master[n], master[n])
+                        for n in new_master}
+                    new_state = {
+                        n: tuple(jnp.where(ok, ns_, os_) for ns_, os_ in
+                                 zip(new_state[n], opt_state[n]))
+                        for n in t_names}
+                    new_residual = {n: jnp.where(ok, nr, residual[n])
+                                    for n, nr in new_residual.items()}
+                    new_f = {n: jnp.where(ok, new_f[n], f_params[n])
+                             for n in f_names}
                 outs = (new_params, new_f, new_master, new_state,
                         new_residual, loss_val, ok)
             else:
@@ -1669,12 +1704,27 @@ class ShardedTrainStep:
         """The step program as the backend compiled it (``as_text()`` is
         the optimized HLO chip_smoke.py reads for the Mosaic custom
         calls and the collectives around them; ``memory_analysis()`` is
-        XLA's own byte plan). Compiled once more from the stored avals —
-        a persistent-cache hit when the cache is on; raises before the
-        first step."""
+        XLA's own byte plan). Compiled once more from the stored avals;
+        raises before the first step.
+
+        Its ``op_name``s are this process's own. The persistent cache's
+        key leaves metadata out, so a hit may hand back an executable
+        built from an older source, or from a model under another
+        prefix, with *that* program's names in its text: the same
+        instructions, scopes that no longer exist. A reader that splits a
+        device trace by scope (chipbench/scopes.py) would then split by
+        nothing. So this one compile makes the metadata part of the key:
+        a persistent-cache hit only on a program traced from the same
+        source, a compile of its own otherwise."""
         if self._compiled is None or self._cost_args is None:
             raise MXNetError("compiled_program(): the step has not run yet")
-        return self._compiled.lower(*self._cost_args).compile()
+        flag = 'jax_compilation_cache_include_metadata_in_key'
+        before = getattr(jax.config, flag)
+        jax.config.update(flag, True)
+        try:
+            return self._compiled.lower(*self._cost_args).compile()
+        finally:
+            jax.config.update(flag, before)
 
     def memory_pools(self):
         """This step's live persistent arrays as named residency pools

@@ -1,0 +1,51 @@
+"""The names mxnet_tpu writes into the programs it builds.
+
+A device trace (``jax.profiler``, ``mx.profiler``) names each executed op
+by the ``op_name`` of the HLO instruction it ran, and that is the stack of
+``jax.named_scope``s open when the op was traced. The framework opens
+three kinds, all at trace time only -- there is no switch, and a compiled
+program runs the same instructions with or without them:
+
+* a phase of ``ShardedTrainStep``'s one program (parallel/step.py): the
+  constants below. Forward and backward are both under ``FWD_BWD``; JAX
+  itself tells them apart, wrapping the first scope inside it in
+  ``jvp(...)`` on the way forward and ``transpose(jvp(...))`` on the way
+  back;
+* a Gluon block (gluon/block.py): every block's forward runs under the
+  name its parent knows it by, so an op reads
+  ``bertmodel0/encoder/bertlayer3/bertselfattention0/qkv/dot_general``;
+* a hand-written stretch that is no block of its own (models/bert.py,
+  models/gpt.py, ops/attention.py): a plain word, listed below.
+
+A Pallas kernel is named by ``pallas_call(name=...)``: the name becomes
+the custom call's instruction name and the last scope of its ``op_name``.
+
+To give a stretch of your own code a line in the trace::
+
+    with jax.named_scope('my_stretch'):
+        y = my_ops(x)
+
+PERF.md section 3 lists which benchmark metric reads which name.
+"""
+
+# phases of the train step
+FWD_BWD = 'mxtpu.fwd_bwd'       # value_and_grad of the model and its loss
+LOSS = 'mxtpu.loss'             # the loss function, inside FWD_BWD
+GATHER = 'mxtpu.gather'         # ZeRO-3's per-layer parameter gathers
+EXCHANGE = 'mxtpu.exchange'     # gradient cast, ZeRO layout, compression
+GUARD = 'mxtpu.guard'           # the non-finite check and the gated writeback
+UPDATE = 'mxtpu.update'         # optimizer update, cast back, compute copy
+
+# stretches inside blocks that are no child block
+ATTN_LAYOUT = 'attn_layout'     # (N,T,H*D) <-> (N,H,T,D) round the kernel
+ATTN_CORE = 'attn_core'         # scores, softmax, dropout, weighted sum
+FFN1 = 'ffn1'                   # first feed-forward matmul + GELU
+LN1, LN2 = 'ln1', 'ln2'         # residual add + LayerNorm
+LM_HEAD = 'lm_head'             # GPT's tied output projection
+
+# Pallas kernels
+FLASH_FWD = 'mxtpu_flash_fwd'
+FLASH_BWD_DQ = 'mxtpu_flash_bwd_dq'
+FLASH_BWD_DKV = 'mxtpu_flash_bwd_dkv'
+FFN_GELU = 'mxtpu_ffn_gelu'
+ADD_LAYERNORM = 'mxtpu_add_layernorm'
