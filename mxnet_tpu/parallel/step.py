@@ -882,7 +882,27 @@ class ShardedTrainStep:
             sparse_stats = {}
             # each parameter's stretch of the program is traced under
             # three names (scopes.py): the gradient's way to where it is
-            # consumed, the non-finite check, the update
+            # consumed, the non-finite check, the update.
+            # A dense gradient enters its exchange stretch through an
+            # optimization_barrier, as value_and_grad hands it over (bf16
+            # for a bf16 parameter: an identity, no rounding is added).
+            # It is a fusion boundary. Without it XLA:TPU puts the whole
+            # AdamW update (new bf16 weight, master, both moments) into
+            # the epilogue of the weight-gradient matmul, and the seven
+            # f32 tiles of that epilogue leave the matmul a smaller
+            # output window: BERT's FFN2 gradient, 135 GFLOP, took
+            # 2.99 ms with the update inside on one v5e and 1.30 ms on
+            # each of four, where ZeRO-1's reduce-scatter already stood
+            # between the two; 66.2 of bert_base.t512's 297.6 ms step
+            # were such fusions (ledger, PR 26). With the boundary the
+            # same matmul takes 1.49 ms, the separate updates 4.7 ms a
+            # step, and the step 278.9 ms (PERF.md 6, PR 28).
+            # phase_mixed_ms_per_step guards this line: a few ms there
+            # mean an update is back inside a matmul. Per leaf, not over
+            # the gradient tree: a gradient lives from its matmul to its
+            # update, and XLA's plan for the step grew by 0.002 GiB. The
+            # RowSparse row blocks below have no matmul-shaped gradient
+            # and take no barrier.
             exchange = functools.partial(jax.named_scope, _scopes.EXCHANGE)
             guard = functools.partial(jax.named_scope, _scopes.GUARD)
             update = functools.partial(jax.named_scope, _scopes.UPDATE)
@@ -985,7 +1005,8 @@ class ShardedTrainStep:
                             .at[uids].add(rows, mode='drop')
                 else:
                     with exchange():
-                        g32 = grads[n].astype(jnp.float32)
+                        g32 = jax.lax.optimization_barrier(
+                            grads[n]).astype(jnp.float32)
                 fz = flat_meta.get(n)
                 zsh = shard_constraint.get(n)
                 with exchange():

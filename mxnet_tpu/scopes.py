@@ -32,7 +32,8 @@ PERF.md section 3 lists which benchmark metric reads which name.
 FWD_BWD = 'mxtpu.fwd_bwd'       # value_and_grad of the model and its loss
 LOSS = 'mxtpu.loss'             # the loss function, inside FWD_BWD
 GATHER = 'mxtpu.gather'         # ZeRO-3's per-layer parameter gathers
-EXCHANGE = 'mxtpu.exchange'     # gradient cast, ZeRO layout, compression
+EXCHANGE = 'mxtpu.exchange'     # fusion boundary, gradient cast, ZeRO layout,
+                                # compression
 GUARD = 'mxtpu.guard'           # the non-finite check and the gated writeback
 UPDATE = 'mxtpu.update'         # optimizer update, cast back, compute copy
 
