@@ -18,6 +18,20 @@ over k-blocks) and dk/dv (grid (BH/G, Tk/BK, Tq/BQ), accumulating over
 q-blocks) — both recompute the probability block from the saved LSE
 (flash-attention backward recurrence), so live memory stays O(T).
 
+Without ``causal`` every (q-block, k-block) cell of those grids is
+computed. With it the grid is (B*H/G, listed cells): the cells that hold
+a score at or under the diagonal (:func:`_cell_live`), row by row for the
+forward and dq, column by column for dk/dv, from a small table the
+kernels and their index maps read as a scalar-prefetch operand
+(:func:`_causal_cell_table`). A cell wholly above the diagonal is no grid
+step at all: no matmul, no hash, no exp, no copy. Such a cell used to add
+exp(-1e30 - m) = 0 to every accumulator, and the listed cells keep their
+order, so no result changes by a bit. The cells the diagonal crosses
+still mask element by element (:func:`_masked_scores`). All of it hangs on
+the static ``causal`` flag: the non-causal build is the kernels, grids
+and index maps it was before. Each causal build counts its cells in
+``causal_cells``.
+
 Attention dropout runs INSIDE the kernels: the keep mask is a
 counter-based hash (murmur3 finalizer) of the global (batch·head, q, k)
 element coordinates mixed with a per-call seed, so the forward and both
@@ -59,6 +73,90 @@ _NEG_INF = -1e30
 # blocks) with the scratch accumulators carried over the innermost axis
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=('parallel', 'parallel', 'arbitrary'))
+
+# the causal grid is (batch·head groups, listed cells), the accumulators
+# carried over the cells of one row or column
+_CAUSAL_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=('parallel', 'arbitrary'))
+
+# trace-time telemetry of the causal skip, beside ops.attention.route_counts
+# and autotune.decisions(): {(kind, live cells, cells of the full plane):
+# kernel builds}, the plane being one head-group's (q-block, k-block) grid.
+# Static numbers; a non-causal build records nothing.
+causal_cells = {}
+
+
+def _cell_live(qb, kb, bq, bk):
+    """Does cell (q-block qb, k-block kb) hold a score at or under the
+    causal diagonal (top-left aligned, as :func:`_masked_scores` masks)?
+    True iff its last query row sees its first key. A window (ROADMAP R5)
+    is a second inequality here."""
+    return qb * bq + (bq - 1) >= kb * bk
+
+
+def _causal_cell_table(kind, nq, nk, bq, bk, by_row):
+    """int32 (4, n): the cells a causal kernel visits, in grid order, as
+    rows [q-block, k-block, first of its line, last of its line]. A line
+    is a q-block row (``by_row``: forward, dq) or a k-block column
+    (dk/dv), walked in ascending order as the full grid walks it, so the
+    accumulators add up in the same order. A line without a live cell (a
+    k-block no query sees, when Tk > Tq) keeps one dead cell: it adds
+    exact zeros, as every dead cell used to, and the line's output is
+    still initialised and written. Counts the build in ``causal_cells``."""
+    table, live_cells = [], 0
+    for outer in range(nq if by_row else nk):
+        line = [(outer, inner) if by_row else (inner, outer)
+                for inner in range(nk if by_row else nq)]
+        live = [cell for cell in line if _cell_live(*cell, bq, bk)]
+        live_cells += len(live)
+        live = live or line[-1:]
+        table += [(qb, kb, cell == 0, cell == len(live) - 1)
+                  for cell, (qb, kb) in enumerate(live)]
+    key = (kind, live_cells, nq * nk)
+    causal_cells[key] = causal_cells.get(key, 0) + 1
+    return onp.asarray(table, onp.int32).T
+
+
+def _step_cell(cells_ref, q_axis):
+    """(q-block, k-block, is-first, is-last) of this grid step, the last
+    two as thunks evaluated where the kernel asks. Without a table: the
+    full grid, q-blocks on grid axis ``q_axis`` (1: forward and dq, 2:
+    dk/dv) and the accumulators carried over axis 2. With one: the
+    causal grid's listed cell (:func:`_causal_cell_table`)."""
+    if cells_ref is None:
+        outer, inner = pl.program_id(1), pl.program_id(2)
+        n_inner = pl.num_programs(2)
+        qb, kb = (outer, inner) if q_axis == 1 else (inner, outer)
+        return qb, kb, lambda: inner == 0, lambda: inner == n_inner - 1
+    cell = pl.program_id(1)
+    return (cells_ref[0, cell], cells_ref[1, cell],
+            lambda: cells_ref[2, cell] == 1, lambda: cells_ref[3, cell] == 1)
+
+
+def _causal_specs(G, bq, bk, D):
+    """BlockSpecs over the causal grid (head group b, listed cell c) with
+    the cell table as scalar-prefetch operand, by role: q-side block,
+    q-side column (lse, delta), k-side block, key mask."""
+    return (pl.BlockSpec((G, bq, D), lambda b, c, cells: (b, cells[0, c], 0)),
+            pl.BlockSpec((G, bq, 1), lambda b, c, cells: (b, cells[0, c], 0)),
+            pl.BlockSpec((G, bk, D), lambda b, c, cells: (b, cells[1, c], 0)),
+            pl.BlockSpec((G, 1, bk), lambda b, c, cells: (b, 0, cells[1, c])))
+
+
+def _causal_call(kernel, cells, groups, in_specs, out_specs, scratch_shapes,
+                 **call):
+    """``pallas_call`` of ``kernel`` over the grid (groups, listed cells),
+    already applied to the cell table."""
+    def with_table(cells_ref, *refs):
+        return kernel(*refs, cells_ref=cells_ref)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(groups, cells.shape[1]),
+        in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=scratch_shapes)
+    return functools.partial(
+        pl.pallas_call(with_table, grid_spec=grid_spec,
+                       compiler_params=_CAUSAL_COMPILER_PARAMS, **call),
+        jnp.asarray(cells))
 
 
 def pallas_available() -> bool:
@@ -169,7 +267,10 @@ def _counter_keep(seed, bh, rows, cols, rate):
 
 def _masked_scores(q, k, kmask_row, qb, kb, bq, bk, scale, causal, k_len):
     """(bq, bk) f32 scores for one (q-block, k-block) cell of one head:
-    QK^T * scale, key-padding cut at k_len, additive user mask, causal."""
+    QK^T * scale, key-padding cut at k_len, additive user mask, causal.
+    The causal ``where`` is what masks inside the cells the diagonal
+    crosses (and, needlessly, in those wholly under it); the cells wholly
+    above it are not in the causal grid (:func:`_cell_live`)."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -188,17 +289,16 @@ def _masked_scores(q, k, kmask_row, qb, kb, bq, bk, scale, causal, k_len):
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
                    o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                   scale, causal, G, bq, bk, k_len, dropout_p, bh_split):
+                   scale, causal, G, bq, bk, k_len, dropout_p, bh_split,
+                   cells_ref=None):
     """One (head-group, q-block, k-block) cell. Refs are VMEM blocks:
     q (G, bq, D), k/v (G, bk, D), kmask (G, 1, bk) additive f32,
     o (G, bq, D), lse (G, bq, 1); meta (1, 2) uint32 in SMEM
     [dropout seed, global batch·head base];
     scratch acc (G, bq, D) f32, m/l (G, bq, 128) f32."""
-    qb = pl.program_id(1)
-    kb = pl.program_id(2)
-    nkb = pl.num_programs(2)
+    qb, kb, first, last = _step_cell(cells_ref, q_axis=1)
 
-    @pl.when(kb == 0)
+    @pl.when(first())
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -228,7 +328,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
         m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
         l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
-    @pl.when(kb == nkb - 1)
+    @pl.when(last())
     def _finalize():
         for g in range(G):
             l = l_ref[g, :, :1]
@@ -266,31 +366,42 @@ def _fa_forward(q, k, v, kmask, meta, causal, dropout_p, interpret,
     kernel = functools.partial(
         _fa_fwd_kernel, scale=scale, causal=causal, G=G, bq=bq, bk=bk,
         k_len=Tk, dropout_p=float(dropout_p), bh_split=bh_split)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(BH // G, nq, nk),
-        in_specs=[
-            pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((G, 1, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((G, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, nq * bq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, nq * bq, 1), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((G, bq, D), jnp.float32),
-                        pltpu.VMEM((G, bq, 128), jnp.float32),
-                        pltpu.VMEM((G, bq, 128), jnp.float32)],
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
-        name=_scopes.FLASH_FWD,
-    )(q, k, v, km3, meta)
+    out_shape = [jax.ShapeDtypeStruct((BH, nq * bq, D), q.dtype),
+                 jax.ShapeDtypeStruct((BH, nq * bq, 1), jnp.float32)]
+    scratch = [pltpu.VMEM((G, bq, D), jnp.float32),
+               pltpu.VMEM((G, bq, 128), jnp.float32),
+               pltpu.VMEM((G, bq, 128), jnp.float32)]
+    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    if causal:
+        qspec, col1, kspec, mspec = _causal_specs(G, bq, bk, D)
+        call = _causal_call(
+            kernel, _causal_cell_table('fwd', nq, nk, bq, bk, by_row=True),
+            BH // G, in_specs=[qspec, kspec, kspec, mspec, sspec],
+            out_specs=[qspec, col1], out_shape=out_shape,
+            scratch_shapes=scratch, interpret=interpret,
+            name=_scopes.FLASH_FWD)
+    else:
+        call = pl.pallas_call(
+            kernel,
+            grid=(BH // G, nq, nk),
+            in_specs=[
+                pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((G, 1, bk), lambda b, i, j: (b, 0, j)),
+                sspec,
+            ],
+            out_specs=[
+                pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((G, bq, 1), lambda b, i, j: (b, i, 0)),
+            ],
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+            compiler_params=_COMPILER_PARAMS,
+            name=_scopes.FLASH_FWD,
+        )
+    out, lse = call(q, k, v, km3, meta)
     lse = lse[..., 0]
     if pq:
         out = out[:, :Tq]
@@ -304,13 +415,12 @@ def _fa_forward(q, k, v, kmask, meta, causal, dropout_p, interpret,
 
 def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                   lse_ref, delta_ref, dq_ref, dq_acc, *,
-                  scale, causal, G, bq, bk, k_len, dropout_p, bh_split):
+                  scale, causal, G, bq, bk, k_len, dropout_p, bh_split,
+                  cells_ref=None):
     """dq for one q-block, accumulated over k-blocks (grid (BH/G, nq, nk))."""
-    qb = pl.program_id(1)
-    kb = pl.program_id(2)
-    nkb = pl.num_programs(2)
+    qb, kb, first, last = _step_cell(cells_ref, q_axis=1)
 
-    @pl.when(kb == 0)
+    @pl.when(first())
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -333,21 +443,20 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
             ds, k_ref[g].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(kb == nkb - 1)
+    @pl.when(last())
     def _finalize():
         dq_ref[:] = dq_acc[:]
 
 
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                   scale, causal, G, bq, bk, k_len, dropout_p, bh_split):
+                   scale, causal, G, bq, bk, k_len, dropout_p, bh_split,
+                   cells_ref=None):
     """dk/dv for one k-block, accumulated over q-blocks
     (grid (BH/G, nk, nq): k-block is program 1, q-block is program 2)."""
-    kb = pl.program_id(1)
-    qb = pl.program_id(2)
-    nqb = pl.num_programs(2)
+    qb, kb, first, last = _step_cell(cells_ref, q_axis=2)
 
-    @pl.when(qb == 0)
+    @pl.when(first())
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -380,7 +489,7 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
             ds, q_ref[g].astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # (bk, D)
 
-    @pl.when(qb == nqb - 1)
+    @pl.when(last())
     def _finalize():
         dk_ref[:] = dk_acc[:]
         dv_ref[:] = dv_acc[:]
@@ -427,39 +536,60 @@ def _fa_backward(q, k, v, kmask, meta, causal, dropout_p, interpret,
     mspec_j = pl.BlockSpec((G, 1, bk), lambda b, i, j: (b, 0, j))
     sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    dq = pl.pallas_call(
-        functools.partial(_fa_dq_kernel, **kw),
-        grid=(BH // G, nq, nk),
-        in_specs=[qspec_i, kspec_j, kspec_j, mspec_j, sspec,
-                  qspec_i, col1_i, col1_i],
-        out_specs=pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, nq * bq, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((G, bq, D), jnp.float32)],
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
-        name=_scopes.FLASH_BWD_DQ,
-    )(q, k, v, km3, meta, do, lse3, delta)
+    dq_shape = jax.ShapeDtypeStruct((BH, nq * bq, D), jnp.float32)
+    dq_scratch = [pltpu.VMEM((G, bq, D), jnp.float32)]
+    dkv_shape = [jax.ShapeDtypeStruct((BH, nk * bk, D), jnp.float32),
+                 jax.ShapeDtypeStruct((BH, nk * bk, D), jnp.float32)]
+    dkv_scratch = [pltpu.VMEM((G, bk, D), jnp.float32),
+                   pltpu.VMEM((G, bk, D), jnp.float32)]
+    operands = (q, k, v, km3, meta, do, lse3, delta)
+    if causal:
+        qspec, col1, kspec, mspec = _causal_specs(G, bq, bk, D)
+        in_specs = [qspec, kspec, kspec, mspec, sspec, qspec, col1, col1]
+        dq = _causal_call(
+            functools.partial(_fa_dq_kernel, **kw),
+            _causal_cell_table('bwd_dq', nq, nk, bq, bk, by_row=True),
+            BH // G, in_specs=in_specs, out_specs=qspec, out_shape=dq_shape,
+            scratch_shapes=dq_scratch, interpret=interpret,
+            name=_scopes.FLASH_BWD_DQ)(*operands)
+        dk, dv = _causal_call(
+            functools.partial(_fa_dkv_kernel, **kw),
+            _causal_cell_table('bwd_dkv', nq, nk, bq, bk, by_row=False),
+            BH // G, in_specs=in_specs, out_specs=[kspec, kspec],
+            out_shape=dkv_shape, scratch_shapes=dkv_scratch,
+            interpret=interpret, name=_scopes.FLASH_BWD_DKV)(*operands)
+    else:
+        dq = pl.pallas_call(
+            functools.partial(_fa_dq_kernel, **kw),
+            grid=(BH // G, nq, nk),
+            in_specs=[qspec_i, kspec_j, kspec_j, mspec_j, sspec,
+                      qspec_i, col1_i, col1_i],
+            out_specs=pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
+            out_shape=dq_shape,
+            scratch_shapes=dq_scratch,
+            interpret=interpret,
+            compiler_params=_COMPILER_PARAMS,
+            name=_scopes.FLASH_BWD_DQ,
+        )(*operands)
 
-    # dk/dv grid permutes (q-block, k-block): q innermost
-    qspec_2 = pl.BlockSpec((G, bq, D), lambda b, j, i: (b, i, 0))
-    kspec_1 = pl.BlockSpec((G, bk, D), lambda b, j, i: (b, j, 0))
-    col1_2 = pl.BlockSpec((G, bq, 1), lambda b, j, i: (b, i, 0))
-    mspec_1 = pl.BlockSpec((G, 1, bk), lambda b, j, i: (b, 0, j))
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_dkv_kernel, **kw),
-        grid=(BH // G, nk, nq),
-        in_specs=[qspec_2, kspec_1, kspec_1, mspec_1, sspec,
-                  qspec_2, col1_2, col1_2],
-        out_specs=[pl.BlockSpec((G, bk, D), lambda b, j, i: (b, j, 0)),
-                   pl.BlockSpec((G, bk, D), lambda b, j, i: (b, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((BH, nk * bk, D), jnp.float32),
-                   jax.ShapeDtypeStruct((BH, nk * bk, D), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((G, bk, D), jnp.float32),
-                        pltpu.VMEM((G, bk, D), jnp.float32)],
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
-        name=_scopes.FLASH_BWD_DKV,
-    )(q, k, v, km3, meta, do, lse3, delta)
+        # dk/dv grid permutes (q-block, k-block): q innermost
+        qspec_2 = pl.BlockSpec((G, bq, D), lambda b, j, i: (b, i, 0))
+        kspec_1 = pl.BlockSpec((G, bk, D), lambda b, j, i: (b, j, 0))
+        col1_2 = pl.BlockSpec((G, bq, 1), lambda b, j, i: (b, i, 0))
+        mspec_1 = pl.BlockSpec((G, 1, bk), lambda b, j, i: (b, 0, j))
+        dk, dv = pl.pallas_call(
+            functools.partial(_fa_dkv_kernel, **kw),
+            grid=(BH // G, nk, nq),
+            in_specs=[qspec_2, kspec_1, kspec_1, mspec_1, sspec,
+                      qspec_2, col1_2, col1_2],
+            out_specs=[pl.BlockSpec((G, bk, D), lambda b, j, i: (b, j, 0)),
+                       pl.BlockSpec((G, bk, D), lambda b, j, i: (b, j, 0))],
+            out_shape=dkv_shape,
+            scratch_shapes=dkv_scratch,
+            interpret=interpret,
+            compiler_params=_COMPILER_PARAMS,
+            name=_scopes.FLASH_BWD_DKV,
+        )(*operands)
 
     dq = dq[:, :Tq].astype(q.dtype)
     dk = dk[:, :Tk].astype(k.dtype)

@@ -1,0 +1,295 @@
+"""The causal flash kernels visit only the (q-block, k-block) cells at or
+under the diagonal (ops/pallas_attention.py: ``_cell_live``).
+
+What is held here: the causal results against the naive attention of
+tests/test_operator.py; that a skipped cell contributed nothing (the same
+bits with the skip patched out); the counts of live cells a build records;
+that a non-causal build is, equation for equation, the kernel it was before
+the skip existed; and that Mosaic takes the guarded kernels at the
+benchmark cells' shapes, compiled here for a described v5e:2x2 with no chip.
+"""
+import contextlib
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops import autotune
+from mxnet_tpu.ops import pallas_attention as pa
+from test_operator import _naive_mha
+
+SEED = 1234
+# T=128: a 2 x 2 forward grid and a 4 x 4 backward grid (3 of 4 and 10 of
+# 16 cells live, the GPT-2 cell's pattern)
+FWD_BLOCKS = (2, 64, 64)
+BWD_BLOCKS = (2, 32, 32)
+
+
+def _forced(fwd=FWD_BLOCKS, bwd=BWD_BLOCKS):
+    stack = contextlib.ExitStack()
+    stack.enter_context(autotune.forced(autotune.KERNEL_FA, 'fwd', fwd))
+    stack.enter_context(autotune.forced(autotune.KERNEL_FA, 'bwd', bwd))
+    return stack
+
+
+def _qkv(B, H, Tq, Tk, D, seed=0):
+    rng = onp.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((B, H, t, D)), jnp.float32)
+                 for t in (Tq, Tk, Tk))
+
+
+def _right_padding(B, Tk, pad=7):
+    """Additive key mask: the first sequence ends ``pad`` keys early. Key 0
+    stays, so every causal row sees a key."""
+    vlen = jnp.array([Tk - pad] + [Tk] * (B - 1))
+    return jnp.where(jnp.arange(Tk)[None, :] < vlen[:, None],
+                     0.0, -1e30).astype(jnp.float32)
+
+
+def _naive_mha_dropped(q, k, v, key_mask, rate):
+    """``_naive_mha`` (causal) with the kernels' dropout bits on the
+    probabilities, regenerated from coordinates."""
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
+    s = jnp.einsum('bhqd,bhkd->bhqk', q, k) / onp.sqrt(D)
+    if key_mask is not None:
+        s = s + key_mask[:, None, None, :]
+    s = jnp.where(jnp.tril(jnp.ones((Tq, Tk), bool)), s, -1e30)
+    bh = jnp.arange(B * H, dtype=jnp.uint32).reshape(B, H, 1, 1)
+    rows = jnp.arange(Tq, dtype=jnp.uint32)[None, None, :, None]
+    cols = jnp.arange(Tk, dtype=jnp.uint32)[None, None, None, :]
+    keep = pa._counter_keep(jnp.uint32(SEED), bh, rows, cols, rate)
+    return jnp.einsum('bhqk,bhkd->bhqd', jax.nn.softmax(s, -1) * keep, v)
+
+
+def _out_and_grads(fn, q, k, v):
+    D = q.shape[-1]
+
+    def loss(q, k, v):
+        out = fn(q, k, v)
+        return jnp.sum(out * jnp.cos(jnp.arange(D, dtype=jnp.float32))), out
+    (_, out), grads = jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return [onp.asarray(x) for x in (out,) + grads]
+
+
+def _flash(key_mask, rate):
+    def fn(q, k, v):
+        return pa.flash_attention(
+            q, k, v, key_mask=key_mask, causal=True, dropout_p=rate,
+            dropout_seed=jnp.uint32(SEED) if rate else None, interpret=True)
+    return fn
+
+
+# (a) ------------------------------------------------------------------------
+
+@pytest.mark.parametrize('T', [128, 100])        # 100: padded last blocks
+@pytest.mark.parametrize('masked', [False, True])
+@pytest.mark.parametrize('rate', [0.0, 0.1])
+def test_causal_skip_matches_naive(rate, masked, T):
+    """out, dq, dk, dv of the causal kernels, forward and backward tiled
+    differently, against the naive attention in float32."""
+    B, H, D = 2, 2, 16
+    q, k, v = _qkv(B, H, T, T, D)
+    km = _right_padding(B, T) if masked else None
+    with _forced():
+        got = _out_and_grads(_flash(km, rate), q, k, v)
+    if rate:
+        def naive(q, k, v):
+            return _naive_mha_dropped(q, k, v, km, rate)
+    else:
+        def naive(q, k, v):
+            return _naive_mha(q, k, v, km, causal=True)
+    want = _out_and_grads(naive, q, k, v)
+    for name, a, b in zip(('out', 'dq', 'dk', 'dv'), got, want):
+        onp.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5,
+                                    err_msg=name)
+
+
+# (b) ------------------------------------------------------------------------
+
+@pytest.mark.parametrize('Tq,Tk', [(128, 128), (100, 100), (64, 128),
+                                   (128, 64)])
+def test_a_skipped_cell_contributed_nothing(monkeypatch, Tq, Tk):
+    """With every cell declared live the table lists the full grid, as
+    the kernels ran before the skip existed (a dead cell adds
+    exp(-1e30 - m) = 0 everywhere). The results are the same to the bit:
+    dropout, key mask, square and oblong."""
+    B, H, D = 2, 2, 16
+    q, k, v = _qkv(B, H, Tq, Tk, D, seed=1)
+    km = _right_padding(B, Tk)
+    with _forced():
+        skipped = _out_and_grads(_flash(km, 0.1), q, k, v)
+    monkeypatch.setattr(pa, '_cell_live', lambda qb, kb, bq, bk: qb >= 0)
+    with _forced():
+        every_cell = _out_and_grads(_flash(km, 0.1), q, k, v)
+    for name, a, b in zip(('out', 'dq', 'dk', 'dv'), skipped, every_cell):
+        onp.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_dropout_bits_do_not_depend_on_the_backward_tiling():
+    """Backward blocks 32 or 64 wide: another pattern of dead cells (10 of
+    16, 3 of 4), the same dropout bits in the live ones. ``out`` is equal
+    to the bit (the forward's tiling is the same); the gradients sum their
+    blocks in another order and agree to rounding, where one moved
+    dropout bit would move them by a whole probability."""
+    q, k, v = _qkv(2, 2, 128, 128, 16, seed=2)
+    with _forced(bwd=(2, 32, 32)):
+        narrow = _out_and_grads(_flash(None, 0.1), q, k, v)
+    with _forced(bwd=(2, 64, 64)):
+        wide = _out_and_grads(_flash(None, 0.1), q, k, v)
+    onp.testing.assert_array_equal(narrow[0], wide[0])
+    for name, a, b in zip(('dq', 'dk', 'dv'), narrow[1:], wide[1:]):
+        onp.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5,
+                                    err_msg=name)
+
+
+# (c), (d): what a build is, read from its jaxpr ------------------------------
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                inner = getattr(sub, 'jaxpr', sub)
+                if hasattr(inner, 'eqns'):
+                    yield from _walk(inner)
+
+
+def _kernel_builds(causal, B, H, T, D=64, dtype=jnp.bfloat16):
+    """{kernel name: its pallas_call equation} of one traced forward +
+    backward at the default blocks. Traced, not run."""
+    x = jax.ShapeDtypeStruct((B, H, T, D), dtype)
+    km = jax.ShapeDtypeStruct((B, T), jnp.float32)
+
+    def loss(q, k, v, km, seed):
+        out = pa.flash_attention(q, k, v, key_mask=km, causal=causal,
+                                 dropout_p=0.1, dropout_seed=seed,
+                                 interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+        x, x, x, km, jax.ShapeDtypeStruct((), jnp.uint32))
+    return {e.params['name']: e
+            for e in _walk(traced.jaxpr) if e.primitive.name == 'pallas_call'}
+
+
+def test_live_cells_at_the_gpt2_cells_grid():
+    """gpt2_small.t1024 (B=24, 12 heads, T=1024): forward (4, 512, 512)
+    runs 3 of its 4 cells, backward (4, 256, 256) 10 of 16, and a
+    non-causal build records nothing."""
+    before = dict(pa.causal_cells)
+
+    def new():
+        return {key: n - before.get(key, 0)
+                for key, n in pa.causal_cells.items()
+                if n != before.get(key, 0)}
+    builds = _kernel_builds(False, B=56, H=12, T=512)
+    assert len(builds) == 3
+    assert new() == {}
+    builds = _kernel_builds(True, B=24, H=12, T=1024)
+    assert new() == {('fwd', 3, 4): 1, ('bwd_dq', 10, 16): 1,
+                     ('bwd_dkv', 10, 16): 1}
+    # the causal grid is (head groups, listed cells), and nothing guards a
+    # cell's body: the two conds are _init and _finalize, as without causal
+    assert {name: tuple(e.params['grid_mapping'].grid)
+            for name, e in builds.items()} == {
+        'mxtpu_flash_fwd': (72, 3), 'mxtpu_flash_bwd_dq': (72, 10),
+        'mxtpu_flash_bwd_dkv': (72, 10)}
+    for e in builds.values():
+        assert sum(x.primitive.name == 'cond'
+                   for x in _walk(e.params['jaxpr'])) == 2
+
+
+def test_the_cell_table_lists_live_cells_in_grid_order():
+    """Row by row for the forward and dq, column by column for dk/dv,
+    ascending inside a line as the full grid walks it; a column no query
+    sees keeps one dead cell, so that its dk/dv are still written."""
+    def rows(table):
+        return [tuple(int(x) for x in col) for col in table.T]
+    assert rows(pa._causal_cell_table('t', 2, 2, 64, 64, by_row=True)) == [
+        (0, 0, 1, 1), (1, 0, 1, 0), (1, 1, 0, 1)]
+    assert rows(pa._causal_cell_table('t', 2, 2, 64, 64, by_row=False)) == [
+        (0, 0, 1, 0), (1, 0, 0, 1), (1, 1, 1, 1)]
+    # blocks of different widths: bq=64, bk=32
+    assert rows(pa._causal_cell_table('t', 1, 3, 64, 32, by_row=True)) == [
+        (0, 0, 1, 0), (0, 1, 0, 1)]
+    # Tq=32 < Tk=64 at 32-wide blocks: k-block 1 is seen by no query
+    assert rows(pa._causal_cell_table('t', 1, 2, 32, 32, by_row=False)) == [
+        (0, 0, 1, 1), (0, 1, 1, 1)]
+    for key in [k for k in pa.causal_cells if k[0] == 't']:
+        del pa.causal_cells[key]
+
+
+# the numbers of the commit before the skip (a371ff9), BERT-base's cell:
+# (top-level equations, equations with those of nested jaxprs, conds, grid)
+NON_CAUSAL_AT_BERT_T512 = {
+    'mxtpu_flash_fwd': (276, 338, 2, (168, 1, 1)),
+    'mxtpu_flash_bwd_dq': (252, 272, 2, (168, 2, 2)),
+    'mxtpu_flash_bwd_dkv': (272, 296, 2, (168, 2, 2)),
+}
+
+
+@pytest.mark.parametrize('kernel', sorted(NON_CAUSAL_AT_BERT_T512))
+def test_the_non_causal_build_is_what_it_was(kernel):
+    """causal=False at bert_base.t512's shape (B=56, 12 heads, T=512,
+    bf16, key mask, dropout): each kernel's jaxpr has the equations and
+    the two conds (_init, _finalize) it had before the causal skip, the
+    grid is the full one, and no index map computes anything. A skip that
+    leaks into the non-causal path changes one of these."""
+    e = _kernel_builds(False, B=56, H=12, T=512)[kernel]
+    body = e.params['jaxpr']
+    nested = list(_walk(body))
+    mapping = e.params['grid_mapping']
+    assert (len(body.eqns), len(nested),
+            sum(x.primitive.name == 'cond' for x in nested),
+            tuple(mapping.grid)) == NON_CAUSAL_AT_BERT_T512[kernel]
+    for block in mapping.block_mappings:
+        assert len(block.index_map_jaxpr.jaxpr.eqns) == 0
+
+
+# Mosaic, without the chip ----------------------------------------------------
+
+@pytest.fixture(scope='module')
+def one_chip():
+    """A described v5e chip to compile for. The compile cache is off
+    around it: an executable compiled for a described chip cannot be read
+    back without one."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update('jax_enable_compilation_cache', before)
+
+
+@pytest.mark.parametrize('causal,B,T', [(True, 8, 1024), (False, 56, 512)],
+                         ids=['gpt2_t1024_causal', 'bert_t512'])
+def test_mosaic_compiles_the_kernels_for_a_described_v5e(one_chip, causal,
+                                                         B, T):
+    """Forward, dq and dk/dv at the cells' shapes and default blocks
+    (GPT-2: BH=96, T=1024, D=64, bf16, dropout 0.1): a guard or an index
+    map Mosaic refuses fails here, at no chip time."""
+    H, D = 12, 64
+    x = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one_chip)
+    km = jax.ShapeDtypeStruct((B, T), jnp.float32, sharding=one_chip)
+    seed = jax.ShapeDtypeStruct((), jnp.uint32, sharding=one_chip)
+
+    def loss(q, k, v, km, seed):
+        out = pa.flash_attention(q, k, v, key_mask=km, causal=causal,
+                                 dropout_p=0.1, dropout_seed=seed,
+                                 interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, km, seed).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ('mxtpu_flash_fwd', 'mxtpu_flash_bwd_dq',
+                 'mxtpu_flash_bwd_dkv'):
+        assert name in text
