@@ -464,7 +464,7 @@ class Trainer:
         re-runs on the next fused-cache rebuild (set_states_bytes clears
         the cache)."""
         from jax.sharding import NamedSharding, PartitionSpec
-        from ..parallel.step import compose_zero_spec
+        from ..parallel.layout import compose_zero_spec
         from .. import config as _config
         stage = int(_config.get('MXTPU_ZERO') or 0)
         mesh = None

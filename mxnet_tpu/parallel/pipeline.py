@@ -189,7 +189,7 @@ class PipelineTrainStep:
     def __init__(self, params, embed_fn, stage_fn, head_fn, loss_fn,
                  optimizer='sgd', optimizer_params=None, mesh=None,
                  pp_axis='pp'):
-        from .step import _OPTS
+        from .update import _OPTS
         from .mesh import default_mesh
         if optimizer not in _OPTS:
             raise ValueError(f"PipelineTrainStep supports {sorted(_OPTS)}")

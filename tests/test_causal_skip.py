@@ -254,10 +254,10 @@ def test_the_non_causal_build_is_what_it_was(kernel):
 # Mosaic, without the chip ----------------------------------------------------
 
 @pytest.fixture(scope='module')
-def one_chip():
-    """A described v5e chip to compile for. The compile cache is off
-    around it: an executable compiled for a described chip cannot be read
-    back without one."""
+def four_chips():
+    """The devices of a described v5e:2x2 to compile for. The compile
+    cache is off around them: an executable compiled for a described chip
+    cannot be read back without one."""
     from jax.experimental import topologies
     try:
         topo = topologies.get_topology_desc(platform='tpu',
@@ -266,8 +266,13 @@ def one_chip():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     before = jax.config.jax_enable_compilation_cache
     jax.config.update('jax_enable_compilation_cache', False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update('jax_enable_compilation_cache', before)
+
+
+@pytest.fixture(scope='module')
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
 
 
 @pytest.mark.parametrize('causal,B,T', [(True, 8, 1024), (False, 56, 512)],
@@ -293,3 +298,33 @@ def test_mosaic_compiles_the_kernels_for_a_described_v5e(one_chip, causal,
     for name in ('mxtpu_flash_fwd', 'mxtpu_flash_bwd_dq',
                  'mxtpu_flash_bwd_dkv'):
         assert name in text
+
+
+def test_a_train_step_lowers_and_plans_for_a_described_mesh(four_chips):
+    """``ShardedTrainStep.lower()`` over shapes, for a dp=4 mesh of
+    devices that are described and not attached: how a cell's batch is
+    sized before a chip is taken (PERF.md 7). No array can be placed on
+    such a device, so this is the layout's purity as well."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.parallel import ShardedTrainStep, make_mesh
+    mx.random.seed(3)
+    net = nn.HybridSequential(prefix='mlp_')
+    with net.name_scope():
+        net.add(nn.Dense(256, activation='relu', in_units=128))
+        net.add(nn.Dense(128, in_units=256))
+    net.initialize()
+    net.cast('bfloat16')
+    step = ShardedTrainStep(
+        net, lambda out, label: (out.astype('float32') - label) ** 2,
+        'adamw', {'learning_rate': 1e-3},
+        mesh=make_mesh((4,), ('dp',), devices=four_chips))
+    lowered = step.lower(jax.ShapeDtypeStruct((64, 128), jnp.bfloat16),
+                         jax.ShapeDtypeStruct((64, 128), jnp.float32))
+    compiled = lowered.compile()
+    # ZeRO-1 over the four chips: masters and moments a quarter each
+    plan = compiled.memory_analysis()
+    masters_and_moments = 3 * 4 * (128 * 256 + 256 + 256 * 128 + 128) // 4
+    assert plan.argument_size_in_bytes > masters_and_moments
+    assert plan.argument_size_in_bytes < 2 * masters_and_moments
+    assert 'reduce-scatter' in compiled.as_text()

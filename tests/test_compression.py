@@ -255,7 +255,7 @@ def test_hierarchy_parity_uncompressed(H):
     rb = step_flat_off.opt_state_bytes_per_device()
     zb = step_h.opt_state_bytes_per_device()
     assert zb <= rb / h * 1.3 + 4096, (zb, rb, h)
-    assert step_h._shard_size == h and step_h._cross_size == H
+    assert step_h._axes.shard_size == h and step_h._axes.cross_size == H
     assert tuple(step_h.mesh.axis_names) == ('dph', 'dpi')
 
 

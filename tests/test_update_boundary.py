@@ -107,7 +107,7 @@ def test_one_barrier_per_dense_gradient(devices, dtype, kwargs):
     assert not [s for s in found
                 if scopes.UPDATE in s or scopes.FWD_BWD in s], found
     # and it survives lowering: the program XLA is handed has them
-    text = step._compiled.lower(*step._cost_args).as_text()
+    text = step.lower().as_text()
     assert text.count('optimization_barrier') >= 4
 
 

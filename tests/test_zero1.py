@@ -16,7 +16,7 @@ from mxnet_tpu import nd, gluon, autograd, telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.parallel import make_mesh, ShardedTrainStep
-from mxnet_tpu.parallel.step import compose_zero_spec
+from mxnet_tpu.parallel import compose_zero_spec
 
 
 def _data(n=64, din=16, classes=8, seed=0):

@@ -21,7 +21,7 @@ from mxnet_tpu.gluon import nn
 from mxnet_tpu.parallel import make_mesh, ShardedTrainStep
 from mxnet_tpu.parallel.collectives import (group_params_by_layer,
                                             ordered_barrier)
-from mxnet_tpu.parallel.step import compose_zero_spec, zero3_layout
+from mxnet_tpu.parallel import compose_zero_spec, zero3_layout
 
 
 def _data(n=64, din=16, classes=8, seed=0):

@@ -414,6 +414,8 @@ HOT_PATH_ROOTS = [
 # formatting — where a host read is fine)
 HOT_PATH_FILES = (
     'parallel/step.py',
+    'parallel/layout.py',       # the step's placement helpers (put_batch)
+    'parallel/exchange.py',     # record_wire runs in every dispatch
     'parallel/collectives.py',
     'gluon/trainer.py',
     'gluon/data/dataloader.py',

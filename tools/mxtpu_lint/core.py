@@ -212,10 +212,10 @@ _UBIQUITOUS_METHODS = frozenset({
     'open', 'close', 'send', 'sendall', 'recv', 'accept', 'connect',
     'get', 'put', 'pop', 'append', 'extend', 'add', 'remove', 'clear',
     'update', 'copy', 'keys', 'values', 'items', 'join', 'split',
-    'strip', 'encode', 'decode', 'format', 'count', 'index', 'sort',
-    'reverse', 'setdefault', 'acquire', 'release', 'wait', 'notify',
-    'set', 'start', 'cancel', 'fileno', 'settimeout', 'bind', 'listen',
-    'run', 'next',
+    'strip', 'lower', 'encode', 'decode', 'format', 'count', 'index',
+    'sort', 'reverse', 'setdefault', 'acquire', 'release', 'wait',
+    'notify', 'set', 'start', 'cancel', 'fileno', 'settimeout', 'bind',
+    'listen', 'run', 'next',
 })
 
 
