@@ -3,7 +3,7 @@
 Ref: the GluonNLP BERT-base recipe named in BASELINE.json; attention kernels
 correspond to the reference's interleaved_matmul selfatt ops
 (src/operator/contrib/transformer.cc:650-828), realised here as the fused
-multi_head_attention op (XLA/Pallas flash path).
+self_attention op over the fused qkv projection (XLA/Pallas flash path).
 
 bf16-friendly: activations run in the block dtype; layernorm statistics in
 fp32 (see ops/nn.py layer_norm).
@@ -50,8 +50,7 @@ class BertSelfAttention(HybridBlock):
         qkv = self.qkv(x)
         # no block of its own: a plain scope names it in a device trace
         with jax.named_scope(_scopes.ATTN_CORE):
-            q, k, v = qkv.split(3, axis=-1)
-            out = _invoke(attn_ops.multi_head_attention, q, k, v, mask,
+            out = _invoke(attn_ops.self_attention, qkv, mask,
                           num_heads=self._heads,
                           dropout_p=self._attn_dropout)
         return self.dropout(self.proj(out))
@@ -289,9 +288,7 @@ def bert_pipeline_funcs(model: 'BertForPretraining', n_stages,
 
     def one_layer(x, lp):
         qkv = x @ lp['qkv_w'].T + lp['qkv_b']
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        attn = attn_ops.multi_head_attention(q, k, v, num_heads=heads,
-                                             dropout_p=0.0)
+        attn = attn_ops.self_attention(qkv, num_heads=heads, dropout_p=0.0)
         attn = attn @ lp['proj_w'].T + lp['proj_b']
         x = F.layer_norm(x + attn, lp['ln1_g'], lp['ln1_b'], eps=eps)
         h = F.dense_gelu(x, lp['ffn1_w'], lp['ffn1_b'])

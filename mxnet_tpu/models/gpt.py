@@ -1,7 +1,7 @@
 """GPT-style decoder-only causal language model.
 
 The autoregressive counterpart of the BERT flagship: pre-norm transformer
-decoder blocks over the fused `multi_head_attention` op with
+decoder blocks over the fused `self_attention` op with
 `causal=True`, which routes through the Pallas flash kernel's causal path
 on TPU (ops/pallas_attention.py) — no (T, T) mask tensor is ever
 materialised. Weight-tied output head (standard GPT recipe).
@@ -52,8 +52,7 @@ class GPTBlock(HybridBlock):
         qkv = self.qkv(h)
         # no block of its own: a plain scope names it in a device trace
         with jax.named_scope(_scopes.ATTN_CORE):
-            q, k, v = qkv.split(3, axis=-1)
-            attn = _invoke(attn_ops.multi_head_attention, q, k, v, None,
+            attn = _invoke(attn_ops.self_attention, qkv, None,
                            num_heads=self._heads,
                            dropout_p=self._attn_dropout, causal=True)
         x = x + self.dropout(self.proj(attn))

@@ -173,32 +173,39 @@ def _axes_size(mesh, axes):
     return math.prod(mesh.shape[a] for a in axes)
 
 
-def _flash_route(q, k, v, kpm, causal, dropout_p, seed):
-    """The Pallas route: the flash kernel, mapped over the mesh when a
-    ``mesh_placement`` is active."""
+def _flash_route(arrays, H, D, kpm, causal, dropout_p, seed):
+    """The Pallas route: the flash kernels on the model's own arrays --
+    (q, k, v), each (N, T, H*D), or (qkv,), their fused projection --
+    with no transpose, mapped over the mesh when a ``mesh_placement`` is
+    active: the batch axes shard dim 0, the head axes the columns. A
+    fused array's columns are not one range a head shard (a third of
+    each of q, k and v), so with head axes it is split first."""
     from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
-    from .pallas_attention import flash_attention
+    from .pallas_attention import _lane_block, flash_mha
     mesh, batch_axes, head_axes = \
         _mesh_placement[-1] if _mesh_placement else (None, (), ())
-    N, H = q.shape[0], q.shape[1]
+    N = arrays[0].shape[0]
     n_b = _axes_size(mesh, batch_axes)
     if N % n_b:
         raise MXNetError(
             f"multi_head_attention: batch {N} does not divide over mesh "
             f"axes {batch_axes} (size {n_b}), so the flash kernel cannot "
             f"be mapped per chip")
-    if H % _axes_size(mesh, head_axes):
-        head_axes = ()      # heads stay whole on every chip of those axes
     n_h = _axes_size(mesh, head_axes)
+    if H % n_h or _lane_block(H // n_h * D, D) is None:
+        head_axes = ()      # heads stay whole on every chip of those axes
+        n_h = 1
     if n_b * n_h == 1:
-        return flash_attention(q, k, v, key_mask=kpm, causal=causal,
-                               dropout_p=dropout_p, dropout_seed=seed)
+        return flash_mha(arrays, H, key_mask=kpm, causal=causal,
+                         dropout_p=dropout_p, dropout_seed=seed)
+    if n_h > 1 and len(arrays) == 1:
+        arrays = tuple(jnp.split(arrays[0], 3, axis=-1))
     n_loc, h_loc = N // n_b, H // n_h
 
-    def local(q_, k_, v_, kpm_, seed_):
-        # global id of this shard's first (batch, head) slice, so the
-        # in-kernel dropout draws the bits the unsharded call would
+    def local(kpm_, seed_, *arrays_):
+        # global id of this shard's first (row, head), so the in-kernel
+        # dropout draws the bits the unsharded call would
         base = jnp.zeros((), jnp.uint32)
         if batch_axes:
             base += lax.axis_index(batch_axes).astype(jnp.uint32) \
@@ -206,16 +213,16 @@ def _flash_route(q, k, v, kpm, causal, dropout_p, seed):
         if head_axes:
             base += lax.axis_index(head_axes).astype(jnp.uint32) \
                 * jnp.uint32(h_loc)
-        return flash_attention(q_, k_, v_, key_mask=kpm_, causal=causal,
-                               dropout_p=dropout_p, dropout_seed=seed_,
-                               bh_base=base,
-                               bh_split=(h_loc, H) if n_h > 1 else None)
+        return flash_mha(arrays_, h_loc, key_mask=kpm_, causal=causal,
+                         dropout_p=dropout_p, dropout_seed=seed_,
+                         bh_base=base,
+                         bh_split=(h_loc, H) if n_h > 1 else None)
 
     b_spec = batch_axes or None
-    qkv = P(b_spec, head_axes or None, None, None)
+    ntc = P(b_spec, None, head_axes or None)
     return shard_map(local, mesh=mesh,
-                     in_specs=(qkv, qkv, qkv, P(b_spec, None), P()),
-                     out_specs=qkv, check_vma=False)(q, k, v, kpm, seed)
+                     in_specs=(P(b_spec, None), P()) + (ntc,) * len(arrays),
+                     out_specs=ntc, check_vma=False)(kpm, seed, *arrays)
 
 
 @_reg
@@ -237,6 +244,12 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
     ``route_counts``; a kernel that then fails to build or compile
     raises. True forces the kernel, False forces XLA.
 
+    The Pallas route hands the (N, T, H*D) arrays to the kernels as they
+    are and returns the kernels' (N, T, H*D) result: their blocks address
+    a head's columns in place, and nothing is transposed. The XLA and
+    ring routes compute on (N, H, T, D) and pay the transposes there and
+    back, under ``attn_layout``.
+
     dropout_p: attention-probability dropout, applied after softmax (the
     standard transformer recipe), active in autograd training mode (same
     gate as the dropout op). The PRNG key comes from the framework key
@@ -246,32 +259,55 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
     materialised even in training; the flagship BERT config (dropout=0.1)
     runs the flash kernel.
     """
-    N, Tq, tot = query.shape
-    H = num_heads
+    return _attend((query, key, value), mask, num_heads, dropout_p, causal,
+                   use_pallas, dropout_key)
+
+
+@_reg
+def self_attention(qkv, mask=None, num_heads=1, dropout_p=0.0, causal=False,
+                   use_pallas='auto', dropout_key=None):
+    """Self-attention over the fused (N, T, 3*H*D) projection: by
+    definition ``multi_head_attention(*split(qkv, 3, -1), ...)``, and
+    exactly that on the XLA and ring routes. The Pallas route hands the
+    kernels the one array three times with column offsets, so the three
+    slices are never materialised; the cotangent of ``qkv`` is dq, dk and
+    dv side by side."""
+    return _attend((qkv,), mask, num_heads, dropout_p, causal, use_pallas,
+                   dropout_key)
+
+
+def _attend(arrays, mask, H, dropout_p, causal, use_pallas, dropout_key):
+    """The routes of :func:`multi_head_attention` (``arrays`` = (q, k,
+    v)) and :func:`self_attention` ((qkv,))."""
+    N, Tq = arrays[0].shape[:2]
+    Tk = arrays[-1].shape[1]
+    tot = arrays[-1].shape[2] // (3 if len(arrays) == 1 else 1)
     D = tot // H
 
+    def split_heads():
+        # (N, T, H*D) to (N, H, T, D) and, with merge_heads, back: the
+        # layout copies of the routes that compute head-major, a line of
+        # their own in a trace
+        q, k, v = arrays if len(arrays) == 3 \
+            else jnp.split(arrays[0], 3, axis=-1)
+        with jax.named_scope(_scopes.ATTN_LAYOUT):
+            return [x.reshape(N, x.shape[1], H, D).transpose(0, 2, 1, 3)
+                    for x in (q, k, v)]
+
     def merge_heads(out):
-        # (N, H, T, D) back to (N, T, H*D): with the split below, the
-        # layout copies round the kernel, a line of their own in a trace
         with jax.named_scope(_scopes.ATTN_LAYOUT):
             return out.transpose(0, 2, 1, 3).reshape(N, Tq, tot)
-
-    with jax.named_scope(_scopes.ATTN_LAYOUT):
-        q = query.reshape(N, Tq, H, D).transpose(0, 2, 1, 3)
-        k = key.reshape(N, key.shape[1], H, D).transpose(0, 2, 1, 3)
-        v = value.reshape(N, value.shape[1], H, D).transpose(0, 2, 1, 3)
 
     apply_dropout = dropout_p > 0.0 and (dropout_key is not None
                                          or _flags.is_training)
 
     # key-padding-mask normalization shared by the ring and Pallas
     # routes: (N, Tk), boolean truthy-keep (floating stays additive)
-    kpm = _as_key_padding_mask(mask, N, k.shape[2])
+    kpm = _as_key_padding_mask(mask, N, Tk)
     if kpm is not None and not jnp.issubdtype(kpm.dtype, jnp.floating):
         kpm = kpm.astype(jnp.bool_)
 
     if _seq_parallel:
-        Tk = k.shape[2]
         # dropout no longer blocks the ring route: the ring kernel
         # regenerates the keep mask in-kernel from global coordinates
         # (same counter-based PRNG as the Pallas flash kernel), so the
@@ -289,6 +325,7 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
                 ring_kwargs = dict(
                     dropout_p=dropout_p,
                     dropout_seed=jax.random.bits(key_, (1,), jnp.uint32))
+            q, k, v = split_heads()
             out = ring_attention(q, k, v, sp_mesh, sp_axis=sp_axis,
                                  causal=causal, key_mask=kpm,
                                  **ring_kwargs)
@@ -309,7 +346,7 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
         from .pallas_attention import flash_legal, pallas_available
         use_pallas = pallas_available() \
             and (mask is None or kpm is not None) \
-            and flash_legal(N * H, Tq, k.shape[2], D, q.dtype)
+            and flash_legal(N * H, Tq, Tk, D, arrays[0].dtype, num_heads=H)
     if use_pallas:
         if mask is not None and kpm is None:
             raise MXNetError(
@@ -321,17 +358,17 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
             key_ = dropout_key if dropout_key is not None \
                 else _random.next_key()
             seed = jax.random.bits(key_, (1, 1), jnp.uint32)
-        out = _flash_route(q, k, v, kpm, causal,
+        out = _flash_route(arrays, H, D, kpm, causal,
                            dropout_p if apply_dropout else 0.0, seed)
         route_counts['pallas'] += 1
-        return merge_heads(out)
+        return out
 
     route_counts['xla'] += 1
+    q, k, v = split_heads()
     scale = 1.0 / math.sqrt(D)
     scores = jnp.einsum('nhqd,nhkd->nhqk', q * scale, k,
                         preferred_element_type=jnp.float32)
     if causal:
-        Tk = k.shape[2]
         cmask = jnp.tril(jnp.ones((Tq, Tk), bool))
         scores = jnp.where(cmask, scores, -1e30)
     if mask is not None:

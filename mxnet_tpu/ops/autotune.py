@@ -104,7 +104,7 @@ def tile_legal(array_shape, block_shape, dtype):
     Round 3's failure shape is the canonical counterexample: a 2-D
     key-mask block (1, 512) over a (BH, Tk) array — 1 is neither a
     multiple of 8 nor equal to BH, so Mosaic refuses to lower it (the
-    fix rides the mask as (BH, 1, Tk) with (G, 1, bk) blocks, whose
+    fix rides the mask as (N, 1, Tk) with (rows, 1, bk) blocks, whose
     trailing-two dims (1, bk) match the array's (1, Tk) leading dim
     exactly)."""
     if len(array_shape) != len(block_shape):
@@ -132,25 +132,33 @@ def _pad_up(n, b):
 
 def fa_block_layouts(BH, Tq, Tk, D, kind, G, bq, bk):
     """(name, array_shape, block_shape) for every operand block the
-    flash kernels of ``kind`` would instantiate at (G, bq, bk) — the
-    exact layouts ``_fa_forward``/``_fa_backward`` build, including the
-    bq/bk padding of the sequence dims."""
+    flash kernels of ``kind`` would instantiate at (G, bq, bk), including
+    the bq/bk padding of the sequence dims. The kernels address the
+    model's (N, T, H*D) arrays in lane blocks of W columns
+    (``pallas_attention._lane_block``: 128 where D divides it, W // D
+    heads side by side, else D), G heads a grid step being G // (W // D)
+    batch rows of one lane block. N and H are not in the tuning key, so
+    the arrays are written here one lane block wide: the trailing two
+    dims, which the tile rule reads, are the kernels' own."""
     tq, tk = _pad_up(Tq, bq), _pad_up(Tk, bk)
+    W = _LANE if _LANE % D == 0 else D
+    hb = W // D
+    rows, Gn = max(1, BH // hb), max(1, G // hb)
     layouts = [
-        ('q', (BH, tq, D), (G, bq, D)),
-        ('k', (BH, tk, D), (G, bk, D)),
-        ('v', (BH, tk, D), (G, bk, D)),
-        ('kmask', (BH, 1, tk), (G, 1, bk)),
-        ('lse', (BH, tq, 1), (G, bq, 1)),
+        ('q', (rows, tq, W), (Gn, bq, W)),
+        ('k', (rows, tk, W), (Gn, bk, W)),
+        ('v', (rows, tk, W), (Gn, bk, W)),
+        ('kmask', (rows, 1, tk), (Gn, 1, bk)),
+        ('lse', (rows, hb, tq, 1), (Gn, hb, bq, 1)),
     ]
     if kind == 'fwd':
-        layouts.append(('out', (BH, tq, D), (G, bq, D)))
+        layouts.append(('out', (rows, tq, W), (Gn, bq, W)))
     else:
-        layouts += [('do', (BH, tq, D), (G, bq, D)),
-                    ('delta', (BH, tq, 1), (G, bq, 1)),
-                    ('dq', (BH, tq, D), (G, bq, D)),
-                    ('dk', (BH, tk, D), (G, bk, D)),
-                    ('dv', (BH, tk, D), (G, bk, D))]
+        layouts += [('do', (rows, tq, W), (Gn, bq, W)),
+                    ('delta', (rows, hb, tq, 1), (Gn, hb, bq, 1)),
+                    ('dq', (rows, tq, W), (Gn, bq, W)),
+                    ('dk', (rows, tk, W), (Gn, bk, W)),
+                    ('dv', (rows, tk, W), (Gn, bk, W))]
     return layouts
 
 

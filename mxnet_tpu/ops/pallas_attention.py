@@ -1,52 +1,67 @@
 """Pallas flash-attention kernels for TPU (forward AND backward).
 
-The fused MHA op (ops/attention.py multi_head_attention) routes here. This
-is the TPU-native realisation of the reference's interleaved_matmul
-attention kernels (ref: src/operator/contrib/transformer.cc:650-828): one
-hand-written kernel instead of two batched-GEMM ops, with the T×T score
-matrix living only in VMEM.
+The fused MHA ops (ops/attention.py multi_head_attention, self_attention)
+route here. This is the TPU-native realisation of the reference's
+interleaved_matmul attention kernels (ref:
+src/operator/contrib/transformer.cc:650-828): one hand-written kernel
+instead of two batched-GEMM ops, with the T×T score matrix living only in
+VMEM.
 
-Forward: grid (B*H/G, Tq/BQ, Tk/BK) — each invocation processes G
-batch·head slices (per-invocation overhead on the TPU is tens of
-microseconds, so tiny per-head grids are dispatch-bound; G amortises it).
-Scratch (VMEM) carries the online-softmax state (running max m, running
-sum l, f32 accumulator) across k-blocks; the final k-block normalises and
-writes the output block plus the logsumexp (saved for the backward pass).
+Operands and results are the model's own arrays: q, k, v and o, dO, dq,
+dk, dv are (N, T, C = H*D), in the model's dtype, and q, k and v may be
+the three thirds of one fused (N, T, 3C) projection, each addressed at a
+static column offset. A block is (Gn, bq | bk, W): Gn batch rows, a
+sequence block, one *lane block* of W columns that holds W // D whole
+heads (:func:`_lane_block`: two 64-wide heads to 128 lanes). Inside a
+grid step a head is picked out of its lane block by a lane mask on the
+(·, W) operands (:func:`_own`): a contraction over 128 lanes of which 64
+are zero costs the 128-deep MXU what one over 64 does. Nothing is
+transposed, split or cast round the calls.
 
-Backward: two Pallas kernels — dq (grid (BH/G, Tq/BQ, Tk/BK), accumulating
-over k-blocks) and dk/dv (grid (BH/G, Tk/BK, Tq/BQ), accumulating over
-q-blocks) — both recompute the probability block from the saved LSE
-(flash-attention backward recurrence), so live memory stays O(T).
+Forward: grid (N/Gn, C/W, Tq/BQ, Tk/BK) — each invocation processes
+G = Gn·(W // D) batch·head slices (per-invocation overhead on the TPU is
+tens of microseconds, so tiny per-head grids are dispatch-bound; G
+amortises it). Scratch (VMEM) carries the online-softmax state (running
+max m, running sum l, f32 accumulator) across k-blocks; the final k-block
+normalises and writes the output block plus the logsumexp (saved for the
+backward pass).
+
+Backward: two Pallas kernels — dq (grid (N/Gn, C/W, Tq/BQ, Tk/BK),
+accumulating over k-blocks) and dk/dv (grid (N/Gn, C/W, Tk/BK, Tq/BQ),
+accumulating over q-blocks) — both recompute the probability block from
+the saved LSE (flash-attention backward recurrence), so live memory stays
+O(T); each rounds its float32 accumulator to the operands' dtype once, as
+it writes.
 
 Without ``causal`` every (q-block, k-block) cell of those grids is
-computed. With it the grid is (B*H/G, listed cells): the cells that hold
-a score at or under the diagonal (:func:`_cell_live`), row by row for the
-forward and dq, column by column for dk/dv, from a small table the
+computed. With it the grid is (N/Gn, C/W, listed cells): the cells that
+hold a score at or under the diagonal (:func:`_cell_live`), row by row for
+the forward and dq, column by column for dk/dv, from a small table the
 kernels and their index maps read as a scalar-prefetch operand
 (:func:`_causal_cell_table`). A cell wholly above the diagonal is no grid
 step at all: no matmul, no hash, no exp, no copy. Such a cell used to add
 exp(-1e30 - m) = 0 to every accumulator, and the listed cells keep their
 order, so no result changes by a bit. The cells the diagonal crosses
-still mask element by element (:func:`_masked_scores`). All of it hangs on
-the static ``causal`` flag: the non-causal build is the kernels, grids
-and index maps it was before. Each causal build counts its cells in
-``causal_cells``.
+still mask element by element (:func:`_cell`'s ``scores``). All of it hangs on
+the static ``causal`` flag. Each causal build counts its cells in
+``causal_cells``, and every build its addressing in ``head_blocks``.
 
 Attention dropout runs INSIDE the kernels: the keep mask is a
 counter-based hash (murmur3 finalizer) of the global (batch·head, q, k)
 element coordinates mixed with a per-call seed, so the forward and both
 backward kernels regenerate bit-identical masks with no T×T tensor ever
 materialised, and the same bits fall out in Mosaic and interpreter modes.
+The batch·head id is n·H + h (:func:`_cell`), whatever the blocks.
 Softmax statistics (m, l) are computed on the UNdropped probabilities —
 dropout scales only the value accumulation — matching the standard
 softmax→dropout→matmul recipe.
 
 Mosaic layout constraints honoured throughout: every block's trailing two
 dims are (multiple-of-8, multiple-of-128) or equal to the array dims —
-the key-mask rides as (BH, 1, Tk) with (G, 1, bk) blocks and the LSE as
-(BH, Tq, 1) with (G, bq, 1) blocks (a (1, bk) 2-D mask block is refused).
-The two scalars the kernels read (dropout seed, global batch·head base)
-ride in SMEM.
+the key-mask rides as (N, 1, Tk) with (Gn, 1, bk) blocks, one row a
+batch row, and the LSE as (N, H, Tq, 1) with (Gn, W // D, bq, 1) blocks
+(a (1, bk) 2-D mask block is refused). The two scalars the kernels read
+(dropout seed, global batch·head base) ride in SMEM.
 
 Kernel mode is explicit: ``interpret=True`` runs the identical kernels
 through the Pallas interpreter (CPU tests exercise the real kernel code),
@@ -68,16 +83,18 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import scopes as _scopes
 
 _NEG_INF = -1e30
+_LANES = 128
 
-# every grid here is (batch·head groups, outer seq blocks, inner seq
-# blocks) with the scratch accumulators carried over the innermost axis
+# every grid here is (batch-row groups, lane blocks, outer seq blocks,
+# inner seq blocks) with the scratch accumulators carried over the
+# innermost axis
 _COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=('parallel', 'parallel', 'arbitrary'))
+    dimension_semantics=('parallel', 'parallel', 'parallel', 'arbitrary'))
 
-# the causal grid is (batch·head groups, listed cells), the accumulators
-# carried over the cells of one row or column
+# the causal grid is (batch-row groups, lane blocks, listed cells), the
+# accumulators carried over the cells of one row or column
 _CAUSAL_COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=('parallel', 'arbitrary'))
+    dimension_semantics=('parallel', 'parallel', 'arbitrary'))
 
 # trace-time telemetry of the causal skip, beside ops.attention.route_counts
 # and autotune.decisions(): {(kind, live cells, cells of the full plane):
@@ -85,10 +102,42 @@ _CAUSAL_COMPILER_PARAMS = pltpu.CompilerParams(
 # Static numbers; a non-causal build records nothing.
 causal_cells = {}
 
+# and of the addressing: {(kind, H, D, heads per lane block, fused):
+# kernel builds}, ``fused`` being whether q, k and v are column ranges of
+# one (N, T, 3*H*D) array. Static numbers, counted at build.
+head_blocks = {}
+
+
+def _lane_block(C, D):
+    """(W, hb): the columns one block of the kernels spans in an
+    (N, T, C) array of D-wide heads, and the whole heads they hold. 128
+    where D divides 128 (two heads at D = 64), D where D is a multiple
+    of 128, the whole C where C < 128. None where the columns do not come
+    apart into such blocks: the XLA route's shapes."""
+    if C < _LANES:
+        W = C
+    elif _LANES % D == 0:
+        W = _LANES
+    elif D % _LANES == 0:
+        W = D
+    else:
+        return None
+    return (W, W // D) if C % W == 0 else None
+
+
+def _rows_per_step(N, G, hb):
+    """Batch rows of one grid step: ``G`` heads a step (:func:`_block_sizes`)
+    are G // hb rows of one lane block, at least one, clamped to a divisor
+    of N as ``autotune.resolve`` clamps G to one of N*H."""
+    Gn = max(1, min(G // hb, N))
+    while N % Gn:
+        Gn -= 1
+    return Gn
+
 
 def _cell_live(qb, kb, bq, bk):
     """Does cell (q-block qb, k-block kb) hold a score at or under the
-    causal diagonal (top-left aligned, as :func:`_masked_scores` masks)?
+    causal diagonal (top-left aligned, as :func:`_cell`'s scores mask)?
     True iff its last query row sees its first key. A window (ROADMAP R5)
     is a second inequality here."""
     return qb * bq + (bq - 1) >= kb * bk
@@ -120,37 +169,65 @@ def _causal_cell_table(kind, nq, nk, bq, bk, by_row):
 def _step_cell(cells_ref, q_axis):
     """(q-block, k-block, is-first, is-last) of this grid step, the last
     two as thunks evaluated where the kernel asks. Without a table: the
-    full grid, q-blocks on grid axis ``q_axis`` (1: forward and dq, 2:
-    dk/dv) and the accumulators carried over axis 2. With one: the
+    full grid, q-blocks on grid axis ``q_axis`` (2: forward and dq, 3:
+    dk/dv) and the accumulators carried over axis 3. With one: the
     causal grid's listed cell (:func:`_causal_cell_table`)."""
     if cells_ref is None:
-        outer, inner = pl.program_id(1), pl.program_id(2)
-        n_inner = pl.num_programs(2)
-        qb, kb = (outer, inner) if q_axis == 1 else (inner, outer)
+        outer, inner = pl.program_id(2), pl.program_id(3)
+        n_inner = pl.num_programs(3)
+        qb, kb = (outer, inner) if q_axis == 2 else (inner, outer)
         return qb, kb, lambda: inner == 0, lambda: inner == n_inner - 1
-    cell = pl.program_id(1)
+    cell = pl.program_id(2)
     return (cells_ref[0, cell], cells_ref[1, cell],
             lambda: cells_ref[2, cell] == 1, lambda: cells_ref[3, cell] == 1)
 
 
-def _causal_specs(G, bq, bk, D):
-    """BlockSpecs over the causal grid (head group b, listed cell c) with
-    the cell table as scalar-prefetch operand, by role: q-side block,
-    q-side column (lse, delta), k-side block, key mask."""
-    return (pl.BlockSpec((G, bq, D), lambda b, c, cells: (b, cells[0, c], 0)),
-            pl.BlockSpec((G, bq, 1), lambda b, c, cells: (b, cells[0, c], 0)),
-            pl.BlockSpec((G, bk, D), lambda b, c, cells: (b, cells[1, c], 0)),
-            pl.BlockSpec((G, 1, bk), lambda b, c, cells: (b, 0, cells[1, c])))
+def _block_specs(Gn, hb, bq, bk, W, causal, q_axis=2):
+    """The BlockSpecs of one call, by role, as (seq, col, mask):
+    ``seq(side, off)`` a (Gn, bq | bk, W) block of an (N, T, columns)
+    array on the 'q' or the 'k' side, ``off`` lane blocks into the
+    columns (where q, k and v are ranges of one array); ``col`` the
+    q-side (Gn, hb, bq, 1) block of an (N, H, Tq, 1) column (lse,
+    delta); ``mask`` the (Gn, 1, bk) block of the (N, 1, Tk) key mask.
+    The grid is (row group b, lane block l, then the full plane with
+    q-blocks on ``q_axis``, or the listed cell with the cell table as
+    scalar-prefetch operand, ``causal``). No index map computes
+    anything, but for an ``off``."""
+    if causal:
+        def qi(c, cells): return cells[0, c]
+        def ki(c, cells): return cells[1, c]
+    elif q_axis == 2:
+        def qi(i, j): return i
+        def ki(i, j): return j
+    else:
+        def qi(j, i): return i
+        def ki(j, i): return j
+
+    def seq(side, off=0):
+        rows, at = (bq, qi) if side == 'q' else (bk, ki)
+        if off:
+            return pl.BlockSpec((Gn, rows, W),
+                                lambda b, l, *s: (b, at(*s), l + off))
+        return pl.BlockSpec((Gn, rows, W), lambda b, l, *s: (b, at(*s), l))
+    col = pl.BlockSpec((Gn, hb, bq, 1), lambda b, l, *s: (b, l, qi(*s), 0))
+    mask = pl.BlockSpec((Gn, 1, bk), lambda b, l, *s: (b, 0, ki(*s)))
+    return seq, col, mask
 
 
-def _causal_call(kernel, cells, groups, in_specs, out_specs, scratch_shapes,
-                 **call):
-    """``pallas_call`` of ``kernel`` over the grid (groups, listed cells),
-    already applied to the cell table."""
+def _call(kernel, cells, grid, in_specs, out_specs, scratch_shapes, **call):
+    """``pallas_call`` of ``kernel``: over ``grid`` as it is (``cells``
+    None: it ends in the full plane's two axes), or over ``grid`` + the
+    listed cells of a causal build, already applied to the cell table."""
+    if cells is None:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
+            compiler_params=_COMPILER_PARAMS, **call)
+
     def with_table(cells_ref, *refs):
         return kernel(*refs, cells_ref=cells_ref)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(groups, cells.shape[1]),
+        num_scalar_prefetch=1, grid=grid + (cells.shape[1],),
         in_specs=in_specs, out_specs=out_specs,
         scratch_shapes=scratch_shapes)
     return functools.partial(
@@ -173,18 +250,21 @@ def default_interpret() -> bool:
 
 
 def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd'):
-    """(G, bq, bk): head-group size and MXU/VPU-aligned seq blocks.
-    Sublane minimum is 8 (f32) / 16 (bf16); lanes are 128. G amortises
-    the per-invocation kernel overhead over several batch·head slices.
+    """(G, bq, bk): heads a grid step and MXU/VPU-aligned seq blocks, for
+    BH = N*H batch·head slices. Sublane minimum is 8 (f32) / 16 (bf16);
+    lanes are 128. G amortises the per-invocation kernel overhead over
+    several batch·head slices; the kernels take them as G // hb batch
+    rows of one lane block of hb heads (:func:`_rows_per_step`).
 
     kind='bwd' sizes the backward kernels, whose per-cell stack holds
     ~6 live (bq, bk) f32 temporaries (s, p, dp, ds, keep, pv) vs the
     forward's ~3, so backward defaults to 256-wide blocks where the
     forward takes 512. Established on a v5e with libtpu 0.0.34 (chip
-    run, PR 23): these defaults — forward (4, 512, 512), backward
-    (4, 256, 256) — compile under Mosaic's default scoped-VMEM limit,
-    with no ``vmem_limit_bytes``, at BERT-base (BH=384, T=512, D=64,
-    bf16, padding mask, dropout) and at GPT-2's causal T=1024 (BH=96).
+    run, PR 23; again with two heads to a lane block, PR 33): these
+    defaults — forward (4, 512, 512), backward (4, 256, 256) — compile
+    under Mosaic's default scoped-VMEM limit, with no
+    ``vmem_limit_bytes``, at BERT-base (BH=672, T=512, D=64, bf16,
+    padding mask, dropout) and at GPT-2's causal T=1024 (BH=288).
     Wider backward blocks on this installation: not measured.
 
     The defaults computed here are only the LAST rung of the ISSUE 18
@@ -212,48 +292,25 @@ def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd'):
 # portable counter-based dropout bits
 # ---------------------------------------------------------------------------
 
-def _global_bh(meta_ref, local_bh, bh_split):
-    """Global batch·head id (uint32) of this call's slice ``local_bh``.
-
-    A call that holds the whole (B, H) problem numbers its slices
-    0..BH-1 and ``meta_ref[0, 1]`` is 0. A call mapped over a mesh
-    (ops/attention.py) holds one shard: ``meta_ref[0, 1]`` is the global
-    id of its first slice, and ``bh_split=(H_local, H)`` re-strides the
-    local (batch, head) numbering into the global one when the heads are
-    sharded too — so a sharded run draws the same dropout bits as the
-    unsharded one."""
-    if bh_split is not None:
-        h_loc, h_all = bh_split
-        local_bh = lax.div(local_bh, h_loc) * h_all + lax.rem(local_bh, h_loc)
-    return meta_ref[0, 1] + local_bh.astype(jnp.uint32)
-
-
-def _dropout_keep(seed, bh, q_base, k_base, bq, bk, rate):
-    """(bq, bk) float32 keep/(1-rate) multiplier for one attention block.
-
-    Hash of (seed, global element id) through the murmur3 finalizer.
-    uint32 arithmetic wraps identically in Mosaic, XLA and the Pallas
-    interpreter, so forward and backward kernels regenerate the same
-    mask from coordinates alone — grid iteration order is irrelevant,
-    and the row mixing uses a CONSTANT odd multiplier (not the padded
-    key length) so the backward kernels may tile the sequence
+def _element_ids(rows, cols):
+    """uint32 id of score element (row, col), the part of the hash every
+    head shares. The row mixing uses a CONSTANT odd multiplier (not the
+    padded key length) so the backward kernels may tile the sequence
     differently from the forward and still reproduce bit-identical
     masks. The odd multiplier is a bijection on uint32, so no two rows
     ever share a whole mask row (a power-of-two stride would duplicate
     rows every 2^32/stride queries)."""
-    rows = q_base + lax.broadcasted_iota(jnp.uint32, (bq, bk), 0)
-    cols = k_base + lax.broadcasted_iota(jnp.uint32, (bq, bk), 1)
-    return _counter_keep(seed, bh, rows, cols, rate)
+    return rows * jnp.uint32(0x9E3779B1) + cols
 
 
-def _counter_keep(seed, bh, rows, cols, rate):
-    """The shared hash core: keep/(1-rate) multipliers from broadcastable
-    uint32 (bh, rows, cols) index arrays. Used by the Pallas kernels via
-    _dropout_keep and by ring attention (parallel/ring_attention.py) with
-    GLOBAL sequence positions, so both regenerate identical masks from
-    coordinates alone."""
-    h = rows * jnp.uint32(0x9E3779B1) + cols
-    h = h + bh * jnp.uint32(0x9e3779b9)
+def _keep_of(ids, seed, bh, rate):
+    """float32 keep/(1-rate) multipliers of the elements ``ids`` of
+    batch·head slice ``bh``: the hash of (seed, global element id)
+    through the murmur3 finalizer. uint32 arithmetic wraps identically
+    in Mosaic, XLA and the Pallas interpreter, so forward and backward
+    kernels regenerate the same mask from coordinates alone — grid
+    iteration order is irrelevant."""
+    h = ids + bh * jnp.uint32(0x9e3779b9)
     h = h ^ seed
     h = h ^ (h >> jnp.uint32(16))
     h = h * jnp.uint32(0x85ebca6b)
@@ -265,22 +322,86 @@ def _counter_keep(seed, bh, rows, cols, rate):
     return keep * jnp.float32(1.0 / (1.0 - rate))
 
 
-def _masked_scores(q, k, kmask_row, qb, kb, bq, bk, scale, causal, k_len):
-    """(bq, bk) f32 scores for one (q-block, k-block) cell of one head:
-    QK^T * scale, key-padding cut at k_len, additive user mask, causal.
-    The causal ``where`` is what masks inside the cells the diagonal
-    crosses (and, needlessly, in those wholly under it); the cells wholly
-    above it are not in the causal grid (:func:`_cell_live`)."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    s = jnp.where(k_pos < k_len, s, _NEG_INF)
-    s = s + kmask_row
+def _counter_keep(seed, bh, rows, cols, rate):
+    """The shared hash core: keep/(1-rate) multipliers from broadcastable
+    uint32 (bh, rows, cols) index arrays. Used by the Pallas kernels
+    (:func:`_cell`) and by ring attention (parallel/ring_attention.py)
+    with GLOBAL sequence positions, so both regenerate identical masks
+    from coordinates alone."""
+    return _keep_of(_element_ids(rows, cols), seed, bh, rate)
+
+
+def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
+          k_len, dropout_p, h_all):
+    """What the heads of one grid step share, computed once a step:
+    (own, is-first, is-last, scores, keep).
+
+    ``own``: per head of the step's W-column lane block, the (bq, W) mask
+    of its own D columns (:func:`_lane_masks`).
+
+    ``scores(q, k, kmask_row)``: (bq, bk) f32 scores of one head for this
+    (q-block, k-block) cell: QK^T * scale, key-padding cut at k_len,
+    additive user mask, causal. The causal ``where`` is what masks inside
+    the cells the diagonal crosses (and, needlessly, in those wholly
+    under it); the cells wholly above it are not in the causal grid
+    (:func:`_cell_live`).
+
+    ``keep(g, hh)``: the (bq, bk) dropout multiplier of row g, head hh of
+    the step, or None without dropout. Its batch·head id is n * h_all + h
+    from ``meta_ref[0, 1]``, the numbering the (N*H, T, D) layout had. A
+    call that holds the whole (N, H) problem has ``h_all`` = H and
+    ``meta_ref[0, 1]`` 0. A call mapped over a mesh (ops/attention.py)
+    holds one shard: ``meta_ref[0, 1]`` is the global id of its first
+    (row, head) and ``h_all`` the heads of the whole problem, its own
+    being fewer when the heads are sharded too — so a sharded run draws
+    the same dropout bits as the unsharded one."""
+    own = _lane_masks(bq, W, D)
+    qb, kb, first, last = _step_cell(cells_ref, q_axis)
+    k_pos = kb * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    in_range = k_pos < k_len
+    under = None
     if causal:
-        q_pos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-    return s
+        q_pos = qb * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        under = q_pos >= k_pos
+
+    def scores(q, k, kmask_row):
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(in_range, s, _NEG_INF) + kmask_row
+        return s if under is None else jnp.where(under, s, _NEG_INF)
+
+    if dropout_p <= 0.0:
+        return own, first, last, scores, lambda g, hh: None
+    ids = _element_ids(
+        jnp.uint32(qb * bq) + lax.broadcasted_iota(jnp.uint32, (bq, bk), 0),
+        jnp.uint32(kb * bk) + lax.broadcasted_iota(jnp.uint32, (bq, bk), 1))
+    seed = meta_ref[0, 0]
+    bh0 = meta_ref[0, 1] + (pl.program_id(0) * (Gn * h_all)
+                            + pl.program_id(1) * len(own)).astype(jnp.uint32)
+
+    def keep(g, hh):
+        return _keep_of(ids, seed, bh0 + jnp.uint32(g * h_all + hh),
+                        dropout_p)
+    return own, first, last, scores, keep
+
+
+def _lane_masks(rows, W, D):
+    """Per head of a W-column lane block, the (rows, W) mask of its own
+    D columns; [None] where the block is one head."""
+    if W == D:
+        return [None]
+    lane = lax.broadcasted_iota(jnp.int32, (rows, W), 1)
+    masks = [lane < D]
+    masks += [(lane >= h * D) & (lane < (h + 1) * D)
+              for h in range(1, W // D - 1)]
+    return masks + [lane >= W - D]
+
+
+def _own(x, mask):
+    """``x`` with the columns of the block's other heads zeroed: a
+    contraction over the block's W lanes is then one over this head's D,
+    and a product into W columns adds exact zeros to the others'."""
+    return x if mask is None else lax.select(mask, x, lax.full_like(x, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -289,14 +410,20 @@ def _masked_scores(q, k, kmask_row, qb, kb, bq, bk, scale, causal, k_len):
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
                    o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                   scale, causal, G, bq, bk, k_len, dropout_p, bh_split,
+                   scale, causal, D, bq, bk, k_len, dropout_p, h_all,
                    cells_ref=None):
-    """One (head-group, q-block, k-block) cell. Refs are VMEM blocks:
-    q (G, bq, D), k/v (G, bk, D), kmask (G, 1, bk) additive f32,
-    o (G, bq, D), lse (G, bq, 1); meta (1, 2) uint32 in SMEM
-    [dropout seed, global batch·head base];
-    scratch acc (G, bq, D) f32, m/l (G, bq, 128) f32."""
-    qb, kb, first, last = _step_cell(cells_ref, q_axis=1)
+    """One (row-group, lane-block, q-block, k-block) cell. Refs are VMEM
+    blocks: q (Gn, bq, W), k/v (Gn, bk, W), kmask (Gn, 1, bk) additive
+    f32, o (Gn, bq, W), lse (Gn, hb, bq, 1), hb = W // D heads side by
+    side in the W columns; meta (1, 2) uint32 in SMEM [dropout seed,
+    global batch*head base]; scratch acc (Gn, bq, W) f32, m/l
+    (Gn*hb, bq, 128) f32. A head's scores, softmax, dropout and
+    accumulation are what they were when it had a block of its own."""
+    Gn, _, W = q_ref.shape
+    own, first, last, scores, keep = _cell(
+        cells_ref, 2, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
+        dropout_p, h_all)
+    hb = len(own)
 
     @pl.when(first())
     def _init():
@@ -304,108 +431,128 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    for g in range(G):
-        s = _masked_scores(q_ref[g], k_ref[g], kmask_ref[g], qb, kb,
-                           bq, bk, scale, causal, k_len)
-        m_prev = m_ref[g, :, :1]                         # (bq, 1)
-        l_prev = l_ref[g, :, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                           # (bq, bk) f32
-        alpha = jnp.exp(m_prev - m_new)                  # (bq, 1)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if dropout_p > 0.0:
-            bh = _global_bh(meta_ref, pl.program_id(0) * G + g, bh_split)
-            keep = _dropout_keep(meta_ref[0, 0], bh,
-                                 jnp.uint32(qb * bq), jnp.uint32(kb * bk),
-                                 bq, bk, dropout_p)
-            pv = p * keep
-        else:
-            pv = p
-        acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
-            pv.astype(v_ref.dtype), v_ref[g], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-        l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+    for g in range(Gn):
+        q, k, v, kmask_row = q_ref[g], k_ref[g], v_ref[g], kmask_ref[g]
+        acc = acc_ref[g]
+        for hh in range(hb):
+            slot = g * hb + hh
+            s = scores(_own(q, own[hh]), k, kmask_row)
+            m_prev = m_ref[slot, :, :1]                      # (bq, 1)
+            l_prev = l_ref[slot, :, :1]
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)                           # (bq, bk) f32
+            alpha = jnp.exp(m_prev - m_new)                  # (bq, 1)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            kept = keep(g, hh)
+            pv = p if kept is None else p * kept
+            # p·v fills all W columns; this head's are kept
+            new = acc * alpha + lax.dot_general(
+                pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc = new if own[hh] is None else lax.select(own[hh], new, acc)
+            m_ref[slot] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[slot] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        acc_ref[g] = acc
 
     @pl.when(last())
     def _finalize():
-        for g in range(G):
-            l = l_ref[g, :, :1]
-            safe_l = jnp.maximum(l, 1e-30)
-            o_ref[g] = (acc_ref[g] / safe_l).astype(o_ref.dtype)
-            lse_ref[g] = m_ref[g, :, :1] + jnp.log(safe_l)
+        for g in range(Gn):
+            acc = out = acc_ref[g]
+            for hh in range(hb):
+                slot = g * hb + hh
+                safe_l = jnp.maximum(l_ref[slot, :, :1], 1e-30)
+                out = acc / safe_l if own[hh] is None \
+                    else lax.select(own[hh], acc / safe_l, out)
+                lse_ref[g, hh] = m_ref[slot, :, :1] + jnp.log(safe_l)
+            o_ref[g] = out.astype(o_ref.dtype)
 
 
-def _fa_forward(q, k, v, kmask, meta, causal, dropout_p, interpret,
-                bh_split):
-    """q/k/v: (BH, T, D) flattened over batch*heads.
-    kmask: (BH, Tk) additive f32 or None. meta: (1, 2) uint32
-    [dropout seed, global batch·head base].
-    Returns (out, lse), both sliced back to (BH, Tq[, D]) — the backward
-    re-pads them for its own (possibly different) tiling."""
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    scale = 1.0 / math.sqrt(D)
-    G, bq, bk = _block_sizes(BH, Tq, Tk, D, q.dtype)
-    nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
-    pq, pk = nq * bq - Tq, nk * bk - Tk
+def _addressing(arrays, H, kind):
+    """The static numbers of one build from its operands' shapes:
+    (N, Tq, Tk, C, D, W, hb, Gn, bq, bk). ``arrays`` is (q, k, v), each
+    (N, T, C = H*D), or (qkv,), their (N, T, 3C) fusion."""
+    N, Tq = arrays[0].shape[:2]
+    Tk = arrays[-1].shape[1]
+    C = arrays[-1].shape[2] // (3 if len(arrays) == 1 else 1)
+    D = C // H
+    if _lane_block(C, D) is None:
+        raise ValueError(
+            f"flash attention: {H} heads of {D} columns do not come apart "
+            f"into 128-lane blocks of whole heads (flash_legal says so)")
+    W, hb = _lane_block(C, D)
+    G, bq, bk = _block_sizes(N * H, Tq, Tk, D, arrays[0].dtype, kind)
+    return N, Tq, Tk, C, D, W, hb, _rows_per_step(N, G, hb), bq, bk
+
+
+def _operands(arrays, C, W, pq, pk):
+    """((q, k, v), their offsets in lane blocks) as the kernels address
+    them: a fused (N, T, 3C) array three times, at 0, C/W and 2C/W, where
+    its lane blocks are whole 128s and no sequence needs padding to its
+    blocks (pq, pk rows); else three arrays, padded."""
+    if len(arrays) == 1:
+        if W % _LANES == 0 and not pq and not pk:
+            return arrays * 3, (0, C // W, 2 * C // W)
+        arrays = jnp.split(arrays[0], 3, axis=-1)
+    q, k, v = arrays
     if pq:
         q = jnp.pad(q, ((0, 0), (0, pq), (0, 0)))
     if pk:
         k = jnp.pad(k, ((0, 0), (0, pk), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pk), (0, 0)))
-        if kmask is not None:
-            kmask = jnp.pad(kmask, ((0, 0), (0, pk)))
-    tk_pad = nk * bk
+    return (q, k, v), (0, 0, 0)
+
+
+def _mask_operand(kmask, N, Tk, pk):
+    """(N, Tk) additive mask or None -> the kernels' (N, 1, Tk + pk) f32
+    operand, one row a batch row."""
     if kmask is None:
-        km3 = jnp.zeros((BH, 1, tk_pad), jnp.float32)
-    else:
-        km3 = kmask.astype(jnp.float32).reshape(BH, 1, tk_pad)
+        return jnp.zeros((N, 1, Tk + pk), jnp.float32)
+    return jnp.pad(kmask.astype(jnp.float32), ((0, 0), (0, pk)))[:, None, :]
+
+
+def _count_build(kind, H, D, hb, offs):
+    key = (kind, H, D, hb, offs != (0, 0, 0))
+    head_blocks[key] = head_blocks.get(key, 0) + 1
+
+
+def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all):
+    """arrays: (q, k, v), each (N, T, H*D) as the model holds them, or
+    (qkv,), the fused (N, T, 3*H*D) projection; the kernels' blocks
+    address them in place (:func:`_block_specs`). kmask: (N, Tk) additive
+    f32 or None. meta: (1, 2) uint32 [dropout seed, global batch*head
+    base]. Returns (out (N, Tq, H*D), lse (N, H, Tq)), sliced back from
+    the blocks' padding -- the backward re-pads them for its own
+    (possibly different) tiling."""
+    N, Tq, Tk, C, D, W, hb, Gn, bq, bk = _addressing(arrays, H, 'fwd')
+    dtype = arrays[0].dtype
+    nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
+    pq, pk = nq * bq - Tq, nk * bk - Tk
+    (q, k, v), (qo, ko, vo) = _operands(arrays, C, W, pq, pk)
+    _count_build('fwd', H, D, hb, (qo, ko, vo))
 
     kernel = functools.partial(
-        _fa_fwd_kernel, scale=scale, causal=causal, G=G, bq=bq, bk=bk,
-        k_len=Tk, dropout_p=float(dropout_p), bh_split=bh_split)
-    out_shape = [jax.ShapeDtypeStruct((BH, nq * bq, D), q.dtype),
-                 jax.ShapeDtypeStruct((BH, nq * bq, 1), jnp.float32)]
-    scratch = [pltpu.VMEM((G, bq, D), jnp.float32),
-               pltpu.VMEM((G, bq, 128), jnp.float32),
-               pltpu.VMEM((G, bq, 128), jnp.float32)]
-    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    if causal:
-        qspec, col1, kspec, mspec = _causal_specs(G, bq, bk, D)
-        call = _causal_call(
-            kernel, _causal_cell_table('fwd', nq, nk, bq, bk, by_row=True),
-            BH // G, in_specs=[qspec, kspec, kspec, mspec, sspec],
-            out_specs=[qspec, col1], out_shape=out_shape,
-            scratch_shapes=scratch, interpret=interpret,
-            name=_scopes.FLASH_FWD)
-    else:
-        call = pl.pallas_call(
-            kernel,
-            grid=(BH // G, nq, nk),
-            in_specs=[
-                pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((G, 1, bk), lambda b, i, j: (b, 0, j)),
-                sspec,
-            ],
-            out_specs=[
-                pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((G, bq, 1), lambda b, i, j: (b, i, 0)),
-            ],
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            interpret=interpret,
-            compiler_params=_COMPILER_PARAMS,
-            name=_scopes.FLASH_FWD,
-        )
-    out, lse = call(q, k, v, km3, meta)
+        _fa_fwd_kernel, scale=1.0 / math.sqrt(D), causal=causal, D=D, bq=bq,
+        bk=bk, k_len=Tk, dropout_p=float(dropout_p), h_all=h_all)
+    seq, col, mask = _block_specs(Gn, hb, bq, bk, W, causal)
+    cells = _causal_cell_table('fwd', nq, nk, bq, bk, by_row=True) \
+        if causal else None
+    out, lse = _call(
+        kernel, cells, (N // Gn, C // W) + (() if causal else (nq, nk)),
+        in_specs=[seq('q', qo), seq('k', ko), seq('k', vo), mask,
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=[seq('q'), col],
+        out_shape=[jax.ShapeDtypeStruct((N, nq * bq, C), dtype),
+                   jax.ShapeDtypeStruct((N, H, nq * bq, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((Gn, bq, W), jnp.float32),
+                        pltpu.VMEM((Gn * hb, bq, 128), jnp.float32),
+                        pltpu.VMEM((Gn * hb, bq, 128), jnp.float32)],
+        interpret=interpret, name=_scopes.FLASH_FWD,
+    )(q, k, v, _mask_operand(kmask, N, Tk, pk), meta)
     lse = lse[..., 0]
     if pq:
         out = out[:, :Tq]
-        lse = lse[:, :Tq]
+        lse = lse[:, :, :Tq]
     return out, lse
 
 
@@ -415,238 +562,215 @@ def _fa_forward(q, k, v, kmask, meta, causal, dropout_p, interpret,
 
 def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                   lse_ref, delta_ref, dq_ref, dq_acc, *,
-                  scale, causal, G, bq, bk, k_len, dropout_p, bh_split,
+                  scale, causal, D, bq, bk, k_len, dropout_p, h_all,
                   cells_ref=None):
-    """dq for one q-block, accumulated over k-blocks (grid (BH/G, nq, nk))."""
-    qb, kb, first, last = _step_cell(cells_ref, q_axis=1)
+    """dq for one q-block of one lane block, accumulated over k-blocks
+    (grid (N/Gn, C/W, nq, nk)), written in the operands' dtype."""
+    Gn, _, W = q_ref.shape
+    own, first, last, scores, keep = _cell(
+        cells_ref, 2, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
+        dropout_p, h_all)
+    hb = len(own)
 
     @pl.when(first())
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    for g in range(G):
-        s = _masked_scores(q_ref[g], k_ref[g], kmask_ref[g], qb, kb,
-                           bq, bk, scale, causal, k_len)
-        p = jnp.exp(s - lse_ref[g])                   # (bq, bk), lse (bq,1)
-        dp = jax.lax.dot_general(
-            do_ref[g].astype(jnp.float32), v_ref[g].astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (bq, bk)
-        if dropout_p > 0.0:
-            bh = _global_bh(meta_ref, pl.program_id(0) * G + g, bh_split)
-            keep = _dropout_keep(meta_ref[0, 0], bh,
-                                 jnp.uint32(qb * bq), jnp.uint32(kb * bk),
-                                 bq, bk, dropout_p)
-            dp = dp * keep
-        ds = p * (dp - delta_ref[g]) * scale          # (bq, bk)
-        dq_acc[g] = dq_acc[g] + jax.lax.dot_general(
-            ds, k_ref[g].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    for g in range(Gn):
+        q, k, kmask_row = q_ref[g], k_ref[g], kmask_ref[g]
+        k32 = k.astype(jnp.float32)                       # (bk, W)
+        v32 = v_ref[g].astype(jnp.float32)
+        do32 = do_ref[g].astype(jnp.float32)              # (bq, W)
+        dq = dq_acc[g]
+        for hh in range(hb):
+            s = scores(_own(q, own[hh]), k, kmask_row)
+            p = jnp.exp(s - lse_ref[g, hh])               # (bq, bk)
+            dp = lax.dot_general(
+                _own(do32, own[hh]), v32, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (bq, bk)
+            kept = keep(g, hh)
+            if kept is not None:
+                dp = dp * kept
+            ds = p * (dp - delta_ref[g, hh]) * scale      # (bq, bk)
+            # ds·k fills all W columns; this head's are kept
+            dq = dq + _own(lax.dot_general(
+                ds, k32, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32), own[hh])
+        dq_acc[g] = dq
 
     @pl.when(last())
     def _finalize():
-        dq_ref[:] = dq_acc[:]
+        dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                   scale, causal, G, bq, bk, k_len, dropout_p, bh_split,
+                   scale, causal, D, bq, bk, k_len, dropout_p, h_all,
                    cells_ref=None):
-    """dk/dv for one k-block, accumulated over q-blocks
-    (grid (BH/G, nk, nq): k-block is program 1, q-block is program 2)."""
-    qb, kb, first, last = _step_cell(cells_ref, q_axis=2)
+    """dk/dv for one k-block of one lane block, accumulated over q-blocks
+    (grid (N/Gn, C/W, nk, nq): k-block is program 2, q-block program 3),
+    written in the operands' dtype."""
+    Gn, _, W = q_ref.shape
+    own, first, last, scores, keep = _cell(
+        cells_ref, 3, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
+        dropout_p, h_all)
+    hb = len(own)
 
     @pl.when(first())
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    for g in range(G):
-        s = _masked_scores(q_ref[g], k_ref[g], kmask_ref[g], qb, kb,
-                           bq, bk, scale, causal, k_len)
-        p = jnp.exp(s - lse_ref[g])                   # (bq, bk)
-        do32 = do_ref[g].astype(jnp.float32)          # (bq, D)
-        if dropout_p > 0.0:
-            bh = _global_bh(meta_ref, pl.program_id(0) * G + g, bh_split)
-            keep = _dropout_keep(meta_ref[0, 0], bh,
-                                 jnp.uint32(qb * bq), jnp.uint32(kb * bk),
-                                 bq, bk, dropout_p)
-            pv = p * keep
-        else:
-            keep = None
-            pv = p
-        # dv_j += sum_i P_drop_ij dO_i
-        dv_acc[g] = dv_acc[g] + jax.lax.dot_general(
-            pv, do32, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (bk, D)
-        dp = jax.lax.dot_general(
-            do32, v_ref[g].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (bq, bk)
-        if keep is not None:
-            dp = dp * keep
-        ds = p * (dp - delta_ref[g]) * scale          # (bq, bk)
-        dk_acc[g] = dk_acc[g] + jax.lax.dot_general(
-            ds, q_ref[g].astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (bk, D)
+    for g in range(Gn):
+        q, k, kmask_row = q_ref[g], k_ref[g], kmask_ref[g]
+        v32 = v_ref[g].astype(jnp.float32)                # (bk, W)
+        do32 = do_ref[g].astype(jnp.float32)              # (bq, W)
+        dk, dv = dk_acc[g], dv_acc[g]
+        for hh in range(hb):
+            # q and dO with the other heads' columns zeroed: what they
+            # are contracted into lands in this head's columns alone
+            q_own, do_own = _own(q, own[hh]), _own(do32, own[hh])
+            s = scores(q_own, k, kmask_row)
+            p = jnp.exp(s - lse_ref[g, hh])               # (bq, bk)
+            kept = keep(g, hh)
+            pv = p if kept is None else p * kept
+            # dv_j += sum_i P_drop_ij dO_i
+            dv = dv + lax.dot_general(
+                pv, do_own, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (bk, W)
+            dp = lax.dot_general(
+                do_own, v32, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (bq, bk)
+            if kept is not None:
+                dp = dp * kept
+            ds = p * (dp - delta_ref[g, hh]) * scale      # (bq, bk)
+            dk = dk + lax.dot_general(
+                ds, q_own.astype(jnp.float32), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (bk, W)
+        dk_acc[g], dv_acc[g] = dk, dv
 
     @pl.when(last())
     def _finalize():
-        dk_ref[:] = dk_acc[:]
-        dv_ref[:] = dv_acc[:]
+        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _fa_backward(q, k, v, kmask, meta, causal, dropout_p, interpret,
-                 bh_split, out, lse, do):
+def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
+                 h_all, out, lse, do):
     """Pallas backward: recompute probability blocks from the saved LSE.
-    Returns (dq, dk, dv) in the input dtypes."""
-    BH, Tq, D = q.shape
-    Tk = k.shape[1]
-    scale = 1.0 / math.sqrt(D)
-    G, bq, bk = _block_sizes(BH, Tq, Tk, D, q.dtype, kind='bwd')
+    Returns the cotangents of ``arrays``, in their dtype: (dq, dk, dv),
+    or (dqkv,), the three side by side."""
+    N, Tq, Tk, C, D, W, hb, Gn, bq, bk = _addressing(arrays, H, 'bwd')
+    dtype = arrays[0].dtype
     nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
     pq, pk = nq * bq - Tq, nk * bk - Tk
+    (q, k, v), (qo, ko, vo) = _operands(arrays, C, W, pq, pk)
     if pq:
         # padded q rows contribute nothing: their dO is zero, so dv += p·0
         # and ds = p·(0 - 0) vanish; lse pads as 0 harmlessly
-        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0)))
         do = jnp.pad(do, ((0, 0), (0, pq), (0, 0)))
         out = jnp.pad(out, ((0, 0), (0, pq), (0, 0)))
-        lse = jnp.pad(lse, ((0, 0), (0, pq)))
-    if pk:
-        k = jnp.pad(k, ((0, 0), (0, pk), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pk), (0, 0)))
-        if kmask is not None:
-            kmask = jnp.pad(kmask, ((0, 0), (0, pk)))
-    tk_pad = nk * bk
-    if kmask is None:
-        km3 = jnp.zeros((BH, 1, tk_pad), jnp.float32)
-    else:
-        km3 = kmask.astype(jnp.float32).reshape(BH, 1, tk_pad)
+        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pq)))
 
-    # delta_i = dO_i · O_i (rowwise) — cheap XLA preprocessing
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)            # (BH, Tq_pad, 1)
-    lse3 = lse.reshape(BH, nq * bq, 1)
+    # delta_i = dO_i · O_i (rowwise, a head at a time) — cheap XLA
+    # preprocessing, laid out as the lse is: (N, H, Tq_pad, 1). The sum
+    # over a head's D columns is a product with the 0/1 matrix that says
+    # which head a column is of: a reduction over part of the lanes would
+    # have XLA copy the (N, T, C) product into another layout first
+    head_of = (jnp.arange(C)[:, None] // D == jnp.arange(H)).astype(
+        jnp.float32)
+    delta = jnp.einsum(
+        'ntc,ch->nht', do.astype(jnp.float32) * out.astype(jnp.float32),
+        head_of, precision=lax.Precision.HIGHEST)[..., None]
 
-    kw = dict(scale=scale, causal=causal, G=G, bq=bq, bk=bk, k_len=Tk,
-              dropout_p=float(dropout_p), bh_split=bh_split)
-    qspec_i = pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0))
-    kspec_j = pl.BlockSpec((G, bk, D), lambda b, i, j: (b, j, 0))
-    col1_i = pl.BlockSpec((G, bq, 1), lambda b, i, j: (b, i, 0))
-    mspec_j = pl.BlockSpec((G, 1, bk), lambda b, i, j: (b, 0, j))
-    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
-
-    dq_shape = jax.ShapeDtypeStruct((BH, nq * bq, D), jnp.float32)
-    dq_scratch = [pltpu.VMEM((G, bq, D), jnp.float32)]
-    dkv_shape = [jax.ShapeDtypeStruct((BH, nk * bk, D), jnp.float32),
-                 jax.ShapeDtypeStruct((BH, nk * bk, D), jnp.float32)]
-    dkv_scratch = [pltpu.VMEM((G, bk, D), jnp.float32),
-                   pltpu.VMEM((G, bk, D), jnp.float32)]
-    operands = (q, k, v, km3, meta, do, lse3, delta)
-    if causal:
-        qspec, col1, kspec, mspec = _causal_specs(G, bq, bk, D)
-        in_specs = [qspec, kspec, kspec, mspec, sspec, qspec, col1, col1]
-        dq = _causal_call(
-            functools.partial(_fa_dq_kernel, **kw),
-            _causal_cell_table('bwd_dq', nq, nk, bq, bk, by_row=True),
-            BH // G, in_specs=in_specs, out_specs=qspec, out_shape=dq_shape,
-            scratch_shapes=dq_scratch, interpret=interpret,
-            name=_scopes.FLASH_BWD_DQ)(*operands)
-        dk, dv = _causal_call(
-            functools.partial(_fa_dkv_kernel, **kw),
-            _causal_cell_table('bwd_dkv', nq, nk, bq, bk, by_row=False),
-            BH // G, in_specs=in_specs, out_specs=[kspec, kspec],
-            out_shape=dkv_shape, scratch_shapes=dkv_scratch,
-            interpret=interpret, name=_scopes.FLASH_BWD_DKV)(*operands)
-    else:
-        dq = pl.pallas_call(
-            functools.partial(_fa_dq_kernel, **kw),
-            grid=(BH // G, nq, nk),
-            in_specs=[qspec_i, kspec_j, kspec_j, mspec_j, sspec,
-                      qspec_i, col1_i, col1_i],
-            out_specs=pl.BlockSpec((G, bq, D), lambda b, i, j: (b, i, 0)),
-            out_shape=dq_shape,
-            scratch_shapes=dq_scratch,
+    kw = dict(scale=1.0 / math.sqrt(D), causal=causal, D=D, bq=bq, bk=bk,
+              k_len=Tk, dropout_p=float(dropout_p), h_all=h_all)
+    operands = (q, k, v, _mask_operand(kmask, N, Tk, pk), meta, do,
+                lse[..., None], delta)
+    grid = (N // Gn, C // W)
+    calls = []
+    for kind, kernel, q_axis, plane, side, n_out in (
+            ('bwd_dq', _fa_dq_kernel, 2, (nq, nk), 'q', 1),
+            ('bwd_dkv', _fa_dkv_kernel, 3, (nk, nq), 'k', 2)):
+        _count_build(kind, H, D, hb, (qo, ko, vo))
+        seq, col, mask = _block_specs(Gn, hb, bq, bk, W, causal, q_axis)
+        rows, blk = (nq * bq, bq) if side == 'q' else (nk * bk, bk)
+        cells = _causal_cell_table(kind, nq, nk, bq, bk,
+                                   by_row=side == 'q') if causal else None
+        calls.append(_call(
+            functools.partial(kernel, **kw), cells,
+            grid + (() if causal else plane),
+            in_specs=[seq('q', qo), seq('k', ko), seq('k', vo), mask,
+                      pl.BlockSpec(memory_space=pltpu.SMEM), seq('q'),
+                      col, col],
+            out_specs=[seq(side)] * n_out,
+            out_shape=[jax.ShapeDtypeStruct((N, rows, C), dtype)] * n_out,
+            scratch_shapes=[pltpu.VMEM((Gn, blk, W), jnp.float32)] * n_out,
             interpret=interpret,
-            compiler_params=_COMPILER_PARAMS,
-            name=_scopes.FLASH_BWD_DQ,
-        )(*operands)
-
-        # dk/dv grid permutes (q-block, k-block): q innermost
-        qspec_2 = pl.BlockSpec((G, bq, D), lambda b, j, i: (b, i, 0))
-        kspec_1 = pl.BlockSpec((G, bk, D), lambda b, j, i: (b, j, 0))
-        col1_2 = pl.BlockSpec((G, bq, 1), lambda b, j, i: (b, i, 0))
-        mspec_1 = pl.BlockSpec((G, 1, bk), lambda b, j, i: (b, 0, j))
-        dk, dv = pl.pallas_call(
-            functools.partial(_fa_dkv_kernel, **kw),
-            grid=(BH // G, nk, nq),
-            in_specs=[qspec_2, kspec_1, kspec_1, mspec_1, sspec,
-                      qspec_2, col1_2, col1_2],
-            out_specs=[pl.BlockSpec((G, bk, D), lambda b, j, i: (b, j, 0)),
-                       pl.BlockSpec((G, bk, D), lambda b, j, i: (b, j, 0))],
-            out_shape=dkv_shape,
-            scratch_shapes=dkv_scratch,
-            interpret=interpret,
-            compiler_params=_COMPILER_PARAMS,
-            name=_scopes.FLASH_BWD_DKV,
-        )(*operands)
-
-    dq = dq[:, :Tq].astype(q.dtype)
-    dk = dk[:, :Tk].astype(k.dtype)
-    dv = dv[:, :Tk].astype(v.dtype)
-    return dq, dk, dv
+            name=_scopes.FLASH_BWD_DQ if side == 'q'
+            else _scopes.FLASH_BWD_DKV)(*operands))
+    (dq,), (dk, dv) = calls
+    grads = (dq[:, :Tq], dk[:, :Tk], dv[:, :Tk])
+    if len(arrays) == 1:
+        return (jnp.concatenate(grads, axis=-1),)
+    return grads
 
 
 # ---------------------------------------------------------------------------
 # custom-vjp wrapper
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash(q, k, v, kmask, meta, causal, dropout_p, interpret, bh_split):
-    out, _ = _fa_forward(q, k, v, kmask, meta, causal, dropout_p, interpret,
-                         bh_split)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all):
+    out, _ = _fa_forward(arrays, kmask, meta, H, causal, dropout_p,
+                         interpret, h_all)
     return out
 
 
-def _flash_fwd(q, k, v, kmask, meta, causal, dropout_p, interpret, bh_split):
-    out, lse = _fa_forward(q, k, v, kmask, meta, causal, dropout_p,
-                           interpret, bh_split)
-    return out, (q, k, v, kmask, meta, out, lse)
+def _flash_fwd(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all):
+    out, lse = _fa_forward(arrays, kmask, meta, H, causal, dropout_p,
+                           interpret, h_all)
+    return out, (arrays, kmask, meta, out, lse)
 
 
-def _flash_bwd(causal, dropout_p, interpret, bh_split, res, do):
-    q, k, v, kmask, meta, out, lse = res
-    dq, dk, dv = _fa_backward(q, k, v, kmask, meta, causal, dropout_p,
-                              interpret, bh_split, out, lse, do)
+def _flash_bwd(H, causal, dropout_p, interpret, h_all, res, do):
+    arrays, kmask, meta, out, lse = res
+    grads = _fa_backward(arrays, kmask, meta, H, causal, dropout_p,
+                         interpret, h_all, out, lse, do)
     dmask = None if kmask is None else jnp.zeros_like(kmask)
     dmeta = onp.zeros(meta.shape, jax.dtypes.float0)
-    return dq, dk, dv, dmask, dmeta
+    return grads, dmask, dmeta
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_legal(BH, Tq, Tk, D, dtype) -> bool:
+def flash_legal(BH, Tq, Tk, D, dtype, num_heads=1) -> bool:
     """Can the forward AND backward kernels be built for this shape at
-    the block sizes they would use? The static Mosaic rules of
-    ops/autotune.check_candidate, applied to what :func:`_block_sizes`
-    resolves — the verdict ``multi_head_attention`` routes on."""
+    the block sizes they would use? The heads' columns have to come
+    apart into lane blocks (:func:`_lane_block`), and the static Mosaic
+    rules of ops/autotune.check_candidate hold for what
+    :func:`_block_sizes` resolves — the verdict ``multi_head_attention``
+    routes on."""
     from . import autotune
-    return all(
+    return _lane_block(num_heads * D, D) is not None and all(
         autotune.check_candidate(
             BH, Tq, Tk, D, jnp.dtype(dtype), kind,
             *_block_sizes(BH, Tq, Tk, D, dtype, kind))[0]
         for kind in ('fwd', 'bwd'))
 
 
-def flash_attention(q, k, v, key_mask=None, causal=False, dropout_p=0.0,
-                    dropout_seed=None, interpret=None, bh_base=None,
-                    bh_split=None):
-    """Flash attention. q/k/v: (B, H, T, D). key_mask: optional (B, Tk)
+def flash_mha(arrays, num_heads, key_mask=None, causal=False, dropout_p=0.0,
+              dropout_seed=None, interpret=None, bh_base=None,
+              bh_split=None):
+    """Flash attention on the model's own arrays. ``arrays``: (q, k, v),
+    each (N, T, H*D), or (qkv,), the fused (N, T, 3*H*D) projection whose
+    thirds they are; the kernels read them in place, two 64-wide heads to
+    a 128-lane block (:func:`_lane_block`). key_mask: optional (N, Tk)
     additive f32 mask (0 = keep, large-negative = drop) or boolean
     (True = keep). dropout_p: in-kernel attention-probability dropout;
     dropout_seed: uint32 scalar/array seeding the kernel PRNG (required
-    when dropout_p > 0). Returns (B, H, Tq, D).
+    when dropout_p > 0). Returns (N, Tq, H*D).
 
     interpret: True runs the kernels through the Pallas interpreter,
     False compiles them with Mosaic (and fails where there is no TPU),
@@ -654,29 +778,19 @@ def flash_attention(q, k, v, key_mask=None, causal=False, dropout_p=0.0,
 
     bh_base / bh_split: for a call that holds one shard of a larger
     (batch, heads) problem — the global batch·head id of its first
-    slice (uint32 scalar, may be traced) and the static
-    (H_local, H_global) pair — see :func:`_global_bh`."""
+    (row, head) (uint32 scalar, may be traced) and the static
+    (H_local, H_global) pair — see :func:`_cell`."""
     if interpret is None:
         interpret = default_interpret()
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    qf = q.reshape(B * H, Tq, D)
-    kf = k.reshape(B * H, Tk, D)
-    vf = v.reshape(B * H, Tk, D)
-    km = None
+    N, Tk = arrays[-1].shape[:2]
     if key_mask is not None:
+        if key_mask.shape != (N, Tk):
+            raise ValueError(
+                f"key_mask shape {tuple(key_mask.shape)} is not (batch, "
+                f"keys) = {(N, Tk)}")
         if key_mask.dtype == jnp.bool_:
             key_mask = jnp.where(key_mask, 0.0, _NEG_INF)
         key_mask = key_mask.astype(jnp.float32)
-        if key_mask.shape[0] == B * H:
-            km = key_mask
-        elif key_mask.shape[0] == B:
-            km = jnp.broadcast_to(key_mask[:, None, :],
-                                  (B, H, Tk)).reshape(B * H, Tk)
-        else:
-            raise ValueError(
-                f"key_mask leading dim {key_mask.shape[0]} matches neither "
-                f"batch {B} nor batch*heads {B * H}")
     dropout_p = float(dropout_p)
     if dropout_p > 0.0 and dropout_seed is None:
         raise ValueError("dropout_p > 0 requires dropout_seed")
@@ -685,8 +799,18 @@ def flash_attention(q, k, v, key_mask=None, causal=False, dropout_p=0.0,
     base = jnp.zeros((), jnp.uint32) if bh_base is None \
         else jnp.asarray(bh_base, jnp.uint32).reshape(())
     meta = jnp.stack([seed, base]).reshape(1, 2)
-    if bh_split is not None:
-        bh_split = (int(bh_split[0]), int(bh_split[1]))
-    out = _flash(qf, kf, vf, km, meta, causal, dropout_p, bool(interpret),
-                 bh_split)
-    return out.reshape(B, H, Tq, D)
+    h_all = int(num_heads) if bh_split is None else int(bh_split[1])
+    return _flash(tuple(arrays), key_mask, meta, int(num_heads), causal,
+                  dropout_p, bool(interpret), h_all)
+
+
+def flash_attention(q, k, v, key_mask=None, **kwargs):
+    """:func:`flash_mha` for callers that hold q/k/v as (B, H, T, D):
+    they pay the transposes to (B, T, H*D) and back that the model's own
+    arrays do not need. Returns (B, H, Tq, D)."""
+    B, H, Tq, D = q.shape
+
+    def merge(x):
+        return x.transpose(0, 2, 1, 3).reshape(B, x.shape[2], H * D)
+    out = flash_mha((merge(q), merge(k), merge(v)), H, key_mask, **kwargs)
+    return out.reshape(B, Tq, H, D).transpose(0, 2, 1, 3)

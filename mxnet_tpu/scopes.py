@@ -38,7 +38,8 @@ GUARD = 'mxtpu.guard'           # the non-finite check and the gated writeback
 UPDATE = 'mxtpu.update'         # optimizer update, cast back, compute copy
 
 # stretches inside blocks that are no child block
-ATTN_LAYOUT = 'attn_layout'     # (N,T,H*D) <-> (N,H,T,D) round the kernel
+ATTN_LAYOUT = 'attn_layout'     # (N,T,H*D) <-> (N,H,T,D) on the XLA and ring
+                                # routes; the Pallas route has none
 ATTN_CORE = 'attn_core'         # scores, softmax, dropout, weighted sum
 FFN1 = 'ffn1'                   # first feed-forward matmul + GELU
 LN1, LN2 = 'ln1', 'ln2'         # residual add + LayerNorm

@@ -84,6 +84,30 @@ def test_bf16_raises_sublane_minimum():
     assert ok_f32 and not ok_bf16
 
 
+def test_g_is_heads_a_step_in_lane_blocks():
+    """The tuning key stays (BH, Tq, Tk, D) and G heads a grid step: the
+    layouts the checker reads are the kernels' lane blocks, G // hb batch
+    rows of hb = 128 // D heads side by side, and the VMEM estimate of G
+    heads is what it was when each had a block of its own."""
+    layouts = {name: (array, block) for name, array, block in
+               autotune.fa_block_layouts(672, 512, 512, 64, 'bwd',
+                                         4, 256, 256)}
+    assert layouts['q'] == ((336, 512, 128), (2, 256, 128))
+    assert layouts['dk'] == ((336, 512, 128), (2, 256, 128))
+    assert layouts['kmask'] == ((336, 1, 512), (2, 1, 256))
+    assert layouts['lse'] == ((336, 2, 512, 1), (2, 2, 256, 1))
+    # one 128-wide head a block: G rows
+    assert dict((n, b) for n, _a, b in autotune.fa_block_layouts(
+        32, 512, 512, 128, 'fwd', 4, 512, 512))['q'] == (4, 512, 128)
+    # a G under the heads of a lane block still takes one whole block
+    assert dict((n, b) for n, _a, b in autotune.fa_block_layouts(
+        12, 64, 64, 64, 'fwd', 1, 64, 64))['q'] == (1, 64, 128)
+    assert autotune.vmem_bytes(4, 512, 512, 64, 'fwd') == 11534336
+    bf16 = jnp.dtype(jnp.bfloat16)
+    assert autotune.check_candidate(672, 512, 512, 64, bf16, 'bwd',
+                                    4, 256, 256) == (True, None)
+
+
 # ---------------------------------------------------------------------------
 # tuning DB: round trip, corruption, precedence
 # ---------------------------------------------------------------------------

@@ -4,11 +4,13 @@ under the diagonal (ops/pallas_attention.py: ``_cell_live``).
 What is held here: the causal results against the naive attention of
 tests/test_operator.py; that a skipped cell contributed nothing (the same
 bits with the skip patched out); the counts of live cells a build records;
-that a non-causal build is, equation for equation, the kernel it was before
-the skip existed; and that Mosaic takes the guarded kernels at the
-benchmark cells' shapes, compiled here for a described v5e:2x2 with no chip.
+that a non-causal build is, equation for equation, the kernel it is known
+as; and that Mosaic takes the kernels at the benchmark cells' shapes, the
+fused projection addressed in place, compiled here for a described v5e:2x2
+with no chip.
 """
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -193,12 +195,13 @@ def test_live_cells_at_the_gpt2_cells_grid():
     builds = _kernel_builds(True, B=24, H=12, T=1024)
     assert new() == {('fwd', 3, 4): 1, ('bwd_dq', 10, 16): 1,
                      ('bwd_dkv', 10, 16): 1}
-    # the causal grid is (head groups, listed cells), and nothing guards a
+    # the causal grid is (row groups, lane blocks, listed cells): 24 rows
+    # two a step, 12 heads two a 128-lane block; and nothing guards a
     # cell's body: the two conds are _init and _finalize, as without causal
     assert {name: tuple(e.params['grid_mapping'].grid)
             for name, e in builds.items()} == {
-        'mxtpu_flash_fwd': (72, 3), 'mxtpu_flash_bwd_dq': (72, 10),
-        'mxtpu_flash_bwd_dkv': (72, 10)}
+        'mxtpu_flash_fwd': (12, 6, 3), 'mxtpu_flash_bwd_dq': (12, 6, 10),
+        'mxtpu_flash_bwd_dkv': (12, 6, 10)}
     for e in builds.values():
         assert sum(x.primitive.name == 'cond'
                    for x in _walk(e.params['jaxpr'])) == 2
@@ -224,12 +227,19 @@ def test_the_cell_table_lists_live_cells_in_grid_order():
         del pa.causal_cells[key]
 
 
-# the numbers of the commit before the skip (a371ff9), BERT-base's cell:
-# (top-level equations, equations with those of nested jaxprs, conds, grid)
+# BERT-base's cell: (top-level equations, equations with those of nested
+# jaxprs, conds, grid). Before the kernels addressed (N, T, H*D) (PR 33)
+# these read (276, 338, 2, (168, 1, 1)), (252, 272, 2, (168, 2, 2)) and
+# (272, 296, 2, (168, 2, 2)), as at the commit before the skip (a371ff9):
+# the same four heads a step, now 2 rows x the 2 heads of a lane block,
+# 28 row groups x 6 lane blocks where there were 168 head groups; the
+# lane masks that pick a head out of its block came, and what the heads of
+# a step share (positions, masks, element ids, a row's loads) is computed
+# once a step and no longer once a head
 NON_CAUSAL_AT_BERT_T512 = {
-    'mxtpu_flash_fwd': (276, 338, 2, (168, 1, 1)),
-    'mxtpu_flash_bwd_dq': (252, 272, 2, (168, 2, 2)),
-    'mxtpu_flash_bwd_dkv': (272, 296, 2, (168, 2, 2)),
+    'mxtpu_flash_fwd': (222, 282, 2, (28, 6, 1, 1)),
+    'mxtpu_flash_bwd_dq': (198, 219, 2, (28, 6, 2, 2)),
+    'mxtpu_flash_bwd_dkv': (208, 234, 2, (28, 6, 2, 2)),
 }
 
 
@@ -237,9 +247,9 @@ NON_CAUSAL_AT_BERT_T512 = {
 def test_the_non_causal_build_is_what_it_was(kernel):
     """causal=False at bert_base.t512's shape (B=56, 12 heads, T=512,
     bf16, key mask, dropout): each kernel's jaxpr has the equations and
-    the two conds (_init, _finalize) it had before the causal skip, the
-    grid is the full one, and no index map computes anything. A skip that
-    leaks into the non-causal path changes one of these."""
+    the two conds (_init, _finalize) it is known by, the grid is the full
+    one, and no index map computes anything. A skip that leaks into the
+    non-causal path changes one of these."""
     e = _kernel_builds(False, B=56, H=12, T=512)[kernel]
     body = e.params['jaxpr']
     nested = list(_walk(body))
@@ -275,29 +285,34 @@ def one_chip(four_chips):
     return SingleDeviceSharding(four_chips[0])
 
 
-@pytest.mark.parametrize('causal,B,T', [(True, 8, 1024), (False, 56, 512)],
-                         ids=['gpt2_t1024_causal', 'bert_t512'])
+@pytest.mark.parametrize('causal,B,T', [(True, 24, 1024), (False, 56, 512),
+                                        (False, 224, 128)],
+                         ids=['gpt2_t1024_causal', 'bert_t512', 'bert_t128'])
 def test_mosaic_compiles_the_kernels_for_a_described_v5e(one_chip, causal,
                                                          B, T):
-    """Forward, dq and dk/dv at the cells' shapes and default blocks
-    (GPT-2: BH=96, T=1024, D=64, bf16, dropout 0.1): a guard or an index
-    map Mosaic refuses fails here, at no chip time."""
+    """Forward, dq and dk/dv at the cells' shapes and default blocks, as
+    the models call them: the fused (N, T, 3*768) projection addressed
+    three times, two 64-wide heads to a lane block, key mask and dropout
+    on. A lane mask, a guard or an index map Mosaic refuses fails here, at
+    no chip time; and no copy of the activation is left round the calls."""
     H, D = 12, 64
-    x = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one_chip)
+    qkv = jax.ShapeDtypeStruct((B, T, 3 * H * D), jnp.bfloat16,
+                               sharding=one_chip)
     km = jax.ShapeDtypeStruct((B, T), jnp.float32, sharding=one_chip)
     seed = jax.ShapeDtypeStruct((), jnp.uint32, sharding=one_chip)
 
-    def loss(q, k, v, km, seed):
-        out = pa.flash_attention(q, k, v, key_mask=km, causal=causal,
-                                 dropout_p=0.1, dropout_seed=seed,
-                                 interpret=False)
+    def loss(qkv, km, seed):
+        out = pa.flash_mha((qkv,), H, key_mask=km, causal=causal,
+                           dropout_p=0.1, dropout_seed=seed,
+                           interpret=False)
         return jnp.sum(out.astype(jnp.float32))
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        x, x, x, km, seed).compile().as_text()
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        qkv, km, seed).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     for name in ('mxtpu_flash_fwd', 'mxtpu_flash_bwd_dq',
                  'mxtpu_flash_bwd_dkv'):
         assert name in text
+    assert not re.search(r' transpose\(| copy\(%?(qkv|pallas_call)', text)
 
 
 def test_a_train_step_lowers_and_plans_for_a_described_mesh(four_chips):

@@ -490,7 +490,7 @@ def test_mha_auto_route_propagates_a_kernel_failure(monkeypatch):
     def broken(*_a, **_k):
         raise RuntimeError('Mosaic said no')
     monkeypatch.setattr(pallas_attention, 'pallas_available', lambda: True)
-    monkeypatch.setattr(pallas_attention, 'flash_attention', broken)
+    monkeypatch.setattr(pallas_attention, 'flash_mha', broken)
     q = jnp.asarray(_r(2, 16, 16))
     before = dict(attn_ops.route_counts)
     with warnings.catch_warnings():
@@ -518,9 +518,9 @@ def test_flash_attention_interpret_mode_is_explicit(monkeypatch):
     from mxnet_tpu.ops import pallas_attention as pa
     seen = []
 
-    def spy(qf, kf, vf, km, meta, causal, dropout_p, interpret, bh_split):
+    def spy(arrays, km, meta, heads, causal, dropout_p, interpret, h_all):
         seen.append(interpret)
-        return qf
+        return arrays[0]
     monkeypatch.setattr(pa, '_flash', spy)
     q = jnp.asarray(_r(1, 2, 16, 8))
     pa.flash_attention(q, q, q, interpret=False)
@@ -536,15 +536,19 @@ def test_flash_kernel_maps_over_the_mesh_bit_identically():
     """Inside attention.mesh_placement the kernel runs per shard under
     shard_map (a sharded Mosaic call does not lower otherwise): output and
     gradients — in-kernel dropout included — are bit-identical to the
-    unsharded call, with the batch split over dp and the heads over tp."""
+    unsharded call, with the batch split over dp and the heads over tp
+    (three arrays, their columns sharded), and with the batch alone split
+    under the fused projection (one array, addressed three times). D = 64:
+    a shard then holds whole 128-lane blocks of two heads, as the
+    unsharded call does, so every contraction is the same one."""
     import jax
     import jax.numpy as jnp
     import pytest
     from jax.sharding import NamedSharding, PartitionSpec as P
     from mxnet_tpu.base import MXNetError
-    from mxnet_tpu.ops import attention as A
+    from mxnet_tpu.ops import attention as A, pallas_attention
     from mxnet_tpu.parallel import make_mesh
-    N, T, H, D = 4, 16, 4, 8
+    N, T, H, D = 4, 16, 4, 64
     q, k, v = (jnp.asarray(_r(N, T, H * D)) for _ in range(3))
     mask = (jnp.arange(T)[None, None, None, :] <
             jnp.array([9, 16, 12, 16])[:, None, None, None])
@@ -567,6 +571,37 @@ def test_flash_kernel_maps_over_the_mesh_bit_identically():
     assert onp.array_equal(onp.asarray(out), onp.asarray(ref))
     for a, b in zip(g, g_ref):
         assert onp.array_equal(onp.asarray(a), onp.asarray(b))
+
+    # the fused projection: the batch axes shard the one array, and the
+    # kernels address it three times on every shard
+    def fused_loss(qkv, mask):
+        out = A.self_attention(qkv, mask, num_heads=H, dropout_p=0.3,
+                               use_pallas=True, dropout_key=key)
+        return jnp.sum(jnp.tanh(out)), out
+    fused_grad = jax.value_and_grad(fused_loss, has_aux=True)
+    qkv = jnp.concatenate([q, k, v], axis=-1)
+    before = dict(pallas_attention.head_blocks)
+
+    def fused_mapped(qkv, mask):
+        with A.mesh_placement(mesh, ('dp',), ()):
+            return fused_grad(qkv, mask)
+    (_, out), g_qkv = jax.jit(fused_mapped, in_shardings=(sh,) * 2)(qkv, mask)
+    assert onp.array_equal(onp.asarray(out), onp.asarray(ref))
+    assert onp.array_equal(onp.asarray(g_qkv),
+                           onp.concatenate([onp.asarray(a) for a in g_ref],
+                                           axis=-1))
+    assert {key_: n - before.get(key_, 0)
+            for key_, n in pallas_attention.head_blocks.items()
+            if n != before.get(key_, 0)} == {
+        (kind, H, D, 2, True): 1 for kind in ('fwd', 'bwd_dq', 'bwd_dkv')}
+    # heads whose shard would hold no whole lane block (6 heads of 64 over
+    # tp=2: 192 columns) stay whole on every chip of the head axes
+    six = jnp.asarray(_r(N, T, 3 * 6 * D))
+    whole = A.self_attention(six, num_heads=6, use_pallas=True)
+    with A.mesh_placement(mesh, ('dp',), ('tp',)):
+        kept = jax.jit(lambda x: A.self_attention(
+            x, num_heads=6, use_pallas=True), in_shardings=sh)(six)
+    assert onp.array_equal(onp.asarray(kept), onp.asarray(whole))
     # a batch that does not divide cannot be mapped: say so
     with A.mesh_placement(make_mesh((8,), ('dp',)), ('dp',), ()):
         with pytest.raises(MXNetError, match='does not divide'):

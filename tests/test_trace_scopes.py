@@ -219,6 +219,30 @@ def test_every_pallas_call_carries_its_name(fn, shape, expected):
     assert sorted(set(_pallas_calls(jaxpr.jaxpr))) == sorted(expected)
 
 
+@pytest.mark.parametrize('use_pallas', [False, True], ids=['xla', 'pallas'])
+def test_attn_layout_is_the_routes_that_compute_head_major(use_pallas):
+    """The XLA route pays (N,T,H*D) <-> (N,H,T,D) under ``attn_layout``,
+    forward and backward; the Pallas route has no such scope and no
+    transpose: the kernels address the arrays as they are."""
+    from mxnet_tpu.ops import attention
+    from test_attention_layout import _walk as outside_kernels
+
+    def loss(qkv):
+        return jnp.sum(attention.self_attention(qkv, num_heads=4,
+                                                use_pallas=use_pallas))
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(jnp.ones((2, 16, 96), jnp.float32))
+    layout = [e.primitive.name for e in outside_kernels(jaxpr.jaxpr)
+              if scopes.ATTN_LAYOUT in str(e.source_info.name_stack)]
+    transposes = [e for e in outside_kernels(jaxpr.jaxpr)
+                  if e.primitive.name == 'transpose'
+                  and e.invars[0].aval.size >= 2 * 16 * 32]
+    if use_pallas:
+        assert not layout and not transposes
+    else:
+        # q, k, v and the result, and their cotangents
+        assert layout.count('transpose') == 8 and len(transposes) >= 8
+
+
 # ---------------------------------------------------------------------------
 # the scope enters no cache key
 # ---------------------------------------------------------------------------
