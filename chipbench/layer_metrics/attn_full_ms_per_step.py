@@ -1,0 +1,10 @@
+"""Layer: kernels (ops/pallas_attention.py). Device time of the flash
+kernels (forward, dq, dk/dv) called under the scope ``attn_full``: the
+full-attention layers'. Ms a traced step, mean over chips. With
+attn_window_ms_per_step it sums to the three flash metrics. None where
+the program has no such scope."""
+from chipbench import scoped
+
+
+def read(run):
+    return scoped.flash_ms_per_step(run, 'attn_full')

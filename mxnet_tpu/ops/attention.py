@@ -173,13 +173,15 @@ def _axes_size(mesh, axes):
     return math.prod(mesh.shape[a] for a in axes)
 
 
-def _flash_route(arrays, H, D, kpm, causal, dropout_p, seed):
+def _flash_route(arrays, H, D, kpm, causal, dropout_p, seed, Hkv=None,
+                 window=None):
     """The Pallas route: the flash kernels on the model's own arrays --
     (q, k, v), each (N, T, H*D), or (qkv,), their fused projection --
     with no transpose, mapped over the mesh when a ``mesh_placement`` is
     active: the batch axes shard dim 0, the head axes the columns. A
     fused array's columns are not one range a head shard (a third of
-    each of q, k and v), so with head axes it is split first."""
+    each of q, k and v), so with head axes it is split first. Grouped
+    heads (``Hkv``) stay whole on every chip of the head axes."""
     from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
     from .pallas_attention import _lane_block, flash_mha
@@ -193,12 +195,14 @@ def _flash_route(arrays, H, D, kpm, causal, dropout_p, seed):
             f"axes {batch_axes} (size {n_b}), so the flash kernel cannot "
             f"be mapped per chip")
     n_h = _axes_size(mesh, head_axes)
-    if H % n_h or _lane_block(H // n_h * D, D) is None:
+    grouped = dict(num_kv_heads=Hkv, window=window)
+    if H % n_h or _lane_block(H // n_h * D, D) is None \
+            or Hkv not in (None, H):
         head_axes = ()      # heads stay whole on every chip of those axes
         n_h = 1
     if n_b * n_h == 1:
         return flash_mha(arrays, H, key_mask=kpm, causal=causal,
-                         dropout_p=dropout_p, dropout_seed=seed)
+                         dropout_p=dropout_p, dropout_seed=seed, **grouped)
     if n_h > 1 and len(arrays) == 1:
         arrays = tuple(jnp.split(arrays[0], 3, axis=-1))
     n_loc, h_loc = N // n_b, H // n_h
@@ -216,7 +220,8 @@ def _flash_route(arrays, H, D, kpm, causal, dropout_p, seed):
         return flash_mha(arrays_, h_loc, key_mask=kpm_, causal=causal,
                          dropout_p=dropout_p, dropout_seed=seed_,
                          bh_base=base,
-                         bh_split=(h_loc, H) if n_h > 1 else None)
+                         bh_split=(h_loc, H) if n_h > 1 else None,
+                         **grouped)
 
     b_spec = batch_axes or None
     ntc = P(b_spec, None, head_axes or None)
@@ -228,7 +233,7 @@ def _flash_route(arrays, H, D, kpm, causal, dropout_p, seed):
 @_reg
 def multi_head_attention(query, key, value, mask=None, num_heads=1,
                          dropout_p=0.0, causal=False, use_pallas='auto',
-                         dropout_key=None):
+                         dropout_key=None, num_kv_heads=None, window=None):
     """Fused MHA on (N, T, H*D)-shaped q/k/v. The TPU-native attention entry.
 
     Mask convention (torch-style, identical on both paths): boolean/integer
@@ -258,9 +263,26 @@ def multi_head_attention(query, key, value, mask=None, num_heads=1,
     seeded from the key), so the T×T probability matrix is never
     materialised even in training; the flagship BERT config (dropout=0.1)
     runs the flash kernel.
+
+    num_kv_heads: grouped-query attention. key and value are
+    (N, T, Hkv*D) and query head h reads key/value head h // (H // Hkv).
+    window: with ``causal``, a query sees the ``window`` keys up to its
+    own: score (i, j) is kept iff 0 <= i - j < window. Both mean the same
+    on the Pallas route (heads of a multiple of 128 columns; the kernels
+    visit the band's cells only) and on the XLA route; the ring route
+    has neither.
     """
+    if window is not None and not causal:
+        raise MXNetError("multi_head_attention: a window is a causal "
+                         "layer's (causal=True)")
+    if num_kv_heads is not None and num_heads % num_kv_heads:
+        raise MXNetError(
+            f"multi_head_attention: {num_heads} query heads do not divide "
+            f"over {num_kv_heads} key/value heads")
     return _attend((query, key, value), mask, num_heads, dropout_p, causal,
-                   use_pallas, dropout_key)
+                   use_pallas, dropout_key,
+                   None if num_kv_heads in (None, num_heads)
+                   else int(num_kv_heads), window)
 
 
 @_reg
@@ -276,12 +298,13 @@ def self_attention(qkv, mask=None, num_heads=1, dropout_p=0.0, causal=False,
                    dropout_key)
 
 
-def _attend(arrays, mask, H, dropout_p, causal, use_pallas, dropout_key):
+def _attend(arrays, mask, H, dropout_p, causal, use_pallas, dropout_key,
+            Hkv=None, window=None):
     """The routes of :func:`multi_head_attention` (``arrays`` = (q, k,
     v)) and :func:`self_attention` ((qkv,))."""
     N, Tq = arrays[0].shape[:2]
     Tk = arrays[-1].shape[1]
-    tot = arrays[-1].shape[2] // (3 if len(arrays) == 1 else 1)
+    tot = arrays[0].shape[2] // (3 if len(arrays) == 1 else 1)
     D = tot // H
 
     def split_heads():
@@ -291,8 +314,11 @@ def _attend(arrays, mask, H, dropout_p, causal, use_pallas, dropout_key):
         q, k, v = arrays if len(arrays) == 3 \
             else jnp.split(arrays[0], 3, axis=-1)
         with jax.named_scope(_scopes.ATTN_LAYOUT):
-            return [x.reshape(N, x.shape[1], H, D).transpose(0, 2, 1, 3)
-                    for x in (q, k, v)]
+            q, k, v = [x.reshape(N, x.shape[1], -1, D).transpose(0, 2, 1, 3)
+                       for x in (q, k, v)]
+            if Hkv is not None:     # a group's query heads read one head
+                k, v = [jnp.repeat(x, H // Hkv, axis=1) for x in (k, v)]
+            return [q, k, v]
 
     def merge_heads(out):
         with jax.named_scope(_scopes.ATTN_LAYOUT):
@@ -307,6 +333,9 @@ def _attend(arrays, mask, H, dropout_p, causal, use_pallas, dropout_key):
     if kpm is not None and not jnp.issubdtype(kpm.dtype, jnp.floating):
         kpm = kpm.astype(jnp.bool_)
 
+    if _seq_parallel and (Hkv is not None or window is not None):
+        raise MXNetError("sequence_parallel: ring attention has neither "
+                         "grouped heads nor a window")
     if _seq_parallel:
         # dropout no longer blocks the ring route: the ring kernel
         # regenerates the keep mask in-kernel from global coordinates
@@ -346,7 +375,8 @@ def _attend(arrays, mask, H, dropout_p, causal, use_pallas, dropout_key):
         from .pallas_attention import flash_legal, pallas_available
         use_pallas = pallas_available() \
             and (mask is None or kpm is not None) \
-            and flash_legal(N * H, Tq, Tk, D, arrays[0].dtype, num_heads=H)
+            and flash_legal(N * H, Tq, Tk, D, arrays[0].dtype, num_heads=H,
+                            num_kv_heads=Hkv)
     if use_pallas:
         if mask is not None and kpm is None:
             raise MXNetError(
@@ -359,7 +389,8 @@ def _attend(arrays, mask, H, dropout_p, causal, use_pallas, dropout_key):
                 else _random.next_key()
             seed = jax.random.bits(key_, (1, 1), jnp.uint32)
         out = _flash_route(arrays, H, D, kpm, causal,
-                           dropout_p if apply_dropout else 0.0, seed)
+                           dropout_p if apply_dropout else 0.0, seed, Hkv,
+                           window)
         route_counts['pallas'] += 1
         return out
 
@@ -370,6 +401,8 @@ def _attend(arrays, mask, H, dropout_p, causal, use_pallas, dropout_key):
                         preferred_element_type=jnp.float32)
     if causal:
         cmask = jnp.tril(jnp.ones((Tq, Tk), bool))
+        if window is not None:
+            cmask &= ~jnp.tril(jnp.ones((Tq, Tk), bool), -int(window))
         scores = jnp.where(cmask, scores, -1e30)
     if mask is not None:
         if jnp.issubdtype(mask.dtype, jnp.floating):

@@ -307,6 +307,36 @@ def layer_norm(data, gamma, beta, axis=-1, eps=1e-5):
     return out * gamma.reshape(shape) + beta.reshape(shape)
 
 
+@_reg
+def rms_norm(data, gamma, eps=1e-6):
+    """Root-mean-square normalisation over the last axis (Zhang &
+    Sennrich, 2019): x / sqrt(mean(x^2) + eps) * gamma, no mean taken off
+    and no bias. The statistics are float32, the result the input's
+    dtype, as :func:`layer_norm`'s."""
+    f32 = data.astype(jnp.float32)
+    out = f32 * lax.rsqrt(jnp.mean(f32 * f32, axis=-1, keepdims=True) + eps)
+    return out.astype(data.dtype) * gamma
+
+
+@_reg
+def rotary_embedding(data, num_heads=1, theta=10000.0, offset=0):
+    """Rotary position embedding (Su et al., 2021) on a (N, T, H*D)
+    projection, rotate-half form: within each head, column c of the first
+    half pairs with column c + D/2, and the pair at position t turns by
+    t * theta^(-2c/D). Angles, sines and cosines are float32; the result
+    is the input's dtype. ``offset`` is the position of row 0."""
+    N, T, C = data.shape
+    D = C // num_heads
+    half = D // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / D)
+    angle = (offset + jnp.arange(T, dtype=jnp.float32))[:, None] * inv_freq
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x = data.reshape(N, T, num_heads, D).astype(jnp.float32)
+    x1, x2 = x[..., :half], x[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(data.dtype).reshape(N, T, C)
+
+
 def add_layer_norm(x, res, gamma, beta, eps=1e-5):
     """LN(x + res) — the transformer residual epilogue, twice per BERT
     layer. Routes to the fused Pallas kernel (ops/pallas_layernorm.py)

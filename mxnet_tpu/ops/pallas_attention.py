@@ -46,6 +46,19 @@ still mask element by element (:func:`_cell`'s ``scores``). All of it hangs on
 the static ``causal`` flag. Each causal build counts its cells in
 ``causal_cells``, and every build its addressing in ``head_blocks``.
 
+A ``window`` (a causal layer that sees the ``window`` keys up to its
+own) is a second inequality beside ``_cell_live`` (:func:`_cell_in_window`):
+the table lists the band's cells only, and inside a cell the two masks are
+one unsigned comparison of i - j. Grouped-query heads (``num_kv_heads``
+fewer than the query heads, a head a whole lane block): the forward and dq
+grids run over the query heads and read key/value lane block ``l // rep``
+through the index map; the dk/dv grid runs over the key/value heads, its
+q, dO, lse and delta blocks a whole group of ``rep`` query heads wide, and
+adds the group's heads into the one dk and dv inside the kernel. Each
+windowed build counts its cells in ``window_cells``. Without either, the
+builds are what they were, equation for equation
+(tests/test_causal_skip.py).
+
 Attention dropout runs INSIDE the kernels: the keep mask is a
 counter-based hash (murmur3 finalizer) of the global (batch·head, q, k)
 element coordinates mixed with a per-call seed, so the forward and both
@@ -102,6 +115,10 @@ _CAUSAL_COMPILER_PARAMS = pltpu.CompilerParams(
 # Static numbers; a non-causal build records nothing.
 causal_cells = {}
 
+# of a windowed build (a causal one besides): {(kind, live cells, cells a
+# causal build without the window lists): kernel builds}
+window_cells = {}
+
 # and of the addressing: {(kind, H, D, heads per lane block, fused):
 # kernel builds}, ``fused`` being whether q, k and v are column ranges of
 # one (N, T, 3*H*D) array. Static numbers, counted at build.
@@ -143,7 +160,14 @@ def _cell_live(qb, kb, bq, bk):
     return qb * bq + (bq - 1) >= kb * bk
 
 
-def _causal_cell_table(kind, nq, nk, bq, bk, by_row):
+def _cell_in_window(qb, kb, bq, bk, window):
+    """The second inequality of a windowed causal layer (score (i, j) is
+    kept iff 0 <= i - j < window): does the cell hold a pair nearer than
+    ``window``? True iff its first query row is that near its last key."""
+    return qb * bq - (kb * bk + bk - 1) < window
+
+
+def _causal_cell_table(kind, nq, nk, bq, bk, by_row, window=None):
     """int32 (4, n): the cells a causal kernel visits, in grid order, as
     rows [q-block, k-block, first of its line, last of its line]. A line
     is a q-block row (``by_row``: forward, dq) or a k-block column
@@ -151,18 +175,28 @@ def _causal_cell_table(kind, nq, nk, bq, bk, by_row):
     accumulators add up in the same order. A line without a live cell (a
     k-block no query sees, when Tk > Tq) keeps one dead cell: it adds
     exact zeros, as every dead cell used to, and the line's output is
-    still initialised and written. Counts the build in ``causal_cells``."""
-    table, live_cells = [], 0
+    still initialised and written. Counts the build in ``causal_cells``.
+    With a ``window`` only the band's cells are listed
+    (:func:`_cell_in_window`), and the build is counted in
+    ``window_cells`` beside the causal cells it would have had."""
+    table, live_cells, causal_live = [], 0, 0
     for outer in range(nq if by_row else nk):
         line = [(outer, inner) if by_row else (inner, outer)
                 for inner in range(nk if by_row else nq)]
         live = [cell for cell in line if _cell_live(*cell, bq, bk)]
+        causal_live += len(live)
+        if window is not None:
+            live = [cell for cell in live
+                    if _cell_in_window(*cell, bq, bk, window)]
         live_cells += len(live)
         live = live or line[-1:]
         table += [(qb, kb, cell == 0, cell == len(live) - 1)
                   for cell, (qb, kb) in enumerate(live)]
     key = (kind, live_cells, nq * nk)
     causal_cells[key] = causal_cells.get(key, 0) + 1
+    if window is not None:
+        key = (kind, live_cells, causal_live)
+        window_cells[key] = window_cells.get(key, 0) + 1
     return onp.asarray(table, onp.int32).T
 
 
@@ -182,7 +216,7 @@ def _step_cell(cells_ref, q_axis):
             lambda: cells_ref[2, cell] == 1, lambda: cells_ref[3, cell] == 1)
 
 
-def _block_specs(Gn, hb, bq, bk, W, causal, q_axis=2):
+def _block_specs(Gn, hb, bq, bk, W, causal, q_axis=2, rep=1, wide=False):
     """The BlockSpecs of one call, by role, as (seq, col, mask):
     ``seq(side, off)`` a (Gn, bq | bk, W) block of an (N, T, columns)
     array on the 'q' or the 'k' side, ``off`` lane blocks into the
@@ -192,7 +226,13 @@ def _block_specs(Gn, hb, bq, bk, W, causal, q_axis=2):
     The grid is (row group b, lane block l, then the full plane with
     q-blocks on ``q_axis``, or the listed cell with the cell table as
     scalar-prefetch operand, ``causal``). No index map computes
-    anything, but for an ``off``."""
+    anything, but for an ``off``.
+
+    Grouped-query heads (``rep`` query heads to a key/value head, a head
+    a lane block): where the lane blocks of the grid are the query
+    heads' (forward, dq), the 'k' side reads lane block ``l // rep``;
+    where they are the key/value heads' (dk/dv, ``wide``), the 'q' side
+    and ``col`` are ``rep`` heads wide, the whole group of head ``l``."""
     if causal:
         def qi(c, cells): return cells[0, c]
         def ki(c, cells): return cells[1, c]
@@ -202,14 +242,22 @@ def _block_specs(Gn, hb, bq, bk, W, causal, q_axis=2):
     else:
         def qi(j, i): return i
         def ki(j, i): return j
+    q_wide = rep if wide else 1
 
     def seq(side, off=0):
         rows, at = (bq, qi) if side == 'q' else (bk, ki)
+        if side == 'q' and q_wide > 1:
+            return pl.BlockSpec((Gn, rows, q_wide * W),
+                                lambda b, l, *s: (b, at(*s), l))
+        if side == 'k' and rep > 1 and not wide:
+            return pl.BlockSpec((Gn, rows, W),
+                                lambda b, l, *s: (b, at(*s), l // rep))
         if off:
             return pl.BlockSpec((Gn, rows, W),
                                 lambda b, l, *s: (b, at(*s), l + off))
         return pl.BlockSpec((Gn, rows, W), lambda b, l, *s: (b, at(*s), l))
-    col = pl.BlockSpec((Gn, hb, bq, 1), lambda b, l, *s: (b, l, qi(*s), 0))
+    col = pl.BlockSpec((Gn, q_wide * hb, bq, 1),
+                       lambda b, l, *s: (b, l, qi(*s), 0))
     mask = pl.BlockSpec((Gn, 1, bk), lambda b, l, *s: (b, 0, ki(*s)))
     return seq, col, mask
 
@@ -332,7 +380,7 @@ def _counter_keep(seed, bh, rows, cols, rate):
 
 
 def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
-          k_len, dropout_p, h_all):
+          k_len, dropout_p, h_all, window=None, rep=1):
     """What the heads of one grid step share, computed once a step:
     (own, is-first, is-last, scores, keep).
 
@@ -354,7 +402,14 @@ def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
     holds one shard: ``meta_ref[0, 1]`` is the global id of its first
     (row, head) and ``h_all`` the heads of the whole problem, its own
     being fewer when the heads are sharded too — so a sharded run draws
-    the same dropout bits as the unsharded one."""
+    the same dropout bits as the unsharded one. Where a lane block of the
+    grid stands for ``rep`` query heads (the dk/dv kernel of grouped-query
+    heads), ``hh`` counts through all of them.
+
+    ``window``: keep a score iff 0 <= i - j < window. The difference read
+    as an unsigned number makes that one comparison, as the causal mask
+    alone is, in the cells the window's edge crosses and (needlessly) in
+    the others."""
     own = _lane_masks(bq, W, D)
     qb, kb, first, last = _step_cell(cells_ref, q_axis)
     k_pos = kb * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
@@ -362,7 +417,11 @@ def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
     under = None
     if causal:
         q_pos = qb * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-        under = q_pos >= k_pos
+        if window is None:
+            under = q_pos >= k_pos
+        else:
+            under = lax.bitcast_convert_type(
+                q_pos - k_pos, jnp.uint32) < jnp.uint32(window)
 
     def scores(q, k, kmask_row):
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -377,7 +436,8 @@ def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
         jnp.uint32(kb * bk) + lax.broadcasted_iota(jnp.uint32, (bq, bk), 1))
     seed = meta_ref[0, 0]
     bh0 = meta_ref[0, 1] + (pl.program_id(0) * (Gn * h_all)
-                            + pl.program_id(1) * len(own)).astype(jnp.uint32)
+                            + pl.program_id(1) * (len(own) * rep)
+                            ).astype(jnp.uint32)
 
     def keep(g, hh):
         return _keep_of(ids, seed, bh0 + jnp.uint32(g * h_all + hh),
@@ -411,7 +471,7 @@ def _own(x, mask):
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
                    o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                    scale, causal, D, bq, bk, k_len, dropout_p, h_all,
-                   cells_ref=None):
+                   window=None, cells_ref=None):
     """One (row-group, lane-block, q-block, k-block) cell. Refs are VMEM
     blocks: q (Gn, bq, W), k/v (Gn, bk, W), kmask (Gn, 1, bk) additive
     f32, o (Gn, bq, W), lse (Gn, hb, bq, 1), hb = W // D heads side by
@@ -422,7 +482,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
     Gn, _, W = q_ref.shape
     own, first, last, scores, keep = _cell(
         cells_ref, 2, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
-        dropout_p, h_all)
+        dropout_p, h_all, window)
     hb = len(own)
 
     @pl.when(first())
@@ -468,19 +528,29 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
             o_ref[g] = out.astype(o_ref.dtype)
 
 
-def _addressing(arrays, H, kind):
+def _addressing(arrays, H, kind, Hkv=None):
     """The static numbers of one build from its operands' shapes:
     (N, Tq, Tk, C, D, W, hb, Gn, bq, bk). ``arrays`` is (q, k, v), each
-    (N, T, C = H*D), or (qkv,), their (N, T, 3C) fusion."""
+    (N, T, C = H*D), or (qkv,), their (N, T, 3C) fusion. With ``Hkv``
+    key/value heads to the H query heads, k and v are (N, T, Hkv*D) and a
+    head is a lane block."""
     N, Tq = arrays[0].shape[:2]
     Tk = arrays[-1].shape[1]
-    C = arrays[-1].shape[2] // (3 if len(arrays) == 1 else 1)
+    C = arrays[0].shape[2] // (3 if len(arrays) == 1 else 1)
     D = C // H
     if _lane_block(C, D) is None:
         raise ValueError(
             f"flash attention: {H} heads of {D} columns do not come apart "
             f"into 128-lane blocks of whole heads (flash_legal says so)")
     W, hb = _lane_block(C, D)
+    if Hkv not in (None, H) and (
+            hb != 1 or H % Hkv or len(arrays) != 3
+            or arrays[-1].shape[2] != Hkv * D):
+        raise ValueError(
+            f"flash attention: {H} query heads over {Hkv} key/value heads "
+            f"of {D} columns: a group needs whole 128-lane heads, H a "
+            f"multiple of Hkv and k, v of Hkv*D columns (flash_legal says "
+            f"so)")
     G, bq, bk = _block_sizes(N * H, Tq, Tk, D, arrays[0].dtype, kind)
     return N, Tq, Tk, C, D, W, hb, _rows_per_step(N, G, hb), bq, bk
 
@@ -516,15 +586,19 @@ def _count_build(kind, H, D, hb, offs):
     head_blocks[key] = head_blocks.get(key, 0) + 1
 
 
-def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all):
+def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
+                Hkv=None, window=None):
     """arrays: (q, k, v), each (N, T, H*D) as the model holds them, or
     (qkv,), the fused (N, T, 3*H*D) projection; the kernels' blocks
     address them in place (:func:`_block_specs`). kmask: (N, Tk) additive
     f32 or None. meta: (1, 2) uint32 [dropout seed, global batch*head
-    base]. Returns (out (N, Tq, H*D), lse (N, H, Tq)), sliced back from
-    the blocks' padding -- the backward re-pads them for its own
-    (possibly different) tiling."""
-    N, Tq, Tk, C, D, W, hb, Gn, bq, bk = _addressing(arrays, H, 'fwd')
+    base]. ``Hkv``: key/value heads where they are fewer than the H
+    query heads (k, v: (N, T, Hkv*D)); ``window``: with ``causal``, keep
+    a score iff i - j < window. Returns (out (N, Tq, H*D), lse
+    (N, H, Tq)), sliced back from the blocks' padding -- the backward
+    re-pads them for its own (possibly different) tiling."""
+    N, Tq, Tk, C, D, W, hb, Gn, bq, bk = _addressing(arrays, H, 'fwd', Hkv)
+    rep = H // (Hkv or H)
     dtype = arrays[0].dtype
     nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
     pq, pk = nq * bq - Tq, nk * bk - Tk
@@ -533,10 +607,11 @@ def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all):
 
     kernel = functools.partial(
         _fa_fwd_kernel, scale=1.0 / math.sqrt(D), causal=causal, D=D, bq=bq,
-        bk=bk, k_len=Tk, dropout_p=float(dropout_p), h_all=h_all)
-    seq, col, mask = _block_specs(Gn, hb, bq, bk, W, causal)
-    cells = _causal_cell_table('fwd', nq, nk, bq, bk, by_row=True) \
-        if causal else None
+        bk=bk, k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
+        window=window)
+    seq, col, mask = _block_specs(Gn, hb, bq, bk, W, causal, rep=rep)
+    cells = _causal_cell_table('fwd', nq, nk, bq, bk, by_row=True,
+                               window=window) if causal else None
     out, lse = _call(
         kernel, cells, (N // Gn, C // W) + (() if causal else (nq, nk)),
         in_specs=[seq('q', qo), seq('k', ko), seq('k', vo), mask,
@@ -563,13 +638,13 @@ def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all):
 def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                   lse_ref, delta_ref, dq_ref, dq_acc, *,
                   scale, causal, D, bq, bk, k_len, dropout_p, h_all,
-                  cells_ref=None):
+                  window=None, cells_ref=None):
     """dq for one q-block of one lane block, accumulated over k-blocks
     (grid (N/Gn, C/W, nq, nk)), written in the operands' dtype."""
     Gn, _, W = q_ref.shape
     own, first, last, scores, keep = _cell(
         cells_ref, 2, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
-        dropout_p, h_all)
+        dropout_p, h_all, window)
     hb = len(own)
 
     @pl.when(first())
@@ -606,14 +681,17 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                    scale, causal, D, bq, bk, k_len, dropout_p, h_all,
-                   cells_ref=None):
+                   window=None, rep=1, cells_ref=None):
     """dk/dv for one k-block of one lane block, accumulated over q-blocks
     (grid (N/Gn, C/W, nk, nq): k-block is program 2, q-block program 3),
-    written in the operands' dtype."""
-    Gn, _, W = q_ref.shape
+    written in the operands' dtype. With ``rep`` query heads to a
+    key/value head the lane blocks are the key/value heads', q, dO, lse
+    and delta come ``rep`` heads wide, and the group's heads add into
+    the one dk and dv here, a head at a time."""
+    Gn, _, W = k_ref.shape
     own, first, last, scores, keep = _cell(
         cells_ref, 3, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
-        dropout_p, h_all)
+        dropout_p, h_all, window, rep)
     hb = len(own)
 
     @pl.when(first())
@@ -622,31 +700,35 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     for g in range(Gn):
-        q, k, kmask_row = q_ref[g], k_ref[g], kmask_ref[g]
+        k, kmask_row = k_ref[g], kmask_ref[g]
         v32 = v_ref[g].astype(jnp.float32)                # (bk, W)
-        do32 = do_ref[g].astype(jnp.float32)              # (bq, W)
         dk, dv = dk_acc[g], dv_acc[g]
-        for hh in range(hb):
-            # q and dO with the other heads' columns zeroed: what they
-            # are contracted into lands in this head's columns alone
-            q_own, do_own = _own(q, own[hh]), _own(do32, own[hh])
-            s = scores(q_own, k, kmask_row)
-            p = jnp.exp(s - lse_ref[g, hh])               # (bq, bk)
-            kept = keep(g, hh)
-            pv = p if kept is None else p * kept
-            # dv_j += sum_i P_drop_ij dO_i
-            dv = dv + lax.dot_general(
-                pv, do_own, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)       # (bk, W)
-            dp = lax.dot_general(
-                do_own, v32, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)       # (bq, bk)
-            if kept is not None:
-                dp = dp * kept
-            ds = p * (dp - delta_ref[g, hh]) * scale      # (bq, bk)
-            dk = dk + lax.dot_general(
-                ds, q_own.astype(jnp.float32), (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)       # (bk, W)
+        for r in range(rep):
+            cols = slice(None) if rep == 1 else slice(r * W, (r + 1) * W)
+            q = q_ref[g, :, cols]
+            do32 = do_ref[g, :, cols].astype(jnp.float32)     # (bq, W)
+            for hh in range(hb):
+                head = r * hb + hh
+                # q and dO with the other heads' columns zeroed: what they
+                # are contracted into lands in this head's columns alone
+                q_own, do_own = _own(q, own[hh]), _own(do32, own[hh])
+                s = scores(q_own, k, kmask_row)
+                p = jnp.exp(s - lse_ref[g, head])             # (bq, bk)
+                kept = keep(g, head)
+                pv = p if kept is None else p * kept
+                # dv_j += sum_i P_drop_ij dO_i
+                dv = dv + lax.dot_general(
+                    pv, do_own, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)       # (bk, W)
+                dp = lax.dot_general(
+                    do_own, v32, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)       # (bq, bk)
+                if kept is not None:
+                    dp = dp * kept
+                ds = p * (dp - delta_ref[g, head]) * scale    # (bq, bk)
+                dk = dk + lax.dot_general(
+                    ds, q_own.astype(jnp.float32), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)       # (bk, W)
         dk_acc[g], dv_acc[g] = dk, dv
 
     @pl.when(last())
@@ -656,11 +738,12 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
 
 
 def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
-                 h_all, out, lse, do):
+                 h_all, Hkv, window, out, lse, do):
     """Pallas backward: recompute probability blocks from the saved LSE.
     Returns the cotangents of ``arrays``, in their dtype: (dq, dk, dv),
     or (dqkv,), the three side by side."""
-    N, Tq, Tk, C, D, W, hb, Gn, bq, bk = _addressing(arrays, H, 'bwd')
+    N, Tq, Tk, C, D, W, hb, Gn, bq, bk = _addressing(arrays, H, 'bwd', Hkv)
+    rep = H // (Hkv or H)
     dtype = arrays[0].dtype
     nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
     pq, pk = nq * bq - Tq, nk * bk - Tk
@@ -684,27 +767,34 @@ def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
         head_of, precision=lax.Precision.HIGHEST)[..., None]
 
     kw = dict(scale=1.0 / math.sqrt(D), causal=causal, D=D, bq=bq, bk=bk,
-              k_len=Tk, dropout_p=float(dropout_p), h_all=h_all)
+              k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
+              window=window)
     operands = (q, k, v, _mask_operand(kmask, N, Tk, pk), meta, do,
                 lse[..., None], delta)
-    grid = (N // Gn, C // W)
     calls = []
     for kind, kernel, q_axis, plane, side, n_out in (
             ('bwd_dq', _fa_dq_kernel, 2, (nq, nk), 'q', 1),
             ('bwd_dkv', _fa_dkv_kernel, 3, (nk, nq), 'k', 2)):
         _count_build(kind, H, D, hb, (qo, ko, vo))
-        seq, col, mask = _block_specs(Gn, hb, bq, bk, W, causal, q_axis)
+        # with grouped heads the dk/dv grid runs over the key/value heads,
+        # each step a whole group of query heads wide
+        wide = rep > 1 and side == 'k'
+        group = {'rep': rep} if wide else {}
+        seq, col, mask = _block_specs(Gn, hb, bq, bk, W, causal, q_axis,
+                                      rep=rep, wide=wide)
         rows, blk = (nq * bq, bq) if side == 'q' else (nk * bk, bk)
-        cells = _causal_cell_table(kind, nq, nk, bq, bk,
-                                   by_row=side == 'q') if causal else None
+        cols = C // rep if wide else C
+        cells = _causal_cell_table(kind, nq, nk, bq, bk, by_row=side == 'q',
+                                   window=window) if causal else None
         calls.append(_call(
-            functools.partial(kernel, **kw), cells,
-            grid + (() if causal else plane),
+            functools.partial(kernel, **kw, **group), cells,
+            (N // Gn, cols // W) + (() if causal else plane),
             in_specs=[seq('q', qo), seq('k', ko), seq('k', vo), mask,
                       pl.BlockSpec(memory_space=pltpu.SMEM), seq('q'),
                       col, col],
             out_specs=[seq(side)] * n_out,
-            out_shape=[jax.ShapeDtypeStruct((N, rows, C), dtype)] * n_out,
+            out_shape=[jax.ShapeDtypeStruct(
+                (N, rows, C if side == 'q' else C // rep), dtype)] * n_out,
             scratch_shapes=[pltpu.VMEM((Gn, blk, W), jnp.float32)] * n_out,
             interpret=interpret,
             name=_scopes.FLASH_BWD_DQ if side == 'q'
@@ -720,23 +810,25 @@ def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
 # custom-vjp wrapper
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
+           Hkv=None, window=None):
     out, _ = _fa_forward(arrays, kmask, meta, H, causal, dropout_p,
-                         interpret, h_all)
+                         interpret, h_all, Hkv, window)
     return out
 
 
-def _flash_fwd(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all):
+def _flash_fwd(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
+               Hkv, window):
     out, lse = _fa_forward(arrays, kmask, meta, H, causal, dropout_p,
-                           interpret, h_all)
+                           interpret, h_all, Hkv, window)
     return out, (arrays, kmask, meta, out, lse)
 
 
-def _flash_bwd(H, causal, dropout_p, interpret, h_all, res, do):
+def _flash_bwd(H, causal, dropout_p, interpret, h_all, Hkv, window, res, do):
     arrays, kmask, meta, out, lse = res
     grads = _fa_backward(arrays, kmask, meta, H, causal, dropout_p,
-                         interpret, h_all, out, lse, do)
+                         interpret, h_all, Hkv, window, out, lse, do)
     dmask = None if kmask is None else jnp.zeros_like(kmask)
     dmeta = onp.zeros(meta.shape, jax.dtypes.float0)
     return grads, dmask, dmeta
@@ -745,14 +837,18 @@ def _flash_bwd(H, causal, dropout_p, interpret, h_all, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_legal(BH, Tq, Tk, D, dtype, num_heads=1) -> bool:
+def flash_legal(BH, Tq, Tk, D, dtype, num_heads=1, num_kv_heads=None) -> bool:
     """Can the forward AND backward kernels be built for this shape at
     the block sizes they would use? The heads' columns have to come
-    apart into lane blocks (:func:`_lane_block`), and the static Mosaic
-    rules of ops/autotune.check_candidate hold for what
-    :func:`_block_sizes` resolves — the verdict ``multi_head_attention``
-    routes on."""
+    apart into lane blocks (:func:`_lane_block`), grouped-query heads
+    (``num_kv_heads`` fewer than ``num_heads``) have to be whole lane
+    blocks each, and the static Mosaic rules of
+    ops/autotune.check_candidate hold for what :func:`_block_sizes`
+    resolves — the verdict ``multi_head_attention`` routes on."""
     from . import autotune
+    if num_kv_heads not in (None, num_heads) and (
+            D % _LANES or num_heads % num_kv_heads):
+        return False
     return _lane_block(num_heads * D, D) is not None and all(
         autotune.check_candidate(
             BH, Tq, Tk, D, jnp.dtype(dtype), kind,
@@ -762,7 +858,7 @@ def flash_legal(BH, Tq, Tk, D, dtype, num_heads=1) -> bool:
 
 def flash_mha(arrays, num_heads, key_mask=None, causal=False, dropout_p=0.0,
               dropout_seed=None, interpret=None, bh_base=None,
-              bh_split=None):
+              bh_split=None, num_kv_heads=None, window=None):
     """Flash attention on the model's own arrays. ``arrays``: (q, k, v),
     each (N, T, H*D), or (qkv,), the fused (N, T, 3*H*D) projection whose
     thirds they are; the kernels read them in place, two 64-wide heads to
@@ -771,6 +867,12 @@ def flash_mha(arrays, num_heads, key_mask=None, causal=False, dropout_p=0.0,
     (True = keep). dropout_p: in-kernel attention-probability dropout;
     dropout_seed: uint32 scalar/array seeding the kernel PRNG (required
     when dropout_p > 0). Returns (N, Tq, H*D).
+
+    num_kv_heads: grouped-query attention, k and v being (N, T, Hkv*D)
+    and query head h reading key/value head h // (H // Hkv); a head has
+    to be a multiple of 128 columns. window: with ``causal``, a query
+    sees the ``window`` keys up to its own (score (i, j) kept iff
+    0 <= i - j < window); the kernels then visit the band's cells only.
 
     interpret: True runs the kernels through the Pallas interpreter,
     False compiles them with Mosaic (and fails where there is no TPU),
@@ -800,8 +902,14 @@ def flash_mha(arrays, num_heads, key_mask=None, causal=False, dropout_p=0.0,
         else jnp.asarray(bh_base, jnp.uint32).reshape(())
     meta = jnp.stack([seed, base]).reshape(1, 2)
     h_all = int(num_heads) if bh_split is None else int(bh_split[1])
+    if window is not None and not causal:
+        raise ValueError("flash_mha: a window is a causal layer's")
+    if num_kv_heads is not None and int(num_kv_heads) == int(num_heads):
+        num_kv_heads = None
     return _flash(tuple(arrays), key_mask, meta, int(num_heads), causal,
-                  dropout_p, bool(interpret), h_all)
+                  dropout_p, bool(interpret), h_all,
+                  None if num_kv_heads is None else int(num_kv_heads),
+                  None if window is None else int(window))
 
 
 def flash_attention(q, k, v, key_mask=None, **kwargs):

@@ -15,7 +15,8 @@ program runs the same instructions with or without them:
   name its parent knows it by, so an op reads
   ``bertmodel0/encoder/bertlayer3/bertselfattention0/qkv/dot_general``;
 * a hand-written stretch that is no block of its own (models/bert.py,
-  models/gpt.py, ops/attention.py): a plain word, listed below.
+  models/gpt.py, models/decoder.py, ops/attention.py, ops/moe.py): a
+  plain word, listed below.
 
 A Pallas kernel is named by ``pallas_call(name=...)``: the name becomes
 the custom call's instruction name and the last scope of its ``op_name``.
@@ -44,6 +45,13 @@ ATTN_CORE = 'attn_core'         # scores, softmax, dropout, weighted sum
 FFN1 = 'ffn1'                   # first feed-forward matmul + GELU
 LN1, LN2 = 'ln1', 'ln2'         # residual add + LayerNorm
 LM_HEAD = 'lm_head'             # GPT's tied output projection
+ATTN_SWA = 'attn_swa'           # decoder: a windowed layer's attention core
+ATTN_FULL = 'attn_full'         # decoder: a full-attention layer's
+ROPE = 'rope'                   # rotary embedding of q and k
+RMSNORM = 'rmsnorm'             # RMS normalisation (no block of its own)
+MOE_ROUTE = 'moe_route'         # router matmul, softmax, top-k, sort, the
+                                # dispatch gather and the combine
+MOE_EXPERTS = 'moe_experts'     # the grouped matmuls and ReGLU between them
 
 # Pallas kernels
 FLASH_FWD = 'mxtpu_flash_fwd'
@@ -51,3 +59,5 @@ FLASH_BWD_DQ = 'mxtpu_flash_bwd_dq'
 FLASH_BWD_DKV = 'mxtpu_flash_bwd_dkv'
 FFN_GELU = 'mxtpu_ffn_gelu'
 ADD_LAYERNORM = 'mxtpu_add_layernorm'
+GROUPED_MATMUL = 'mxtpu_grouped_matmul'     # ops/moe.py: forward and both
+                                            # backward products

@@ -6,8 +6,10 @@ tests/test_operator.py; that a skipped cell contributed nothing (the same
 bits with the skip patched out); the counts of live cells a build records;
 that a non-causal build is, equation for equation, the kernel it is known
 as; and that Mosaic takes the kernels at the benchmark cells' shapes, the
-fused projection addressed in place, compiled here for a described v5e:2x2
-with no chip.
+fused projection addressed in place, grouped and windowed heads and the
+expert layer's grouped matmuls among them, compiled here for a described
+v5e:2x2 with no chip (every such compile of the suite is in this file:
+only one test file of a run may load the TPU's library).
 """
 import contextlib
 import re
@@ -313,6 +315,58 @@ def test_mosaic_compiles_the_kernels_for_a_described_v5e(one_chip, causal,
                  'mxtpu_flash_bwd_dkv'):
         assert name in text
     assert not re.search(r' transpose\(| copy\(%?(qkv|pallas_call)', text)
+
+
+@pytest.mark.parametrize('window', [4096, None], ids=['window', 'full'])
+def test_mosaic_compiles_grouped_and_windowed_heads_for_a_described_v5e(
+        one_chip, window):
+    """smallthinker_21b.t8192's two kinds of layer (tests/test_window_gqa.py
+    holds their results): one 8192-token sequence, 28 query heads over 4
+    key/value heads of 128, bf16, default blocks. Forward, dq and dk/dv
+    (seven query heads wide) compile."""
+    q = jax.ShapeDtypeStruct((1, 8192, 28 * 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4 * 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        out = pa.flash_mha((q, k, v), 28, causal=True, num_kv_heads=4,
+                           window=window, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ('mxtpu_flash_fwd', 'mxtpu_flash_bwd_dq',
+                 'mxtpu_flash_bwd_dkv'):
+        assert name in text
+
+
+def test_mosaic_compiles_the_grouped_matmuls_for_a_described_v5e(one_chip):
+    """ops/moe.py's kernel at smallthinker_21b.t8192's shapes: 208 row
+    tiles of 256 against 16 experts' (2560, 1536) and (768, 2560) bf16
+    weights, the forward, its transposed-weights twin and the weight
+    gradient with its float32 accumulator."""
+    from mxnet_tpu.ops import moe
+    rows, tile, tiles = moe.plan(8192, 16, 6)
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def products(x, w_gate_up, w_down, tile_expert):
+        gate_up = moe.grouped_matmul(x, w_gate_up, tile_expert, tile, False,
+                                     False)
+        y = moe.grouped_matmul(moe._reglu(gate_up), w_down, tile_expert, tile,
+                               False, False)
+        dx = moe.grouped_matmul(gate_up, w_gate_up, tile_expert, tile, True,
+                                False)
+        dw = moe.grouped_matmul_dw(x, gate_up, tile_expert, tile, 16, False)
+        return y, dx, dw
+    text = jax.jit(products).lower(
+        shaped(tile * tiles, 2560), shaped(16, 2560, 1536),
+        shaped(16, 768, 2560), shaped(tiles, dtype=jnp.int32)
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert 'mxtpu_grouped_matmul' in text
 
 
 def test_a_train_step_lowers_and_plans_for_a_described_mesh(four_chips):

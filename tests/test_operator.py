@@ -518,7 +518,8 @@ def test_flash_attention_interpret_mode_is_explicit(monkeypatch):
     from mxnet_tpu.ops import pallas_attention as pa
     seen = []
 
-    def spy(arrays, km, meta, heads, causal, dropout_p, interpret, h_all):
+    def spy(arrays, km, meta, heads, causal, dropout_p, interpret, h_all,
+            *grouped):
         seen.append(interpret)
         return arrays[0]
     monkeypatch.setattr(pa, '_flash', spy)
