@@ -137,9 +137,12 @@ def fa_block_layouts(BH, Tq, Tk, D, kind, G, bq, bk):
     model's (N, T, H*D) arrays in lane blocks of W columns
     (``pallas_attention._lane_block``: 128 where D divides it, W // D
     heads side by side, else D), G heads a grid step being G // (W // D)
-    batch rows of one lane block. N and H are not in the tuning key, so
-    the arrays are written here one lane block wide: the trailing two
-    dims, which the tile rule reads, are the kernels' own."""
+    batch rows of one lane block. The softmax row statistics (lse,
+    delta) cross HBM with the queries on the lanes, (N, H, 1, Tq) in
+    (Gn, hb, 1, bq) blocks: bq, like bk for the key mask, is a multiple
+    of 128 or the whole padded sequence. N and H are not in the tuning
+    key, so the arrays are written here one lane block wide: the
+    trailing two dims, which the tile rule reads, are the kernels' own."""
     tq, tk = _pad_up(Tq, bq), _pad_up(Tk, bk)
     W = _LANE if _LANE % D == 0 else D
     hb = W // D
@@ -149,13 +152,13 @@ def fa_block_layouts(BH, Tq, Tk, D, kind, G, bq, bk):
         ('k', (rows, tk, W), (Gn, bk, W)),
         ('v', (rows, tk, W), (Gn, bk, W)),
         ('kmask', (rows, 1, tk), (Gn, 1, bk)),
-        ('lse', (rows, hb, tq, 1), (Gn, hb, bq, 1)),
+        ('lse', (rows, hb, 1, tq), (Gn, hb, 1, bq)),
     ]
     if kind == 'fwd':
         layouts.append(('out', (rows, tq, W), (Gn, bq, W)))
     else:
         layouts += [('do', (rows, tq, W), (Gn, bq, W)),
-                    ('delta', (rows, hb, tq, 1), (Gn, hb, bq, 1)),
+                    ('delta', (rows, hb, 1, tq), (Gn, hb, 1, bq)),
                     ('dq', (rows, tq, W), (Gn, bq, W)),
                     ('dk', (rows, tk, W), (Gn, bk, W)),
                     ('dv', (rows, tk, W), (Gn, bk, W))]
@@ -166,6 +169,9 @@ def vmem_bytes(G, bq, bk, D, kind):
     """Scoped-VMEM estimate for one kernel invocation: double-buffered
     IO blocks + f32 scratch accumulators + the live (bq, bk) f32 stack
     temporaries (~3 forward: s/p/pv; ~6 backward: s/p/dp/ds/keep/pv).
+    The 256 columns a row beside D are two 128-lane columns of float32:
+    the forward's m and l, dq's lse and delta turned into columns; the
+    statistics' own (1, bq) row blocks are a few KB and not counted.
     The same arithmetic ``_block_sizes`` has guarded with since round 4."""
     n_tmp = 3 if kind == 'fwd' else 6
     return (2 * G * (bq + 2 * bk) * D * 4

@@ -24,14 +24,34 @@ tens of microseconds, so tiny per-head grids are dispatch-bound; G
 amortises it). Scratch (VMEM) carries the online-softmax state (running
 max m, running sum l, f32 accumulator) across k-blocks; the final k-block
 normalises and writes the output block plus the logsumexp (saved for the
-backward pass).
+backward pass) as a row, the queries on the lanes.
 
 Backward: two Pallas kernels — dq (grid (N/Gn, C/W, Tq/BQ, Tk/BK),
 accumulating over k-blocks) and dk/dv (grid (N/Gn, C/W, Tk/BK, Tq/BQ),
 accumulating over q-blocks) — both recompute the probability block from
 the saved LSE (flash-attention backward recurrence), so live memory stays
 O(T); each rounds its float32 accumulator to the operands' dtype once, as
-it writes.
+it writes. dq holds a cell as the forward does, (bq, bk), queries down the
+sublanes. dk/dv holds it keys down, (bk, bq): s^T = k·q^T, p^T, dp^T =
+v·dO^T, ds^T, so that dv += p^T·dO and dk += ds^T·q are plain products
+and the queries' statistics broadcast down the sublanes as the rows they
+are (:func:`_cell` hands out every mask and the dropout bits in either
+orientation: the same function of (q position, k position)).
+
+The softmax row statistics — lse = m + log l from the forward, delta =
+rowsum(dO·O) from XLA — are float32 (N, H, 1, Tq) arrays in
+(Gn, hb, 1, bq) blocks: the queries lie on the lanes, so HBM holds and
+moves them at their size (a last dimension of 1 would be held, and
+moved, in 128-lane tiles, 128 times it). dk/dv reads the (1, bq) rows as
+they are. The forward and dq want a statistic beside each row of a
+(bq, bk) cell and turn rows into columns, or back, once an *outer* block
+and not once a cell, all the heads of the step in one transpose: dq lays
+its rows side by side and transposes them into a VMEM scratch at the
+first k-block of a q-block (:func:`_as_columns`), the forward gathers its
+heads' m + log l into the lanes of one (bq, 128) array and transposes
+that at the last. dk/dv does the same for the additive key mask, a
+(1, bk) row it wants as a column, once a k-block. ``row_stat_blocks``
+counts the statistics' blocks of every build.
 
 Without ``causal`` every (q-block, k-block) cell of those grids is
 computed. With it the grid is (N/Gn, C/W, listed cells): the cells that
@@ -56,8 +76,8 @@ through the index map; the dk/dv grid runs over the key/value heads, its
 q, dO, lse and delta blocks a whole group of ``rep`` query heads wide, and
 adds the group's heads into the one dk and dv inside the kernel. Each
 windowed build counts its cells in ``window_cells``. Without either, the
-builds are what they were, equation for equation
-(tests/test_causal_skip.py).
+builds are the ones tests/test_causal_skip.py pins, equation for
+equation.
 
 Attention dropout runs INSIDE the kernels: the keep mask is a
 counter-based hash (murmur3 finalizer) of the global (batch·head, q, k)
@@ -72,9 +92,10 @@ softmax→dropout→matmul recipe.
 Mosaic layout constraints honoured throughout: every block's trailing two
 dims are (multiple-of-8, multiple-of-128) or equal to the array dims —
 the key-mask rides as (N, 1, Tk) with (Gn, 1, bk) blocks, one row a
-batch row, and the LSE as (N, H, Tq, 1) with (Gn, W // D, bq, 1) blocks
-(a (1, bk) 2-D mask block is refused). The two scalars the kernels read
-(dropout seed, global batch·head base) ride in SMEM.
+batch row, and the statistics as (N, H, 1, Tq) with (Gn, W // D, 1, bq)
+blocks, one row a head (a (1, bk) 2-D mask block is refused): bq and bk
+are multiples of 128 or the whole padded sequence. The two scalars the
+kernels read (dropout seed, global batch·head base) ride in SMEM.
 
 Kernel mode is explicit: ``interpret=True`` runs the identical kernels
 through the Pallas interpreter (CPU tests exercise the real kernel code),
@@ -123,6 +144,11 @@ window_cells = {}
 # kernel builds}, ``fused`` being whether q, k and v are column ranges of
 # one (N, T, 3*H*D) array. Static numbers, counted at build.
 head_blocks = {}
+
+# and of the layout the softmax row statistics (lse, delta) cross HBM in:
+# {(kind, statistics block shape): kernel builds}, counted where the
+# specs are built. Static numbers.
+row_stat_blocks = {}
 
 
 def _lane_block(C, D):
@@ -216,50 +242,54 @@ def _step_cell(cells_ref, q_axis):
             lambda: cells_ref[2, cell] == 1, lambda: cells_ref[3, cell] == 1)
 
 
-def _block_specs(Gn, hb, bq, bk, W, causal, q_axis=2, rep=1, wide=False):
-    """The BlockSpecs of one call, by role, as (seq, col, mask):
-    ``seq(side, off)`` a (Gn, bq | bk, W) block of an (N, T, columns)
-    array on the 'q' or the 'k' side, ``off`` lane blocks into the
-    columns (where q, k and v are ranges of one array); ``col`` the
-    q-side (Gn, hb, bq, 1) block of an (N, H, Tq, 1) column (lse,
-    delta); ``mask`` the (Gn, 1, bk) block of the (N, 1, Tk) key mask.
-    The grid is (row group b, lane block l, then the full plane with
-    q-blocks on ``q_axis``, or the listed cell with the cell table as
-    scalar-prefetch operand, ``causal``). No index map computes
-    anything, but for an ``off``.
+def _block_specs(kind, Gn, hb, bq, bk, W, causal, rep=1):
+    """The BlockSpecs of one call of ``kind`` ('fwd', 'bwd_dq',
+    'bwd_dkv'), by role, as (seq, row, mask): ``seq(side, off)`` a
+    (Gn, bq | bk, W) block of an (N, T, columns) array on the 'q' or the
+    'k' side, ``off`` lane blocks into the columns (where q, k and v are
+    ranges of one array); ``row`` the q-side (Gn, hb, 1, bq) block of an
+    (N, H, 1, Tq) array of row statistics (lse, delta), Tq on the lanes;
+    ``mask`` the (Gn, 1, bk) block of the (N, 1, Tk) key mask. The grid
+    is (row group b, lane block l, then the full plane, k-blocks
+    outermost in dk/dv and q-blocks elsewhere, or the listed cell with
+    the cell table as scalar-prefetch operand, ``causal``). No index map
+    computes anything, but for an ``off``. Counts the build in
+    ``row_stat_blocks``.
 
     Grouped-query heads (``rep`` query heads to a key/value head, a head
     a lane block): where the lane blocks of the grid are the query
     heads' (forward, dq), the 'k' side reads lane block ``l // rep``;
-    where they are the key/value heads' (dk/dv, ``wide``), the 'q' side
-    and ``col`` are ``rep`` heads wide, the whole group of head ``l``."""
+    where they are the key/value heads' (dk/dv), the 'q' side and
+    ``row`` are ``rep`` heads wide, the whole group of head ``l``."""
     if causal:
         def qi(c, cells): return cells[0, c]
         def ki(c, cells): return cells[1, c]
-    elif q_axis == 2:
+    elif kind != 'bwd_dkv':
         def qi(i, j): return i
         def ki(i, j): return j
     else:
         def qi(j, i): return i
         def ki(j, i): return j
-    q_wide = rep if wide else 1
+    q_wide = rep if kind == 'bwd_dkv' else 1
 
     def seq(side, off=0):
         rows, at = (bq, qi) if side == 'q' else (bk, ki)
         if side == 'q' and q_wide > 1:
             return pl.BlockSpec((Gn, rows, q_wide * W),
                                 lambda b, l, *s: (b, at(*s), l))
-        if side == 'k' and rep > 1 and not wide:
+        if side == 'k' and rep > 1 and kind != 'bwd_dkv':
             return pl.BlockSpec((Gn, rows, W),
                                 lambda b, l, *s: (b, at(*s), l // rep))
         if off:
             return pl.BlockSpec((Gn, rows, W),
                                 lambda b, l, *s: (b, at(*s), l + off))
         return pl.BlockSpec((Gn, rows, W), lambda b, l, *s: (b, at(*s), l))
-    col = pl.BlockSpec((Gn, q_wide * hb, bq, 1),
-                       lambda b, l, *s: (b, l, qi(*s), 0))
+    row = pl.BlockSpec((Gn, q_wide * hb, 1, bq),
+                       lambda b, l, *s: (b, l, 0, qi(*s)))
     mask = pl.BlockSpec((Gn, 1, bk), lambda b, l, *s: (b, 0, ki(*s)))
-    return seq, col, mask
+    key = (kind, row.block_shape)
+    row_stat_blocks[key] = row_stat_blocks.get(key, 0) + 1
+    return seq, row, mask
 
 
 def _call(kernel, cells, grid, in_specs, out_specs, scratch_shapes, **call):
@@ -384,27 +414,35 @@ def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
     """What the heads of one grid step share, computed once a step:
     (own, is-first, is-last, scores, keep).
 
+    Orientation: the forward and dq (``q_axis`` 2) hold a cell's scores
+    as (bq, bk), queries down the sublanes; dk/dv (``q_axis`` 3) as
+    (bk, bq), keys down the sublanes, so that the row statistics of the
+    queries broadcast down the sublanes as the (1, bq) rows they arrive
+    as and dv, dk are plain (bk, bq)·(bq, W) products. Every mask and
+    the dropout bits are functions of (q position, k position) and are
+    handed out with the two iotas exchanged: the same values, transposed.
+
     ``own``: per head of the step's W-column lane block, the (bq, W) mask
     of its own D columns (:func:`_lane_masks`).
 
-    ``scores(q, k, kmask_row)``: (bq, bk) f32 scores of one head for this
-    (q-block, k-block) cell: QK^T * scale, key-padding cut at k_len,
-    additive user mask, causal. The causal ``where`` is what masks inside
-    the cells the diagonal crosses (and, needlessly, in those wholly
-    under it); the cells wholly above it are not in the causal grid
-    (:func:`_cell_live`).
+    ``scores(q, k, kmask)``: f32 scores of one head for this (q-block,
+    k-block) cell: QK^T * scale (K Q^T in dk/dv), key-padding cut at
+    k_len, additive user mask (a (1, bk) row, a (bk, 1) column in dk/dv),
+    causal. The causal ``where`` is what masks inside the cells the
+    diagonal crosses (and, needlessly, in those wholly under it); the
+    cells wholly above it are not in the causal grid (:func:`_cell_live`).
 
-    ``keep(g, hh)``: the (bq, bk) dropout multiplier of row g, head hh of
-    the step, or None without dropout. Its batch·head id is n * h_all + h
-    from ``meta_ref[0, 1]``, the numbering the (N*H, T, D) layout had. A
-    call that holds the whole (N, H) problem has ``h_all`` = H and
-    ``meta_ref[0, 1]`` 0. A call mapped over a mesh (ops/attention.py)
-    holds one shard: ``meta_ref[0, 1]`` is the global id of its first
-    (row, head) and ``h_all`` the heads of the whole problem, its own
-    being fewer when the heads are sharded too — so a sharded run draws
-    the same dropout bits as the unsharded one. Where a lane block of the
-    grid stands for ``rep`` query heads (the dk/dv kernel of grouped-query
-    heads), ``hh`` counts through all of them.
+    ``keep(g, hh)``: the dropout multiplier of row g, head hh of the
+    step, shaped as the scores, or None without dropout. Its batch·head
+    id is n * h_all + h from ``meta_ref[0, 1]``, the numbering the
+    (N*H, T, D) layout had. A call that holds the whole (N, H) problem
+    has ``h_all`` = H and ``meta_ref[0, 1]`` 0. A call mapped over a mesh
+    (ops/attention.py) holds one shard: ``meta_ref[0, 1]`` is the global
+    id of its first (row, head) and ``h_all`` the heads of the whole
+    problem, its own being fewer when the heads are sharded too — so a
+    sharded run draws the same dropout bits as the unsharded one. Where a
+    lane block of the grid stands for ``rep`` query heads (the dk/dv
+    kernel of grouped-query heads), ``hh`` counts through all of them.
 
     ``window``: keep a score iff 0 <= i - j < window. The difference read
     as an unsigned number makes that one comparison, as the causal mask
@@ -412,28 +450,33 @@ def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
     the others."""
     own = _lane_masks(bq, W, D)
     qb, kb, first, last = _step_cell(cells_ref, q_axis)
-    k_pos = kb * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    kq = q_axis == 3
+    q_dim, k_dim = (1, 0) if kq else (0, 1)
+    cell = (bk, bq) if kq else (bq, bk)
+    q_line, k_line = ((1, bq), (bk, 1)) if kq else ((bq, 1), (1, bk))
+    k_pos = kb * bk + lax.broadcasted_iota(jnp.int32, k_line, k_dim)
     in_range = k_pos < k_len
     under = None
     if causal:
-        q_pos = qb * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        q_pos = qb * bq + lax.broadcasted_iota(jnp.int32, q_line, q_dim)
         if window is None:
             under = q_pos >= k_pos
         else:
             under = lax.bitcast_convert_type(
                 q_pos - k_pos, jnp.uint32) < jnp.uint32(window)
 
-    def scores(q, k, kmask_row):
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    def scores(q, k, kmask):
+        s = lax.dot_general(*((k, q) if kq else (q, k)),
+                            (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        s = jnp.where(in_range, s, _NEG_INF) + kmask_row
+        s = jnp.where(in_range, s, _NEG_INF) + kmask
         return s if under is None else jnp.where(under, s, _NEG_INF)
 
     if dropout_p <= 0.0:
         return own, first, last, scores, lambda g, hh: None
     ids = _element_ids(
-        jnp.uint32(qb * bq) + lax.broadcasted_iota(jnp.uint32, (bq, bk), 0),
-        jnp.uint32(kb * bk) + lax.broadcasted_iota(jnp.uint32, (bq, bk), 1))
+        jnp.uint32(qb * bq) + lax.broadcasted_iota(jnp.uint32, cell, q_dim),
+        jnp.uint32(kb * bk) + lax.broadcasted_iota(jnp.uint32, cell, k_dim))
     seed = meta_ref[0, 0]
     bh0 = meta_ref[0, 1] + (pl.program_id(0) * (Gn * h_all)
                             + pl.program_id(1) * (len(own) * rep)
@@ -464,6 +507,14 @@ def _own(x, mask):
     return x if mask is None else lax.select(mask, x, lax.full_like(x, 0))
 
 
+def _as_columns(rows):
+    """(1, n) rows, each along the lanes, as the columns of one (n, rows)
+    array, row i down the sublanes at lane i: one small transpose for all
+    of them, where turning each row into a (n, 1) column of its own is a
+    relayout a row."""
+    return jnp.concatenate(rows, axis=0).T
+
+
 # ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
@@ -474,11 +525,14 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
                    window=None, cells_ref=None):
     """One (row-group, lane-block, q-block, k-block) cell. Refs are VMEM
     blocks: q (Gn, bq, W), k/v (Gn, bk, W), kmask (Gn, 1, bk) additive
-    f32, o (Gn, bq, W), lse (Gn, hb, bq, 1), hb = W // D heads side by
+    f32, o (Gn, bq, W), lse (Gn, hb, 1, bq), hb = W // D heads side by
     side in the W columns; meta (1, 2) uint32 in SMEM [dropout seed,
     global batch*head base]; scratch acc (Gn, bq, W) f32, m/l
-    (Gn*hb, bq, 128) f32. A head's scores, softmax, dropout and
-    accumulation are what they were when it had a block of its own."""
+    (Gn*hb, bq, 128) f32, every lane a row's value. A head's scores,
+    softmax, dropout and accumulation are what they were when it had a
+    block of its own. The last k-block writes m + log l as (1, bq) rows:
+    the step's heads side by side in the lanes of one (bq, 128) array,
+    transposed once."""
     Gn, _, W = q_ref.shape
     own, first, last, scores, keep = _cell(
         cells_ref, 2, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
@@ -517,15 +571,23 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
 
     @pl.when(last())
     def _finalize():
+        lane = lax.broadcasted_iota(jnp.int32, m_ref.shape[1:], 1)
         for g in range(Gn):
             acc = out = acc_ref[g]
             for hh in range(hb):
                 slot = g * hb + hh
-                safe_l = jnp.maximum(l_ref[slot, :, :1], 1e-30)
-                out = acc / safe_l if own[hh] is None \
-                    else lax.select(own[hh], acc / safe_l, out)
-                lse_ref[g, hh] = m_ref[slot, :, :1] + jnp.log(safe_l)
+                safe_l = jnp.maximum(l_ref[slot], 1e-30)      # (bq, 128)
+                out = acc / safe_l[:, :1] if own[hh] is None \
+                    else lax.select(own[hh], acc / safe_l[:, :1], out)
+                # every lane of m and l is the row's value: lane ``slot``
+                # of ``lse`` takes this head's
+                head = m_ref[slot] + jnp.log(safe_l)
+                lse = head if slot == 0 else jnp.where(lane == slot, head,
+                                                       lse)
             o_ref[g] = out.astype(o_ref.dtype)
+        rows = lse.T                                          # (128, bq)
+        for slot in range(Gn * hb):
+            lse_ref[slot // hb, slot % hb] = rows[slot:slot + 1]
 
 
 def _addressing(arrays, H, kind, Hkv=None):
@@ -595,8 +657,9 @@ def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
     base]. ``Hkv``: key/value heads where they are fewer than the H
     query heads (k, v: (N, T, Hkv*D)); ``window``: with ``causal``, keep
     a score iff i - j < window. Returns (out (N, Tq, H*D), lse
-    (N, H, Tq)), sliced back from the blocks' padding -- the backward
-    re-pads them for its own (possibly different) tiling."""
+    (N, H, 1, Tq), Tq on the lanes as the kernel wrote it), sliced back
+    from the blocks' padding -- the backward re-pads them for its own
+    (possibly different) tiling."""
     N, Tq, Tk, C, D, W, hb, Gn, bq, bk = _addressing(arrays, H, 'fwd', Hkv)
     rep = H // (Hkv or H)
     dtype = arrays[0].dtype
@@ -609,25 +672,24 @@ def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
         _fa_fwd_kernel, scale=1.0 / math.sqrt(D), causal=causal, D=D, bq=bq,
         bk=bk, k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
         window=window)
-    seq, col, mask = _block_specs(Gn, hb, bq, bk, W, causal, rep=rep)
+    seq, row, mask = _block_specs('fwd', Gn, hb, bq, bk, W, causal, rep)
     cells = _causal_cell_table('fwd', nq, nk, bq, bk, by_row=True,
                                window=window) if causal else None
     out, lse = _call(
         kernel, cells, (N // Gn, C // W) + (() if causal else (nq, nk)),
         in_specs=[seq('q', qo), seq('k', ko), seq('k', vo), mask,
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[seq('q'), col],
+        out_specs=[seq('q'), row],
         out_shape=[jax.ShapeDtypeStruct((N, nq * bq, C), dtype),
-                   jax.ShapeDtypeStruct((N, H, nq * bq, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((N, H, 1, nq * bq), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((Gn, bq, W), jnp.float32),
                         pltpu.VMEM((Gn * hb, bq, 128), jnp.float32),
                         pltpu.VMEM((Gn * hb, bq, 128), jnp.float32)],
         interpret=interpret, name=_scopes.FLASH_FWD,
     )(q, k, v, _mask_operand(kmask, N, Tk, pk), meta)
-    lse = lse[..., 0]
     if pq:
         out = out[:, :Tq]
-        lse = lse[:, :, :Tq]
+        lse = lse[..., :Tq]
     return out, lse
 
 
@@ -636,20 +698,27 @@ def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
 # ---------------------------------------------------------------------------
 
 def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
-                  lse_ref, delta_ref, dq_ref, dq_acc, *,
+                  lse_ref, delta_ref, dq_ref, dq_acc, stat_col, *,
                   scale, causal, D, bq, bk, k_len, dropout_p, h_all,
                   window=None, cells_ref=None):
     """dq for one q-block of one lane block, accumulated over k-blocks
-    (grid (N/Gn, C/W, nq, nk)), written in the operands' dtype."""
+    (grid (N/Gn, C/W, nq, nk)), written in the operands' dtype. lse and
+    delta arrive as (Gn, hb, 1, bq) rows and are wanted down the
+    sublanes, beside the (bq, bk) scores: the first k-block of a q-block
+    lays them into ``stat_col`` (bq, 2*Gn*hb), a column a head and
+    statistic, with one transpose an outer block."""
     Gn, _, W = q_ref.shape
     own, first, last, scores, keep = _cell(
         cells_ref, 2, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
         dropout_p, h_all, window)
     hb = len(own)
+    heads = Gn * hb         # lse in columns [0, heads), delta in the next
 
     @pl.when(first())
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        stat_col[:] = _as_columns([ref[g, hh] for ref in (lse_ref, delta_ref)
+                                   for g in range(Gn) for hh in range(hb)])
 
     for g in range(Gn):
         q, k, kmask_row = q_ref[g], k_ref[g], kmask_ref[g]
@@ -658,15 +727,17 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
         do32 = do_ref[g].astype(jnp.float32)              # (bq, W)
         dq = dq_acc[g]
         for hh in range(hb):
+            slot = g * hb + hh
             s = scores(_own(q, own[hh]), k, kmask_row)
-            p = jnp.exp(s - lse_ref[g, hh])               # (bq, bk)
+            p = jnp.exp(s - stat_col[:, slot:slot + 1])   # (bq, bk)
             dp = lax.dot_general(
                 _own(do32, own[hh]), v32, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)       # (bq, bk)
             kept = keep(g, hh)
             if kept is not None:
                 dp = dp * kept
-            ds = p * (dp - delta_ref[g, hh]) * scale      # (bq, bk)
+            delta = stat_col[:, heads + slot:heads + slot + 1]
+            ds = p * (dp - delta) * scale                 # (bq, bk)
             # ds·k fills all W columns; this head's are kept
             dq = dq + _own(lax.dot_general(
                 ds, k32, (((1,), (0,)), ((), ())),
@@ -679,15 +750,21 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
 
 
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
-                   lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                   lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                   kmask_col, *,
                    scale, causal, D, bq, bk, k_len, dropout_p, h_all,
                    window=None, rep=1, cells_ref=None):
     """dk/dv for one k-block of one lane block, accumulated over q-blocks
     (grid (N/Gn, C/W, nk, nq): k-block is program 2, q-block program 3),
-    written in the operands' dtype. With ``rep`` query heads to a
-    key/value head the lane blocks are the key/value heads', q, dO, lse
-    and delta come ``rep`` heads wide, and the group's heads add into
-    the one dk and dv here, a head at a time."""
+    written in the operands' dtype. A cell is computed keys down the
+    sublanes, (bk, bq) (:func:`_cell`): lse and delta are the (1, bq)
+    rows they arrive as, and dv += p^T·dO, dk += ds^T·q are plain
+    products. The additive key mask is wanted as a column here; the first
+    q-block of a k-block lays it into ``kmask_col`` (bk, Gn), once an
+    outer block. With ``rep`` query heads to a key/value head the lane
+    blocks are the key/value heads', q, dO, lse and delta come ``rep``
+    heads wide, and the group's heads add into the one dk and dv here, a
+    head at a time."""
     Gn, _, W = k_ref.shape
     own, first, last, scores, keep = _cell(
         cells_ref, 3, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
@@ -698,9 +775,10 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        kmask_col[:] = _as_columns([kmask_ref[g] for g in range(Gn)])
 
     for g in range(Gn):
-        k, kmask_row = k_ref[g], kmask_ref[g]
+        k, kmask = k_ref[g], kmask_col[:, g:g + 1]
         v32 = v_ref[g].astype(jnp.float32)                # (bk, W)
         dk, dv = dk_acc[g], dv_acc[g]
         for r in range(rep):
@@ -712,22 +790,22 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                 # q and dO with the other heads' columns zeroed: what they
                 # are contracted into lands in this head's columns alone
                 q_own, do_own = _own(q, own[hh]), _own(do32, own[hh])
-                s = scores(q_own, k, kmask_row)
-                p = jnp.exp(s - lse_ref[g, head])             # (bq, bk)
+                s = scores(q_own, k, kmask)
+                p = jnp.exp(s - lse_ref[g, head])             # (bk, bq)
                 kept = keep(g, head)
                 pv = p if kept is None else p * kept
                 # dv_j += sum_i P_drop_ij dO_i
                 dv = dv + lax.dot_general(
-                    pv, do_own, (((0,), (0,)), ((), ())),
+                    pv, do_own, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)       # (bk, W)
                 dp = lax.dot_general(
-                    do_own, v32, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)       # (bq, bk)
+                    v32, do_own, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)       # (bk, bq)
                 if kept is not None:
                     dp = dp * kept
-                ds = p * (dp - delta_ref[g, head]) * scale    # (bq, bk)
+                ds = p * (dp - delta_ref[g, head]) * scale    # (bk, bq)
                 dk = dk + lax.dot_general(
-                    ds, q_own.astype(jnp.float32), (((0,), (0,)), ((), ())),
+                    ds, q_own.astype(jnp.float32), (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)       # (bk, W)
         dk_acc[g], dv_acc[g] = dk, dv
 
@@ -753,10 +831,10 @@ def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
         # and ds = p·(0 - 0) vanish; lse pads as 0 harmlessly
         do = jnp.pad(do, ((0, 0), (0, pq), (0, 0)))
         out = jnp.pad(out, ((0, 0), (0, pq), (0, 0)))
-        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pq)))
+        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, 0), (0, pq)))
 
     # delta_i = dO_i · O_i (rowwise, a head at a time) — cheap XLA
-    # preprocessing, laid out as the lse is: (N, H, Tq_pad, 1). The sum
+    # preprocessing, laid out as the lse is: (N, H, 1, Tq_pad). The sum
     # over a head's D columns is a product with the 0/1 matrix that says
     # which head a column is of: a reduction over part of the lanes would
     # have XLA copy the (N, T, C) product into another layout first
@@ -764,38 +842,42 @@ def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
         jnp.float32)
     delta = jnp.einsum(
         'ntc,ch->nht', do.astype(jnp.float32) * out.astype(jnp.float32),
-        head_of, precision=lax.Precision.HIGHEST)[..., None]
+        head_of, precision=lax.Precision.HIGHEST).reshape(lse.shape)
 
     kw = dict(scale=1.0 / math.sqrt(D), causal=causal, D=D, bq=bq, bk=bk,
               k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
               window=window)
-    operands = (q, k, v, _mask_operand(kmask, N, Tk, pk), meta, do,
-                lse[..., None], delta)
+    operands = (q, k, v, _mask_operand(kmask, N, Tk, pk), meta, do, lse,
+                delta)
     calls = []
-    for kind, kernel, q_axis, plane, side, n_out in (
-            ('bwd_dq', _fa_dq_kernel, 2, (nq, nk), 'q', 1),
-            ('bwd_dkv', _fa_dkv_kernel, 3, (nk, nq), 'k', 2)):
+    for kind, kernel, plane, side, n_out in (
+            ('bwd_dq', _fa_dq_kernel, (nq, nk), 'q', 1),
+            ('bwd_dkv', _fa_dkv_kernel, (nk, nq), 'k', 2)):
         _count_build(kind, H, D, hb, (qo, ko, vo))
         # with grouped heads the dk/dv grid runs over the key/value heads,
         # each step a whole group of query heads wide
         wide = rep > 1 and side == 'k'
         group = {'rep': rep} if wide else {}
-        seq, col, mask = _block_specs(Gn, hb, bq, bk, W, causal, q_axis,
-                                      rep=rep, wide=wide)
+        seq, row, mask = _block_specs(kind, Gn, hb, bq, bk, W, causal, rep)
         rows, blk = (nq * bq, bq) if side == 'q' else (nk * bk, bk)
         cols = C // rep if wide else C
         cells = _causal_cell_table(kind, nq, nk, bq, bk, by_row=side == 'q',
                                    window=window) if causal else None
+        # what an outer block turns into columns once: the statistics in
+        # dq, the key mask in dk/dv
+        columns = pltpu.VMEM((bq, 2 * Gn * hb) if side == 'q' else (bk, Gn),
+                             jnp.float32)
         calls.append(_call(
             functools.partial(kernel, **kw, **group), cells,
             (N // Gn, cols // W) + (() if causal else plane),
             in_specs=[seq('q', qo), seq('k', ko), seq('k', vo), mask,
                       pl.BlockSpec(memory_space=pltpu.SMEM), seq('q'),
-                      col, col],
+                      row, row],
             out_specs=[seq(side)] * n_out,
             out_shape=[jax.ShapeDtypeStruct(
                 (N, rows, C if side == 'q' else C // rep), dtype)] * n_out,
-            scratch_shapes=[pltpu.VMEM((Gn, blk, W), jnp.float32)] * n_out,
+            scratch_shapes=[pltpu.VMEM((Gn, blk, W), jnp.float32)] * n_out
+            + [columns],
             interpret=interpret,
             name=_scopes.FLASH_BWD_DQ if side == 'q'
             else _scopes.FLASH_BWD_DKV)(*operands))

@@ -76,12 +76,17 @@ def test_legal_candidates_are_self_consistent():
 def test_bf16_raises_sublane_minimum():
     assert autotune.sublane_min(jnp.dtype('float32')) == 8
     assert autotune.sublane_min(jnp.dtype(jnp.bfloat16)) == 16
-    # a bq of 8 is legal for f32 but not for bf16 at T=512
+    # a bq of 8 is legal for f32 but not for bf16: at Tq=8, because the
+    # row statistics ride with the queries on the lanes and a bq under
+    # 128 has to be the whole sequence
     ok_f32, _ = autotune.check_candidate(
-        8, 512, 512, 64, jnp.dtype('float32'), 'fwd', 8, 8, 128)
+        8, 8, 512, 64, jnp.dtype('float32'), 'fwd', 8, 8, 128)
     ok_bf16, _ = autotune.check_candidate(
-        8, 512, 512, 64, jnp.dtype(jnp.bfloat16), 'fwd', 8, 8, 128)
+        8, 8, 512, 64, jnp.dtype(jnp.bfloat16), 'fwd', 8, 8, 128)
     assert ok_f32 and not ok_bf16
+    part, why = autotune.check_candidate(
+        8, 512, 512, 64, jnp.dtype('float32'), 'fwd', 8, 8, 128)
+    assert not part and why.startswith('lse: lane dim 8')
 
 
 def test_g_is_heads_a_step_in_lane_blocks():
@@ -95,7 +100,9 @@ def test_g_is_heads_a_step_in_lane_blocks():
     assert layouts['q'] == ((336, 512, 128), (2, 256, 128))
     assert layouts['dk'] == ((336, 512, 128), (2, 256, 128))
     assert layouts['kmask'] == ((336, 1, 512), (2, 1, 256))
-    assert layouts['lse'] == ((336, 2, 512, 1), (2, 2, 256, 1))
+    # the row statistics: queries on the lanes, a row a head
+    assert layouts['lse'] == layouts['delta'] == ((336, 2, 1, 512),
+                                                  (2, 2, 1, 256))
     # one 128-wide head a block: G rows
     assert dict((n, b) for n, _a, b in autotune.fa_block_layouts(
         32, 512, 512, 128, 'fwd', 4, 512, 512))['q'] == (4, 512, 128)
