@@ -10,7 +10,9 @@ program runs the same instructions with or without them:
   constants below. Forward and backward are both under ``FWD_BWD``; JAX
   itself tells them apart, wrapping the first scope inside it in
   ``jvp(...)`` on the way forward and ``transpose(jvp(...))`` on the way
-  back;
+  back, and puts what a ``jax.checkpoint`` region (a looped decoder's
+  block applications, a chunk of a chunked loss) computes a second time
+  in the backward under ``checkpoint/rematted_computation``;
 * a Gluon block (gluon/block.py): every block's forward runs under the
   name its parent knows it by, so an op reads
   ``bertmodel0/encoder/bertlayer3/bertselfattention0/qkv/dot_general``;
@@ -44,7 +46,9 @@ ATTN_LAYOUT = 'attn_layout'     # (N,T,H*D) <-> (N,H,T,D) on the XLA and ring
 ATTN_CORE = 'attn_core'         # scores, softmax, dropout, weighted sum
 FFN1 = 'ffn1'                   # first feed-forward matmul + GELU
 LN1, LN2 = 'ln1', 'ln2'         # residual add + LayerNorm
-LM_HEAD = 'lm_head'             # GPT's tied output projection
+LM_HEAD = 'lm_head'             # the output projection: GPT's tied one,
+                                # the decoder's own; a looped decoder's lies
+                                # inside LOSS under training
 ATTN_SWA = 'attn_swa'           # decoder: a windowed layer's attention core
 ATTN_FULL = 'attn_full'         # decoder: a full-attention layer's
 ROPE = 'rope'                   # rotary embedding of q and k
@@ -52,6 +56,12 @@ RMSNORM = 'rmsnorm'             # RMS normalisation (no block of its own)
 MOE_ROUTE = 'moe_route'         # router matmul, softmax, top-k, sort, the
                                 # dispatch gather and the combine
 MOE_EXPERTS = 'moe_experts'     # the grouped matmuls and ReGLU between them
+UT_LOOP = 'ut_loop'             # looped decoder: every pass of the stack and
+                                # the final norm, forward, backward and the
+                                # forward run again under jax.checkpoint
+UT_PASS = 'ut_pass'             # one pass of it, numbered: ut_pass0 ...
+FFN_GLU = 'ffn_glu'             # decoder: the dense gated feed-forward
+EXIT_GATE = 'exit_gate'         # looped decoder: the exit gate's logit
 
 # Pallas kernels
 FLASH_FWD = 'mxtpu_flash_fwd'

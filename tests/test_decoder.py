@@ -284,3 +284,41 @@ def test_a_train_step_learns():
     tokens, labels = _tokens()
     losses = [float(step([tokens], [labels]).asscalar()) for _ in range(8)]
     assert losses[-1] < losses[0] - 0.1, losses
+
+
+def test_the_tiny_model_is_the_one_pr_34_built():
+    """The block's later options (a dense gated feed-forward, sandwich
+    norms, a looped model: PR 37) cost this path nothing: the parameter
+    names and shapes, the float32 loss to the last digit and the lowered
+    forward + loss are those read on PR 36's tree, before
+    models/decoder.py was touched."""
+    import hashlib
+    model, loss_fn = _model(seed=5)
+    params = model.collect_params()
+    order = sorted(params)
+    cut = len(model.prefix)
+    names = [(n[cut:], params[n].shape) for n in order]
+    assert len(names) == 39
+    assert hashlib.sha256(repr(names).encode()).hexdigest() == (
+        '971da5bfa31544408c2417b2766c2c42960fba5080850210e7c78e5d6f97f132')
+    tokens, labels = _tokens()
+    loss = loss_fn(model(nd.array(tokens)), nd.array(labels)).asscalar()
+    assert float(loss).hex() == '0x1.9037340000000p+2'     # 6.253369331359863
+
+    model.hybridize(False)
+
+    def forward_loss(arrays, tokens, labels):
+        for n, a in zip(order, arrays):
+            params[n]._set_trace_proxy(nd.NDArray(a))
+        try:
+            return program.payload(loss_fn(model(nd.NDArray(tokens)),
+                                           nd.NDArray(labels)))
+        finally:
+            for n in order:
+                params[n]._clear_trace_proxy()
+    text = jax.jit(forward_loss).lower(
+        [program.payload(params[n].data()) for n in order], tokens,
+        labels).as_text()
+    assert len(text.splitlines()) == 1883
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        '04962777562bacfa8d5ece509490184cb4ae8eec6efedb23f1a8edcabb779628')
