@@ -20,7 +20,9 @@ A model runs its stack once, or ``passes`` times on one set of weights
 and may carry an exit gate, a learned probability of leaving after each
 pass; :func:`looped_lm_loss` is the objective over the passes' heads. A
 looped model recomputes: every application of a block is a
-``jax.checkpoint`` region that keeps its input only.
+``jax.checkpoint`` region that keeps its input and, on the Pallas route,
+the flash forward's output and row statistics (``scopes.FLASH_KEPT``), so
+the backward runs everything of a block again but that kernel.
 
 models/gpt.py and models/bert.py keep their own blocks.
 """
@@ -206,15 +208,22 @@ class ExitGate(HybridBlock):
 
 
 # what the last trace of a looped forward did: its passes, the blocks of
-# its stack, how often a block is applied and how many jax.checkpoint
-# regions the program holds. Read by tests, by no metric.
+# its stack, how often a block is applied, how many jax.checkpoint
+# regions the program holds and the names of what each keeps beside its
+# inputs. Read by tests, by no metric.
 loop_counts = {}
 
 
 def _recomputed(block):
     """``block`` as a function of x under ``jax.checkpoint``: one region
     of x, the block's parameters and a random key, of which the backward
-    keeps the inputs and runs the block forward again. The parameters
+    keeps the inputs and the two arrays the flash kernel's forward rule
+    names (``scopes.FLASH_KEPT``: o, T * hidden * 2 bytes in bf16, and the
+    float32 row statistics, a 64th of that at heads of 128) and runs the
+    rest of the block forward again: norms, projections, rotary positions
+    and the feed-forward, q, k and v for the kernel's backward among them,
+    but not the kernel, the dearest recomputation a block has. On the XLA
+    route nothing carries the names and the inputs are all. The parameters
     reach the block through their trace proxies and the key through a key
     provider of the region's own, as in CachedOp and ShardedTrainStep, so
     the block is called as it always is, draws the same bits both times,
@@ -233,7 +242,9 @@ def _recomputed(block):
         finally:
             for p in params:
                 p._clear_trace_proxy()
-    region = jax.checkpoint(apply)
+    region = jax.checkpoint(
+        apply, policy=jax.checkpoint_policies.save_only_these_names(
+            *_scopes.FLASH_KEPT))
 
     def call(x):
         loop_counts['checkpointed'] += 1
@@ -300,7 +311,8 @@ class DecoderModel(HybridBlock):
     def _looped(self, x):
         loop_counts.update(
             passes=self._passes, blocks=len(self.blocks), checkpointed=0,
-            block_applications=self._passes * len(self.blocks))
+            block_applications=self._passes * len(self.blocks),
+            kept=_scopes.FLASH_KEPT)
         regions = [_recomputed(blk) for blk in self.blocks]
         states = []
         with jax.named_scope(_scopes.UT_LOOP):

@@ -97,6 +97,20 @@ blocks, one row a head (a (1, bk) 2-D mask block is refused): bq and bk
 are multiples of 128 or the whole padded sequence. The two scalars the
 kernels read (dropout seed, global batch·head base) ride in SMEM.
 
+What the backward reads of the forward, ``o`` and ``lse``, the
+``custom_vjp``'s forward rule (:func:`_flash_fwd`) passes through
+``jax.ad_checkpoint.checkpoint_name`` under ``scopes.FLASH_OUT`` and
+``scopes.FLASH_LSE``, and hands the named ``o`` on as the result too. A
+name is an identity and lowers to nothing. Its one reader is a
+``jax.checkpoint`` region whose policy lists the two
+(``save_only_these_names``: a looped decoder's block applications,
+models/decoder.py:_recomputed): such a region keeps them beside its inputs
+and its backward does not run the forward kernel again; q, k and v it
+computes again from the kept input. No other policy in the program reads
+a name of these (parallel/step.py's ``MXTPU_REMAT`` policies read none,
+parallel/exchange.py's ZeRO-3 policy drops ``zero3_gather`` alone), and
+the primal :func:`_flash`, which predict mode takes, never sees the rule.
+
 Kernel mode is explicit: ``interpret=True`` runs the identical kernels
 through the Pallas interpreter (CPU tests exercise the real kernel code),
 ``interpret=False`` compiles them with Mosaic, and ``None`` asks
@@ -111,6 +125,7 @@ import jax
 import jax.numpy as jnp
 import numpy as onp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -904,6 +919,10 @@ def _flash_fwd(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
                Hkv, window):
     out, lse = _fa_forward(arrays, kmask, meta, H, causal, dropout_p,
                            interpret, h_all, Hkv, window)
+    # the named out is the primal result too: what follows reads the
+    # array a checkpoint region keeps, and its second run needs no kernel
+    out = checkpoint_name(out, _scopes.FLASH_OUT)
+    lse = checkpoint_name(lse, _scopes.FLASH_LSE)
     return out, (arrays, kmask, meta, out, lse)
 
 

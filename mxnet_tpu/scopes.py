@@ -12,7 +12,9 @@ program runs the same instructions with or without them:
   ``jvp(...)`` on the way forward and ``transpose(jvp(...))`` on the way
   back, and puts what a ``jax.checkpoint`` region (a looped decoder's
   block applications, a chunk of a chunked loss) computes a second time
-  in the backward under ``checkpoint/rematted_computation``;
+  in the backward under ``checkpoint/rematted_computation``: all of a
+  chunk, and of a block application all but the flash forward kernel,
+  whose two results the region keeps (``FLASH_KEPT`` below);
 * a Gluon block (gluon/block.py): every block's forward runs under the
   name its parent knows it by, so an op reads
   ``bertmodel0/encoder/bertlayer3/bertselfattention0/qkv/dot_general``;
@@ -71,3 +73,14 @@ FFN_GELU = 'mxtpu_ffn_gelu'
 ADD_LAYERNORM = 'mxtpu_add_layernorm'
 GROUPED_MATMUL = 'mxtpu_grouped_matmul'     # ops/moe.py: forward and both
                                             # backward products
+
+# jax.ad_checkpoint.checkpoint_name: what the flash forward hands its
+# backward, named where the custom_vjp's forward rule returns it
+# (ops/pallas_attention.py:_flash_fwd), so that a jax.checkpoint region
+# whose policy lists the names (models/decoder.py:_recomputed) keeps the
+# two arrays and does not run the kernel a second time. Without such a
+# policy a name is an identity and lowers to nothing
+FLASH_OUT = 'mxtpu_flash_out'   # o, (N, Tq, H*D) in the model's dtype
+FLASH_LSE = 'mxtpu_flash_lse'   # the softmax row statistics, float32
+                                # (N, H, 1, Tq)
+FLASH_KEPT = (FLASH_OUT, FLASH_LSE)

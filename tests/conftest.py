@@ -41,6 +41,19 @@ def _seed():
     yield
 
 
+@pytest.fixture(autouse=True, scope='module')
+def _block_names_from_zero():
+    """Gluon's auto-numbered names (dense0_, dense1_, ...) count from zero
+    in every test file, as when the file runs alone. The counter is the
+    process's, and under xdist which files share a worker, and in what
+    order, changes from run to run: a test that pairs two nets'
+    parameters by ``sorted()`` names failed whenever its file happened to
+    start at dense9 (dense10 sorts before dense9)."""
+    from mxnet_tpu.gluon.block import _BlockScope
+    _BlockScope._global_counter.clear()
+    yield
+
+
 def build_native_lib(so_name):
     """Path to mxnet_tpu/_lib/<so_name>, running `make` in src/ if it is
     missing; pytest.skip when the toolchain can't produce it. Shared by

@@ -5,8 +5,10 @@ the float32 reference of the benchmark's ``ouro`` family
 16, feed-forward 96, 2 blocks, 4 passes, vocabulary 512, T = 64. Logits
 of every pass, exit probabilities, objective and the gradient of every
 shared weight; the loop against the blocks applied by hand; the
-checkpoint against none; the faults the comparison has to catch; the
-counter and the scopes.
+checkpoint against none; what a block application's region keeps on the
+flash route (the kernel's output and row statistics, scopes.FLASH_KEPT:
+ISSUE 38); the faults the comparison has to catch; the counter and the
+scopes.
 """
 import os
 import re
@@ -19,6 +21,7 @@ import pytest
 
 from mxnet_tpu import autograd, nd, scopes
 from mxnet_tpu.models import DecoderModel, decoder, looped_lm_loss
+from mxnet_tpu.ops import pallas_attention
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from chipbench import manifest, program    # noqa: E402
@@ -193,6 +196,126 @@ def test_the_checkpoint_changes_no_value_and_no_gradient(monkeypatch):
                                     atol=1e-6, err_msg=name)
 
 
+@pytest.fixture
+def flash_route(monkeypatch):
+    """The attention of every block on the Pallas route, as on a TPU; the
+    kernels run through the interpreter (the backend is the CPU)."""
+    monkeypatch.setattr(pallas_attention, 'pallas_available', lambda: True)
+
+
+def _without_policy(monkeypatch):
+    """``jax.checkpoint(apply)``: the region as it was until ISSUE 38,
+    which keeps its inputs only."""
+    plain = jax.checkpoint
+    monkeypatch.setattr(jax, 'checkpoint', lambda f, **policy: plain(f))
+
+
+def _calls(jaxpr, kernel):
+    """How many ``pallas_call``s named ``kernel`` a jaxpr makes, those of
+    its nested jaxprs counted where they are called."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'pallas_call':
+            total += eqn.params['name'] == kernel
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    total += _calls(sub, kernel)
+    return total
+
+
+def _gradient_program(model, loss_fn, tokens, labels):
+    """The jaxpr of value-and-gradient of the objective in the model's
+    parameters, traced as ShardedTrainStep traces it."""
+    params = model.collect_params()
+    order = sorted(params)
+
+    def objective(arrays):
+        for n, a in zip(order, arrays):
+            params[n]._set_trace_proxy(nd.NDArray(a))
+        try:
+            with autograd.train_mode():
+                return program.payload(loss_fn(
+                    *model(nd.array(tokens)), nd.array(labels)))
+        finally:
+            for n in order:
+                params[n]._clear_trace_proxy()
+    return jax.make_jaxpr(jax.value_and_grad(objective))(
+        [program.payload(params[n].data()) for n in order]).jaxpr
+
+
+def test_the_backward_does_not_run_the_flash_forward_again(
+        flash_route, monkeypatch):
+    """One forward kernel a block application in the whole gradient
+    program, where a region without the policy runs it a second time to
+    get ``o`` and ``lse`` back; dq and dk/dv once an application either
+    way."""
+    tokens, labels = _tokens()
+    kept = _gradient_program(*_model(), tokens, labels)
+    applications = decoder.loop_counts['block_applications']
+    assert applications == 8
+    assert decoder.loop_counts['kept'] == scopes.FLASH_KEPT == (
+        scopes.FLASH_OUT, scopes.FLASH_LSE)
+    _without_policy(monkeypatch)
+    plain = _gradient_program(*_model(), tokens, labels)
+    assert _calls(kept, scopes.FLASH_FWD) == applications
+    assert _calls(plain, scopes.FLASH_FWD) == 2 * applications
+    for jaxpr in (kept, plain):
+        assert _calls(jaxpr, scopes.FLASH_BWD_DQ) == applications
+        assert _calls(jaxpr, scopes.FLASH_BWD_DKV) == applications
+
+
+def test_a_region_keeps_its_inputs_and_the_two_named_arrays(flash_route,
+                                                             capsys):
+    """What one block application saves for its backward, as
+    ``print_saved_residuals`` lists it: x and the block's ten
+    parameters, the kernel's output and its row statistics, and nothing
+    else."""
+    model, _ = _model()
+    tokens, _labels = _tokens()
+    model(nd.array(tokens))                 # loop_counts for the region
+    block = model.blocks[0]
+    region = decoder._recomputed(block)
+    x = jnp.ones((2, T, TINY['hidden_size']), jnp.float32)
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda x: program.payload(region(nd.NDArray(x))), x)
+    saved = [line.split(' ', 1)
+             for line in capsys.readouterr().out.splitlines()]
+    # the region's own arguments: x and ten parameters (the key is drawn
+    # from by no op of this block)
+    own = [line for line in saved if line[1].startswith(
+        ('from the argument', 'from a constant'))]
+    assert len(own) == 1 + len(block.collect_params()) == 11, saved
+    assert [aval for aval, _ in own].count(f'f32[2,{T},64]') == 1
+    # and the kernel's two results. lse is listed under its name; o,
+    # which the block goes on to read, as the output of the no-op
+    # reduce_precision jax.checkpoint puts behind a residual that the
+    # forward uses too
+    (lse, lse_from), (o, o_from) = sorted(
+        line for line in saved if line not in own)
+    assert o == f'f32[2,{T},64]' and '(flash_mha)' in o_from, saved
+    assert lse == f"f32[2,{TINY['num_attention_heads']},1,{T}]"
+    assert lse_from.startswith(f"named '{scopes.FLASH_LSE}'"), saved
+
+
+def test_keeping_them_changes_no_value_and_no_gradient(flash_route,
+                                                       monkeypatch):
+    """The kept ``o`` and ``lse`` are the arrays the second run would
+    have produced: loss and every gradient, bit for bit."""
+    tokens, labels = _tokens()
+    loss, grads = _grads(*_model(), tokens, labels)
+    _without_policy(monkeypatch)
+    plain_loss, plain_grads = _grads(*_model(), tokens, labels)
+    assert loss == plain_loss
+    assert set(grads) == set(plain_grads) and len(grads) == 25
+    for name, grad in grads.items():
+        assert onp.abs(grad).max() > 0, name
+        onp.testing.assert_array_equal(grad, plain_grads[name], err_msg=name)
+
+
 def _quantized(weights, bits=8):
     """Symmetric per-tensor rounding of every matrix to ``bits`` bits."""
     def q(w):
@@ -280,7 +403,9 @@ def test_loop_counts_reads_the_last_trace():
     model(nd.array(_tokens()[0]))
     assert decoder.loop_counts == {'passes': 4, 'blocks': 2,
                                    'block_applications': 8,
-                                   'checkpointed': 8}
+                                   'checkpointed': 8,
+                                   'kept': ('mxtpu_flash_out',
+                                            'mxtpu_flash_lse')}
 
 
 def test_the_objective_on_hand_made_gates():
