@@ -203,8 +203,10 @@ register('MXTPU_TRACE', _bool, False,
          'nested chrome-trace B/E spans over the step lifecycle (io, '
          'h2d, dispatch, collectives, optimizer, checkpoint) in '
          'lock-free per-thread ring buffers, plus the crash-time '
-         'flight recorder. Off: every span site takes a single '
-         'flag-check fast path and allocates nothing.')
+         'flight recorder. Off: a span is its jax.profiler '
+         'TraceAnnotation alone (mxtpu.<name> on the host plane of any '
+         'profile; nothing while none is taken) and records nothing '
+         'here.')
 register('MXTPU_TRACE_RING', int, 16384,
          'Span-trace ring capacity in events PER THREAD. A full ring '
          'overwrites its oldest events (dropped whole spans are '

@@ -130,6 +130,7 @@ class ShardedTrainStep:
         self._opt_state = None
         self._residual = None     # error-feedback residuals (compression)
         self._compiled = None
+        self._executable = None   # compiled_program()'s, run from then on
         self._alias = None        # name-stable jit-boundary key aliases
         self._alias_rev = None
         self._step_count = 0
@@ -604,9 +605,15 @@ class ShardedTrainStep:
                                                jnp.result_type(x)),
                 (t_params, f_params, master, opt_state, residual,
                  in_datas, lab_datas, key, lr_val, fault_scale))
+        run = self._compiled
+        if self._executable is not None and self._is_stored_batch(
+                in_datas, lab_datas):
+            # the program compiled_program() handed out: what a reader
+            # of its text or its byte plan was told about is what runs
+            run = self._executable
         with _trace.span('step.compiled'), \
                 _memory.oom_guard('step.dispatch'):
-            out = self._compiled(
+            out = run(
                 t_params, f_params, master, opt_state, residual,
                 in_datas, lab_datas, key, lr_val, fault_scale)
         if cctx is not None:
@@ -649,6 +656,16 @@ class ShardedTrainStep:
         _memory.on_step(self._step_count)
         _flight.record_step(self._step_count, loss=loss_nd)
         return loss_nd
+
+    def _is_stored_batch(self, in_datas, lab_datas):
+        """Whether this call's batch has the shapes and dtypes of the
+        stored avals, the only part of a call's signature that can move
+        (a compiled executable, unlike the jitted step, takes no other)."""
+        stored = self._cost_args[5] + self._cost_args[6]
+        batch = in_datas + lab_datas
+        return len(batch) == len(stored) and all(
+            x.shape == a.shape and x.dtype == a.dtype
+            for x, a in zip(batch, stored))
 
     def _first_call(self, inputs, in_datas, lab_datas, cctx):
         """Build the step for this batch's shapes, create the optimizer
@@ -850,6 +867,7 @@ class ShardedTrainStep:
         self.zero = self.zero_stage > 0
         self._spans_processes = self._mesh_spans_processes()
         self._compiled = None
+        self._executable = None
         self._cost_args = None
         self._master = None
         self._opt_state = None
@@ -882,10 +900,10 @@ class ShardedTrainStep:
         """{'flops', 'bytes'} of ONE compiled step from XLA's own
         cost_analysis — the deterministic device-side half of the
         per-step attribution report (telemetry.attribution joins it
-        with the measured wall-time spans). Lowers/compiles the step
-        once more from stored avals (cached by the persistent
-        compilation cache when enabled); None before the first step or
-        when the backend exposes no cost model."""
+        with the measured wall-time spans). Read from
+        ``compiled_program()``, which the step runs from then on; None
+        before the first step or when the backend exposes no cost
+        model."""
         from ..telemetry import attribution as _attribution
         try:
             compiled = self.compiled_program()
@@ -897,8 +915,16 @@ class ShardedTrainStep:
         """The step program as the backend compiled it (``as_text()`` is
         the optimized HLO chip_smoke.py reads for the Mosaic custom
         calls and the collectives around them; ``memory_analysis()`` is
-        XLA's own byte plan). Compiled once more from the stored avals;
-        raises before the first step.
+        XLA's own byte plan). Raises before the first step.
+
+        The first call compiles the stored avals ahead of time and keeps
+        the executable; every later call returns the same object, and
+        from then on the step *runs* it (``_call_traced``; a batch of
+        another shape still takes the jitted function). So the text is
+        the text of what a profile taken afterwards profiles: its
+        instruction names are the executed ones, on any mesh. A step
+        that is never asked for its program, its cost or its byte plan
+        dispatches through ``jax.jit`` alone. ``reset_mesh()`` drops it.
 
         Its ``op_name``s are this process's own. The persistent cache's
         key leaves metadata out, so a hit may hand back an executable
@@ -909,13 +935,15 @@ class ShardedTrainStep:
         nothing. So this one compile makes the metadata part of the key:
         a persistent-cache hit only on a program traced from the same
         source, a compile of its own otherwise."""
-        flag = 'jax_compilation_cache_include_metadata_in_key'
-        before = getattr(jax.config, flag)
-        jax.config.update(flag, True)
-        try:
-            return self.lower().compile()
-        finally:
-            jax.config.update(flag, before)
+        if self._executable is None:
+            flag = 'jax_compilation_cache_include_metadata_in_key'
+            before = getattr(jax.config, flag)
+            jax.config.update(flag, True)
+            try:
+                self._executable = self.lower().compile()
+            finally:
+                jax.config.update(flag, before)
+        return self._executable
 
     def memory_pools(self):
         """This step's live persistent arrays as named residency pools

@@ -339,20 +339,3 @@ class Marker:
 
 def scope(name='<unk>:'):
     return _Scope(name, 'scope')
-
-
-def annotate(name):
-    """Decorator/context adding a named region to both the python trace and
-    the jax/XLA device trace."""
-    return jax.profiler.TraceAnnotation(name)
-
-
-class StepTraceAnnotation:
-    def __init__(self, step_num):
-        self._ctx = jax.profiler.StepTraceAnnotation("train", step_num=step_num)
-
-    def __enter__(self):
-        return self._ctx.__enter__()
-
-    def __exit__(self, *exc):
-        return self._ctx.__exit__(*exc)

@@ -23,10 +23,16 @@ embedded stream like any other trace dump).
 
 Armed together with tracing (``MXTPU_TRACE=1``): ``record_step()`` is
 a no-op while tracing is disarmed, so an untraced run pays one dict
-check per step. Loss values are resolved one step deferred — step N's
-device scalar is read when step N+1 is recorded, after its program has
-long finished — so recording never adds a host sync (the same
-deferred-read contract as ``resilience.NonFiniteGuard``).
+check per step. Recording never waits for the device: a step's loss
+joins a short queue of (record, loss) pairs, and each ``record_step()``
+reads only those whose array says ``is_ready()`` (a plain float or a
+numpy scalar is ready at once). The host may run many steps ahead of
+the device; a loss is then in its record a few steps late, and
+``snapshot(resolve_loss=True)`` reads what is still queued. No
+``float()`` of an unfinished program's output runs on the dispatch
+path, so switching the recorder on leaves the loop's pipelining as it
+was (until PR 39 it read the previous step's loss in every dispatch,
+which held the host one step behind the device).
 """
 from __future__ import annotations
 
@@ -69,15 +75,18 @@ class FlightRecorder:
         # to resolve `get().note(...)` through the accessor.
         self._lock = threading.RLock()
         self._last_t = None          # perf_counter of the previous step
-        self._pending_loss = None    # (record, device scalar) to resolve
+        # (record, loss) pairs not read yet; a record the ring has
+        # dropped need not be filled in, hence the same bound
+        self._pending = collections.deque(maxlen=self.capacity)
         self.dumps = 0
 
     # -- recording ---------------------------------------------------------
 
     def record_step(self, step, loss=None, guard_ok=None, extra=None):
-        """One training step completed. `loss` may be a device scalar —
-        it is NOT read here; it resolves at the NEXT record_step (one
-        step deferred, no host sync). No-op while tracing is disarmed."""
+        """One training step completed. `loss` may be a device scalar
+        whose program is still running: it is read by the first
+        record_step (or resolving snapshot) that finds it ready, never
+        waited for. No-op while tracing is disarmed."""
         if not _trace._state['on']:
             return
         now = _time.perf_counter()
@@ -106,35 +115,49 @@ class FlightRecorder:
         if extra:
             rec.update(extra)
         with self._lock:
-            pending, self._pending_loss = (
-                self._pending_loss, (rec, loss) if loss is not None
-                else None)
             self._steps.append(rec)
-        # resolve OUTSIDE the lock: the float() is a device read — ~free
-        # a full step after dispatch, but a wedged device must never
-        # wedge the lock (the watchdog's dump needs it to stall-report)
-        self._resolve(pending)
+            if loss is not None:
+                self._pending.append((rec, loss))
+        self._resolve(self._pop_pending(only_ready=True))
         _trace._sync_metrics()
 
-    def _pop_pending(self):
+    @staticmethod
+    def _ready(loss):
+        """Whether reading `loss` would return at once: a jax array
+        (bare or inside an NDArray) answers for itself, anything else (a
+        float, a numpy scalar) is ready. Never raises; an array that
+        cannot say (deleted, say) counts as ready and reads as None."""
+        probe = getattr(getattr(loss, '_data', loss), 'is_ready', None)
+        try:
+            return probe is None or bool(probe())
+        except Exception:
+            return True
+
+    def _pop_pending(self, only_ready=False):
+        """Take the queued (record, loss) pairs — with `only_ready`
+        those a read would not wait for, the others staying queued."""
+        taken, left = [], []
         with self._lock:
-            pending, self._pending_loss = self._pending_loss, None
-        return pending
+            for pair in self._pending:
+                (left if only_ready and not self._ready(pair[1])
+                 else taken).append(pair)
+            self._pending.clear()
+            self._pending.extend(left)
+        return taken
 
     @staticmethod
-    def _resolve(pending):
-        """Read a deferred loss scalar into its step record (its program
-        finished a full step ago; a failure records None). The record is
-        already in the ring — a concurrent reader sees None or the
-        float, never corruption."""
-        if pending is None:
-            return
-        rec, loss = pending
-        try:
-            # lint: host-sync-ok deliberately deferred ONE step: this program finished long ago
-            rec['loss'] = float(getattr(loss, '_data', loss))
-        except Exception:
-            rec['loss'] = None
+    def _resolve(pairs):
+        """Read each loss into its step record (a failure records None).
+        Called outside the lock: a wedged device must never wedge the
+        lock the watchdog's dump needs. The records are already in the
+        ring — a concurrent reader sees None or the float, never
+        corruption."""
+        for rec, loss in pairs:
+            try:
+                # lint: host-sync-ok only reached for a loss that is_ready() (record_step) or from a snapshot that asked for the wait
+                rec['loss'] = float(getattr(loss, '_data', loss))
+            except Exception:
+                rec['loss'] = None
 
     def note(self, kind, /, **info):
         """One notable event (fault fired, guard tripped, rollback,
@@ -189,9 +212,10 @@ class FlightRecorder:
             return [dict(e) for e in self._events]
 
     def snapshot(self, resolve_loss=False, signal_safe=False):
-        """The full post-mortem document. `resolve_loss=False` at crash
-        time: reading a pending device scalar could block on a wedged
-        device — the dump must never hang. `signal_safe=True` (fatal-
+        """The full post-mortem document. `resolve_loss=True` reads the
+        losses still queued, waiting for their programs; `False` at
+        crash time: that wait could be on a wedged device, and the dump
+        must never hang. `signal_safe=True` (fatal-
         signal handlers) additionally skips every metrics-registry
         touch: the interrupted frame may hold those locks."""
         if resolve_loss:
@@ -285,7 +309,7 @@ class FlightRecorder:
             self._steps.clear()
             self._events.clear()
             self._last_t = None
-            self._pending_loss = None
+            self._pending.clear()
 
 
 def default_dump_path():

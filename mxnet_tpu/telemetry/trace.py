@@ -1,20 +1,35 @@
 """Step-level span tracing: nested scopes over the training-step lifecycle.
 
 The metrics registry (``telemetry.metrics``) answers "how much"; this
-module answers "WHEN, and inside what". A ``span("name", **labels)``
-context manager emits chrome-trace ``'B'``/``'E'`` events into a
-lock-free per-thread ring buffer; ``chrome_events()`` merges every
-thread's ring into one balanced, deterministic ``traceEvents`` stream
-that chrome://tracing / Perfetto load directly (and that
-``profiler.dump()`` folds together with its own op rows and the
-telemetry ``'C'`` counter tracks).
+module answers "WHEN, and inside what". ``span("name", **labels)`` is
+the one entry point, and it writes to two places:
+
+- **Always, the profiler's host plane.** Every span is a
+  ``jax.profiler.TraceAnnotation`` named ``'mxtpu.' + name``. Whoever
+  takes a ``jax.profiler`` trace (the chip benchmark's traced run, a
+  user's ``start_trace``) finds the program's own spans on ``/host:CPU``
+  on the clock of the device planes, nested as the code nests them
+  (``mxtpu.step.dispatch`` round ``mxtpu.h2d.batch_put``,
+  ``mxtpu.step.compiled`` and ``mxtpu.step.gather``), with no switch to
+  set. The ``mxtpu.`` prefix is what a reader filters by
+  (``chipbench/hostspans.py``). While no profile is taken the
+  annotation records nothing.
+- **Armed (``MXTPU_TRACE=1`` or ``trace.enable()``), this module's
+  rings as well.** Chrome-trace ``'B'``/``'E'`` events go into a
+  lock-free per-thread ring buffer; ``chrome_events()`` merges every
+  thread's ring into one balanced, deterministic ``traceEvents`` stream
+  that chrome://tracing / Perfetto load directly (and that
+  ``profiler.dump()`` folds together with its own op rows and the
+  telemetry ``'C'`` counter tracks).
 
 Design constraints, in order:
 
-- **Disarmed cost is one attribute check.** ``span()`` reads the
-  module gate and returns a shared no-op singleton; nothing is
-  allocated, nothing is recorded (``MXTPU_TRACE=1`` arms it, or
-  ``trace.enable()``).
+- **Disarmed cost is the annotation's.** ``span()`` reads the module
+  gate and returns the annotation alone: no ring, no aggregate, the
+  labels not looked at. Entering and leaving it with no profile running
+  takes 0.56 us (this sandbox's CPU, jax 0.9.0, best of five runs of
+  200 000; the bare annotation 0.38 us, an armed span 3.0 us); a train
+  step opens four.
 - **Lock-free when armed.** Each thread appends to its own
   preallocated ring (only ring *creation* takes a lock). No
   cross-thread contention on the hot path; a full ring overwrites its
@@ -30,7 +45,7 @@ Design constraints, in order:
   ``tid_for_current_thread()``), plus ``'M'`` thread-name metadata —
   the merged trace has one coherent tid space instead of raw idents.
 
-Span timing: ``ts`` is ``time.time()`` microseconds (the same timebase
+Ring timing: ``ts`` is ``time.time()`` microseconds (the same timebase
 as profiler.py and the telemetry 'C' events, so merged streams align);
 per-span durations additionally aggregate into a per-thread
 ``{name: [count, total_us, self_us]}`` table — *self* time excludes
@@ -45,6 +60,8 @@ import json
 import os
 import threading
 import time as _time
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from ..base import telem_flags as _telem
 
@@ -200,29 +217,19 @@ def _rings_locked(timeout=2.0):
             _rings_lock.release()
 
 
-class _NullSpan:
-    """Shared disarmed span: enter/exit allocate nothing."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _NullSpan()
+PROFILE_PREFIX = 'mxtpu.'
 
 
 class _Span:
-    __slots__ = ('name', 'args', 'ring', 't0')
+    __slots__ = ('name', 'args', 'ring', 't0', 'mark')
 
     def __init__(self, name, args):
         self.name = name
         self.args = args
+        self.mark = _Annotation(PROFILE_PREFIX + name)
 
     def __enter__(self):
+        self.mark.__enter__()
         r = _ring()
         # lint: lockset-race-ok a _Span instance is created, entered and exited by ONE thread (span() builds a fresh instance per use); nothing shares it
         self.ring = r
@@ -257,15 +264,22 @@ class _Span:
             st[1] += dur
             st[2] += self_us
         r.spans_total += 1
+        self.mark.__exit__(*exc)
         return False
 
 
 def span(name, **labels):
-    """Nested timing scope. Armed: emits a chrome 'B'/'E' pair into
-    this thread's ring and aggregates (count, total, self) time under
-    `name`. Disarmed: returns a shared no-op (one dict check)."""
+    """Nested timing scope, and the one way this package names a stretch
+    of host time. Always a ``jax.profiler.TraceAnnotation`` named
+    ``'mxtpu.' + name``: in any ``jax.profiler`` trace the span lies on
+    the host plane beside the device's, nested as the code nests it, and
+    while no profile is taken the annotation does nothing. Armed, it
+    also emits a chrome 'B'/'E' pair into this thread's ring (with
+    ``labels`` as the event's args) and aggregates (count, total, self)
+    time under ``name``. Disarmed it is the annotation alone: no ring,
+    no aggregate, the labels not looked at."""
     if not _state['on']:
-        return _NULL
+        return _Annotation(PROFILE_PREFIX + name)
     return _Span(name, labels or None)
 
 

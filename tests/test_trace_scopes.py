@@ -390,3 +390,164 @@ def test_compiled_program_reads_its_own_names_through_a_warm_cache(tmp_path):
             is False
     finally:
         comp.clear(ledger='', cache_dir='')
+
+
+# ---------------------------------------------------------------------------
+# the step runs the executable whose text it hands out (ISSUE 39)
+# ---------------------------------------------------------------------------
+
+class _BackendCompiles:
+    """Backend compile requests jax reports, from the cache or not, since
+    this object was made. jax keeps a listener for the life of the
+    process, so the tests share one."""
+
+    _seen = None
+
+    def __init__(self):
+        cls = type(self)
+        if cls._seen is None:
+            cls._seen = [0]
+            jax.monitoring.register_event_duration_secs_listener(cls._on)
+        self._start = cls._seen[0]
+
+    @classmethod
+    def _on(cls, event, _duration, **_kw):
+        cls._seen[0] += event == '/jax/core/compile/backend_compile_duration'
+
+    @property
+    def n(self):
+        return self._seen[0] - self._start
+
+
+def _zero1_run(adopt, steps=3):
+    """One step, then ``steps`` more, on a dp=4 mesh with ZeRO-1 and the
+    guard; with ``adopt`` the step is asked for its program in between.
+    (losses, parameters by name less the prefix, step, what the run saw)"""
+    from mxnet_tpu.resilience import NonFiniteGuard
+    model, step, batch = _bert_step(
+        zero=1, dp=4, guard=NonFiniteGuard(policy='skip'))
+    losses = [float(step(*batch).asscalar())]
+    seen = {}
+    if adopt:
+        seen['donated'] = [p.data()._data
+                           for p in model.collect_params().values()][:4]
+        seen['exe'] = step.compiled_program()
+    compiles = _BackendCompiles()
+    losses += [float(step(*batch).asscalar()) for _ in range(steps)]
+    seen['compiles'] = compiles.n
+    cut = len(model.prefix)
+    params = {n[cut:]: onp.asarray(p.data()._data)
+              for n, p in model.collect_params().items()}
+    return losses, params, step, seen
+
+
+def test_the_step_runs_the_executable_it_hands_out():
+    losses, params, step, seen = _zero1_run(adopt=True)
+    exe = seen['exe']
+    # one object, kept: a second asking compiles nothing and hands it back
+    assert step.compiled_program() is exe
+    assert step._executable is exe
+    # the steps after the adoption compiled nothing: they ran `exe`
+    assert seen['compiles'] == 0
+    # ... which donates what the jitted step donates
+    assert all(a.is_deleted() for a in seen['donated'])
+    assert exe.memory_analysis().alias_size_in_bytes > 0
+    # ... and takes the arrays where the layout placed them: the mesh's
+    # shardings, ZeRO-1's state shards among them, and not one device's
+    flat = jax.tree_util.tree_leaves(exe.input_shardings[0])
+    assert all(len(s.device_set) == 4 for s in flat)
+    twin_losses, twin_params, twin, twin_seen = _zero1_run(adopt=False)
+    assert twin._executable is None and twin_seen['compiles'] == 0
+    assert losses == twin_losses
+    assert params.keys() == twin_params.keys()
+    for name, value in params.items():
+        assert onp.array_equal(value, twin_params[name]), name
+    assert step._guard.bad_steps == twin._guard.bad_steps == 0
+
+
+def test_a_step_never_asked_for_its_program_keeps_to_the_jitted_one():
+    _model, step, batch = _bert_step()
+    step(*batch)
+    step(*batch)
+    assert step._executable is None
+    # cost_analysis() and memory_analysis() read the program too: both
+    # adopt it, once
+    cost = step.cost_analysis()
+    assert cost is None or cost['flops'] > 0
+    exe = step._executable
+    assert exe is not None
+    step.memory_analysis()
+    assert step._executable is exe and step.compiled_program() is exe
+
+
+def test_another_batch_shape_falls_back_to_the_jitted_step():
+    model, step, (inputs, labels) = _bert_step()
+    step(inputs, labels)
+    exe = step.compiled_program()
+    half = ([nd.array(x.asnumpy()[:4]) for x in inputs],
+            [nd.array(x.asnumpy()[:4]) for x in labels])
+    compiles = _BackendCompiles()
+    assert onp.isfinite(float(step(*half).asscalar()))
+    assert compiles.n > 0               # jit built the other shape
+    assert step._executable is exe      # and the stored one stays ...
+    before = compiles.n
+    assert onp.isfinite(float(step(inputs, labels).asscalar()))
+    assert compiles.n == before         # ... for the batch it was made for
+
+
+def test_reset_mesh_drops_the_executable():
+    _model, step, batch = _bert_step(zero=1, dp=4)
+    first = float(step(*batch).asscalar())
+    exe = step.compiled_program()
+    step.reset_mesh(make_mesh((2,), ('dp',)))
+    assert step._executable is None and step._compiled is None
+    again = float(step(*batch).asscalar())
+    assert onp.isfinite(again) and again < first
+    assert step._executable is None
+    assert step.compiled_program() is not exe
+
+
+def test_the_executable_returns_guard_sparse_and_fault_outputs_in_order(
+        monkeypatch):
+    """The tail of the step's outputs — the loss, the guard's flag, the
+    RowSparse statistics — and the fault scale among its inputs are read
+    by position: the adopted executable hands them over as the jitted
+    step does."""
+    from mxnet_tpu.resilience import NonFiniteGuard, faults
+    monkeypatch.setenv('MXTPU_SPARSE', '1')
+
+    def run(adopt):
+        mx.random.seed(11)
+        net = nn.HybridSequential(prefix='sp_')
+        with net.name_scope():
+            net.add(nn.Embedding(200, 8, sparse_grad=True))
+            net.add(nn.Dense(4, flatten=False))
+        net.initialize()
+        guard = NonFiniteGuard(policy='skip', max_consecutive_bad=10)
+        step = ShardedTrainStep(
+            net, lambda out, label: (out - label) ** 2, 'adam',
+            {'learning_rate': 0.01}, mesh=make_mesh((2,), ('dp',)),
+            guard=guard)
+        rng = onp.random.RandomState(0)
+        ids = nd.array(rng.randint(0, 40, (16, 5)).astype(onp.float32))
+        lab = nd.array(rng.randn(16, 5, 4).astype(onp.float32))
+        losses = [float(step(ids, lab).asscalar())]
+        if adopt:
+            step.compiled_program()
+        faults.arm('step.dispatch', 'nan', window=(2, 3))
+        try:
+            losses += [float(step(ids, lab).asscalar()) for _ in range(4)]
+        finally:
+            faults.disarm()
+        assert step._sparse_names and (step._executable is not None) is adopt
+        live = {n: int(v) for n, v in step._sparse_prev_stats.items()}
+        return losses, guard.bad_steps, live, net[0].weight.data().asnumpy()
+
+    losses, bad, live, table = run(adopt=True)
+    twin_losses, twin_bad, twin_live, twin_table = run(adopt=False)
+    # dispatches 2 and 3 (counted from 0) were poisoned, and only they
+    assert list(onp.isnan(losses)) == [False, False, True, True, False]
+    assert bad == twin_bad == 2
+    assert live == twin_live and all(0 < v <= 40 for v in live.values())
+    onp.testing.assert_array_equal(losses, twin_losses)
+    assert onp.array_equal(table, twin_table)
