@@ -130,59 +130,77 @@ def _pad_up(n, b):
     return -(-n // b) * b
 
 
-def fa_block_layouts(BH, Tq, Tk, D, kind, G, bq, bk):
+def fa_block_layouts(BH, Tq, Tk, D, kind, G, bq, bk, N=None, rep=1):
     """(name, array_shape, block_shape) for every operand block the
     flash kernels of ``kind`` would instantiate at (G, bq, bk), including
     the bq/bk padding of the sequence dims. The kernels address the
     model's (N, T, H*D) arrays in lane blocks of W columns
     (``pallas_attention._lane_block``: 128 where D divides it, W // D
-    heads side by side, else D), G heads a grid step being G // (W // D)
-    batch rows of one lane block. The softmax row statistics (lse,
-    delta) cross HBM with the queries on the lanes, (N, H, 1, Tq) in
-    (Gn, hb, 1, bq) blocks: bq, like bk for the key mask, is a multiple
-    of 128 or the whole padded sequence. N and H are not in the tuning
-    key, so the arrays are written here one lane block wide: the
-    trailing two dims, which the tile rule reads, are the kernels' own."""
+    heads side by side, else D). G heads a grid step are Gn batch rows
+    of ``lb`` adjacent lane blocks: rows first, G // (W // D) of them,
+    and what a batch of ``N`` rows cannot supply as lane blocks
+    (``pallas_attention._step_heads``).
+    The softmax row statistics (lse, delta) cross HBM with the queries
+    on the lanes, (N, H, 1, Tq) in (Gn, heads of a row, 1, bq) blocks:
+    bq, like bk for the key mask, is a multiple of 128 or the whole
+    padded sequence. With ``rep`` query heads to a key/value head a
+    step's query heads share one key/value lane block: the blocks listed
+    are the forward's and dq's; dk/dv's are the whole group wide on the
+    query side whatever G is. N is not in the tuning key: without it the
+    batch is taken to fill G by rows and the arrays are written one lane
+    block wide, their trailing two dims, which the tile rule reads, the
+    kernels' own."""
+    from . import pallas_attention as pa
     tq, tk = _pad_up(Tq, bq), _pad_up(Tk, bk)
-    W = _LANE if _LANE % D == 0 else D
-    hb = W // D
-    rows, Gn = max(1, BH // hb), max(1, G // hb)
-    layouts = [
-        ('q', (rows, tq, W), (Gn, bq, W)),
-        ('k', (rows, tk, W), (Gn, bk, W)),
-        ('v', (rows, tk, W), (Gn, bk, W)),
-        ('kmask', (rows, 1, tk), (Gn, 1, bk)),
-        ('lse', (rows, hb, 1, tq), (Gn, hb, 1, bq)),
-    ]
-    if kind == 'fwd':
-        layouts.append(('out', (rows, tq, W), (Gn, bq, W)))
+    if N is None:
+        W = _LANE if _LANE % D == 0 else D
+        hb = heads = W // D
+        rows, Gn, lq, lk = max(1, BH // hb), max(1, G // hb), 1, 1
+        cq = ck = W
     else:
-        layouts += [('do', (rows, tq, W), (Gn, bq, W)),
-                    ('delta', (rows, hb, 1, tq), (Gn, hb, 1, bq)),
-                    ('dq', (rows, tq, W), (Gn, bq, W)),
-                    ('dk', (rows, tk, W), (Gn, bk, W)),
-                    ('dv', (rows, tk, W), (Gn, bk, W))]
+        rows, heads = N, BH // N
+        cq, ck = heads * D, heads * D // rep
+        W, hb = pa._lane_block(cq, D)
+        Gn, lb = pa._step_heads(N, cq, D, G, rep)
+        lq, lk = pa._step_lanes(kind, lb, rep)
+    q_side = ((rows, tq, cq), (Gn, bq, lq * W))
+    k_side = ((rows, tk, ck), (Gn, bk, lk * W))
+    stat = ((rows, heads, 1, tq), (Gn, lq * hb, 1, bq))
+    layouts = [('q',) + q_side, ('k',) + k_side, ('v',) + k_side,
+               ('kmask', (rows, 1, tk), (Gn, 1, bk)), ('lse',) + stat]
+    if kind == 'fwd':
+        layouts.append(('out',) + q_side)
+    else:
+        layouts += [('do',) + q_side, ('delta',) + stat, ('dq',) + q_side,
+                    ('dk',) + k_side, ('dv',) + k_side]
     return layouts
 
 
-def vmem_bytes(G, bq, bk, D, kind):
-    """Scoped-VMEM estimate for one kernel invocation: double-buffered
-    IO blocks + f32 scratch accumulators + the live (bq, bk) f32 stack
-    temporaries (~3 forward: s/p/pv; ~6 backward: s/p/dp/ds/keep/pv).
-    The 256 columns a row beside D are two 128-lane columns of float32:
-    the forward's m and l, dq's lse and delta turned into columns; the
-    statistics' own (1, bq) row blocks are a few KB and not counted.
-    The same arithmetic ``_block_sizes`` has guarded with since round 4."""
+def vmem_bytes(G, bq, bk, D, kind, itemsize=4, kv=None):
+    """Scoped-VMEM estimate for one kernel invocation of G heads a step,
+    whichever way they come (rows or lane blocks): double-buffered IO
+    blocks of ``itemsize`` bytes an element + f32 scratch accumulators +
+    the live (bq, bk) f32 stack temporaries (~3 forward: s/p/pv; ~6
+    backward: s/p/dp/ds/keep/pv). ``kv``: the heads on the key/value
+    side of the step, fewer than G where grouped-query heads share a
+    key/value block (default: G). The 256 columns a row beside D are two
+    128-lane columns of float32: the forward's m and l, dq's lse and
+    delta turned into columns; the statistics' own (1, bq) row blocks
+    are a few KB and not counted. At 4 bytes and kv = G it is the
+    arithmetic ``_block_sizes`` has guarded with since round 4."""
     n_tmp = 3 if kind == 'fwd' else 6
-    return (2 * G * (bq + 2 * bk) * D * 4
-            + G * (bq + bk) * (D + 256) * 4
+    kv = G if kv is None else kv
+    return (2 * (G * bq + 2 * kv * bk) * D * itemsize
+            + (G * bq + kv * bk) * (D + 256) * 4
             + n_tmp * bq * bk * 4)
 
 
-def check_candidate(BH, Tq, Tk, D, dtype, kind, G, bq, bk):
+def check_candidate(BH, Tq, Tk, D, dtype, kind, G, bq, bk, N=None, rep=1):
     """Full static legality of one (G, bq, bk) candidate. Returns
     (ok, reason-or-None); every reject reason names the rule so sweep
-    reports and tests can assert WHY a shape was pruned."""
+    reports and tests can assert WHY a shape was pruned. ``N`` and
+    ``rep`` (query heads to a key/value head) where the caller knows
+    them: :func:`fa_block_layouts`."""
     sub = sublane_min(dtype)
     if G < 1 or BH % G:
         return False, f"G={G} does not divide BH={BH}"
@@ -194,11 +212,12 @@ def check_candidate(BH, Tq, Tk, D, dtype, kind, G, bq, bk):
         return False, (f"blocks ({bq}, {bk}) not multiples of the "
                        f"{sub}-row sublane tile")
     for name, ashape, bshape in fa_block_layouts(BH, Tq, Tk, D, kind,
-                                                 G, bq, bk):
+                                                 G, bq, bk, N, rep):
         ok, why = tile_legal(ashape, bshape, dtype)
         if not ok:
             return False, f"{name}: {why}"
-    vb = vmem_bytes(G, bq, bk, D, kind)
+    vb = vmem_bytes(G, bq, bk, D, kind, jnp.dtype(dtype).itemsize,
+                    max(1, G // rep))
     if vb > VMEM_BUDGET:
         return False, (f"VMEM estimate {vb} exceeds the "
                        f"{VMEM_BUDGET}-byte budget")
@@ -419,13 +438,14 @@ def _env_overrides(kind):
     return out
 
 
-def resolve(kernel, BH, Tq, Tk, D, dtype, kind, default):
+def resolve(kernel, BH, Tq, Tk, D, dtype, kind, default, rep=1):
     """The block shapes a kernel build should use, with precedence
     (sweep-forced) > env override > DB winner > ``default``, followed
     by the safety clamps ``_block_sizes`` has always applied (G to a
-    divisor of BH, then down until the VMEM estimate fits the budget).
-    Records the decision — source included — for the compile-ledger
-    signature (:func:`decision_flags`)."""
+    divisor of BH, then down until the VMEM estimate fits the budget;
+    with ``rep`` query heads to a key/value head G // rep heads on the
+    key/value side). Records the decision — source included — for the
+    compile-ledger signature (:func:`decision_flags`)."""
     sig = shape_sig(BH, Tq, Tk, D, dtype, kind)
     with _lock:
         force = _forced.get((kernel, kind))
@@ -454,7 +474,9 @@ def resolve(kernel, BH, Tq, Tk, D, dtype, kind, default):
         G -= 1
     # scoped-VMEM guard: shrink G (to the next smaller divisor) until
     # the estimate fits — identical to the historical _block_sizes loop
-    while G > 1 and vmem_bytes(G, bq, bk, D, kind) > VMEM_BUDGET:
+    while G > 1 and vmem_bytes(G, bq, bk, D, kind,
+                               jnp.dtype(dtype).itemsize,
+                               max(1, G // rep)) > VMEM_BUDGET:
         G -= 1
         while BH % G:
             G -= 1

@@ -10,74 +10,89 @@ VMEM.
 Operands and results are the model's own arrays: q, k, v and o, dO, dq,
 dk, dv are (N, T, C = H*D), in the model's dtype, and q, k and v may be
 the three thirds of one fused (N, T, 3C) projection, each addressed at a
-static column offset. A block is (Gn, bq | bk, W): Gn batch rows, a
-sequence block, one *lane block* of W columns that holds W // D whole
-heads (:func:`_lane_block`: two 64-wide heads to 128 lanes). Inside a
-grid step a head is picked out of its lane block by a lane mask on the
-(·, W) operands (:func:`_own`): a contraction over 128 lanes of which 64
-are zero costs the 128-deep MXU what one over 64 does. Nothing is
-transposed, split or cast round the calls.
+static column offset. A block is (Gn, bq | bk, lb·W): Gn batch rows, a
+sequence block, ``lb`` adjacent *lane blocks* of W columns that hold
+W // D whole heads each (:func:`_lane_block`: two 64-wide heads to 128
+lanes). Inside a grid step the kernels walk the lane blocks by static
+column slices of their refs (:func:`_step_blocks`), and a head is picked
+out of its lane block by a lane mask on the (·, W) operands
+(:func:`_own`): a contraction over 128 lanes of which 64 are zero costs
+the 128-deep MXU what one over 64 does. Nothing is transposed, split or
+cast round the calls.
 
-Forward: grid (N/Gn, C/W, Tq/BQ, Tk/BK) — each invocation processes
-G = Gn·(W // D) batch·head slices (per-invocation overhead on the TPU is
-tens of microseconds, so tiny per-head grids are dispatch-bound; G
-amortises it). Scratch (VMEM) carries the online-softmax state (running
-max m, running sum l, f32 accumulator) across k-blocks; the final k-block
-normalises and writes the output block plus the logsumexp (saved for the
-backward pass) as a row, the queries on the lanes.
+A grid step holds G = Gn·lb·(W // D) batch·head slices
+(:func:`_block_sizes`): filled first by batch rows
+(:func:`_rows_per_step`) and, what the rows cannot supply, by lane blocks
+(:func:`_lane_blocks_per_step`), so a batch of one row still gets its G
+heads a step. What G amortises is a grid step's fixed cost, measured on a
+v5e at 0.4 µs in the forward and dq and 0.6 µs in dk/dv (chip runs, PR 40:
+one row of 16 heads of 128 at 1, 2, 4 and 8 heads a step fits step = a +
+heads·b with b, a head and cell, 2.0, 0.29 and 0.39 µs): beside one head's
+256 × 256 backward cell it was three fifths of the step. However its heads
+come, a head's scores, softmax, dropout bits, accumulation order over
+cells and rounding are the same: out, lse, dq, dk and dv do not change by
+a bit with G.
 
-Backward: two Pallas kernels — dq (grid (N/Gn, C/W, Tq/BQ, Tk/BK),
-accumulating over k-blocks) and dk/dv (grid (N/Gn, C/W, Tk/BK, Tq/BQ),
-accumulating over q-blocks) — both recompute the probability block from
-the saved LSE (flash-attention backward recurrence), so live memory stays
-O(T); each rounds its float32 accumulator to the operands' dtype once, as
-it writes. dq holds a cell as the forward does, (bq, bk), queries down the
-sublanes. dk/dv holds it keys down, (bk, bq): s^T = k·q^T, p^T, dp^T =
-v·dO^T, ds^T, so that dv += p^T·dO and dk += ds^T·q are plain products
-and the queries' statistics broadcast down the sublanes as the rows they
-are (:func:`_cell` hands out every mask and the dropout bits in either
-orientation: the same function of (q position, k position)).
+Forward: grid (N/Gn, C/(lb·W), Tq/BQ, Tk/BK). Scratch (VMEM) carries the
+online-softmax state (running max m, running sum l, f32 accumulator)
+across k-blocks; the final k-block normalises and writes the output block
+plus the logsumexp (saved for the backward pass) as a row, the queries on
+the lanes.
+
+Backward: two Pallas kernels — dq (grid (N/Gn, C/(lb·W), Tq/BQ, Tk/BK),
+accumulating over k-blocks) and dk/dv (grid (N/Gn, C/(lb·W), Tk/BK,
+Tq/BQ), accumulating over q-blocks) — both recompute the probability block
+from the saved LSE (flash-attention backward recurrence), so live memory
+stays O(T); each rounds its float32 accumulator to the operands' dtype
+once, as it writes. dq holds a cell as the forward does, (bq, bk), queries
+down the sublanes. dk/dv holds it keys down, (bk, bq): s^T = k·q^T, p^T,
+dp^T = v·dO^T, ds^T, so that dv += p^T·dO and dk += ds^T·q are plain
+products and the queries' statistics broadcast down the sublanes as the
+rows they are (:func:`_cell` hands out every mask and the dropout bits in
+either orientation: the same function of (q position, k position)).
 
 The softmax row statistics — lse = m + log l from the forward, delta =
-rowsum(dO·O) from XLA — are float32 (N, H, 1, Tq) arrays in
-(Gn, hb, 1, bq) blocks: the queries lie on the lanes, so HBM holds and
-moves them at their size (a last dimension of 1 would be held, and
-moved, in 128-lane tiles, 128 times it). dk/dv reads the (1, bq) rows as
-they are. The forward and dq want a statistic beside each row of a
-(bq, bk) cell and turn rows into columns, or back, once an *outer* block
-and not once a cell, all the heads of the step in one transpose: dq lays
-its rows side by side and transposes them into a VMEM scratch at the
-first k-block of a q-block (:func:`_as_columns`), the forward gathers its
-heads' m + log l into the lanes of one (bq, 128) array and transposes
-that at the last. dk/dv does the same for the additive key mask, a
-(1, bk) row it wants as a column, once a k-block. ``row_stat_blocks``
-counts the statistics' blocks of every build.
+rowsum(dO·O) from XLA — are float32 (N, H, 1, Tq) arrays in (Gn, heads of
+a row of the step, 1, bq) blocks: the queries lie on the lanes, so HBM
+holds and moves them at their size (a last dimension of 1 would be held,
+and moved, in 128-lane tiles, 128 times it). dk/dv reads the (1, bq) rows
+as they are. The forward and dq want a statistic beside each row of a (bq,
+bk) cell and turn rows into columns, or back, once an *outer* block and
+not once a cell, all the heads of the step in one transpose: dq lays its
+rows side by side and transposes them into a VMEM scratch at the first
+k-block of a q-block (:func:`_as_columns`), the forward gathers its heads'
+m + log l into the lanes of one (bq, 128) array and transposes that at the
+last. dk/dv does the same for the additive key mask, a (1, bk) row it
+wants as a column, once a k-block. ``row_stat_blocks`` counts the
+statistics' blocks of every build.
 
 Without ``causal`` every (q-block, k-block) cell of those grids is
-computed. With it the grid is (N/Gn, C/W, listed cells): the cells that
-hold a score at or under the diagonal (:func:`_cell_live`), row by row for
-the forward and dq, column by column for dk/dv, from a small table the
-kernels and their index maps read as a scalar-prefetch operand
+computed. With it the grid is (N/Gn, C/(lb·W), listed cells): the cells
+that hold a score at or under the diagonal (:func:`_cell_live`), row by
+row for the forward and dq, column by column for dk/dv, from a small table
+the kernels and their index maps read as a scalar-prefetch operand
 (:func:`_causal_cell_table`). A cell wholly above the diagonal is no grid
 step at all: no matmul, no hash, no exp, no copy. Such a cell used to add
 exp(-1e30 - m) = 0 to every accumulator, and the listed cells keep their
-order, so no result changes by a bit. The cells the diagonal crosses
-still mask element by element (:func:`_cell`'s ``scores``). All of it hangs on
+order, so no result changes by a bit. The cells the diagonal crosses still
+mask element by element (:func:`_cell`'s ``scores``). All of it hangs on
 the static ``causal`` flag. Each causal build counts its cells in
 ``causal_cells``, and every build its addressing in ``head_blocks``.
 
-A ``window`` (a causal layer that sees the ``window`` keys up to its
-own) is a second inequality beside ``_cell_live`` (:func:`_cell_in_window`):
+A ``window`` (a causal layer that sees the ``window`` keys up to its own)
+is a second inequality beside ``_cell_live`` (:func:`_cell_in_window`):
 the table lists the band's cells only, and inside a cell the two masks are
 one unsigned comparison of i - j. Grouped-query heads (``num_kv_heads``
 fewer than the query heads, a head a whole lane block): the forward and dq
-grids run over the query heads and read key/value lane block ``l // rep``
-through the index map; the dk/dv grid runs over the key/value heads, its
-q, dO, lse and delta blocks a whole group of ``rep`` query heads wide, and
-adds the group's heads into the one dk and dv inside the kernel. Each
-windowed build counts its cells in ``window_cells``. Without either, the
-builds are the ones tests/test_causal_skip.py pins, equation for
-equation.
+grids run over the query heads, ``lb`` of one group a step (``lb`` divides
+``rep``), and read the group's one key/value lane block through the index
+map; the dk/dv grid runs over the key/value heads, its q, dO, lse and
+delta blocks a whole group of ``rep`` query heads wide, and adds the
+group's heads into the one dk and dv inside the kernel: the same walk over
+a step's lane blocks, ``rep`` of them on the query side to one on the
+key/value side (:func:`_step_lanes`). Each windowed build counts its cells
+in ``window_cells``. Without either, the builds are the ones
+tests/test_causal_skip.py pins, equation for equation.
 
 Attention dropout runs INSIDE the kernels: the keep mask is a
 counter-based hash (murmur3 finalizer) of the global (batch·head, q, k)
@@ -90,12 +105,12 @@ dropout scales only the value accumulation — matching the standard
 softmax→dropout→matmul recipe.
 
 Mosaic layout constraints honoured throughout: every block's trailing two
-dims are (multiple-of-8, multiple-of-128) or equal to the array dims —
-the key-mask rides as (N, 1, Tk) with (Gn, 1, bk) blocks, one row a
-batch row, and the statistics as (N, H, 1, Tq) with (Gn, W // D, 1, bq)
-blocks, one row a head (a (1, bk) 2-D mask block is refused): bq and bk
-are multiples of 128 or the whole padded sequence. The two scalars the
-kernels read (dropout seed, global batch·head base) ride in SMEM.
+dims are (multiple-of-8, multiple-of-128) or equal to the array dims — the
+key-mask rides as (N, 1, Tk) with (Gn, 1, bk) blocks, one row a batch row,
+and the statistics as (N, H, 1, Tq) with (Gn, lb·(W // D), 1, bq) blocks,
+one row a head (a (1, bk) 2-D mask block is refused): bq and bk are
+multiples of 128 or the whole padded sequence. The two scalars the kernels
+read (dropout seed, global batch·head base) ride in SMEM.
 
 What the backward reads of the forward, ``o`` and ``lse``, the
 ``custom_vjp``'s forward rule (:func:`_flash_fwd`) passes through
@@ -134,14 +149,14 @@ from .. import scopes as _scopes
 _NEG_INF = -1e30
 _LANES = 128
 
-# every grid here is (batch-row groups, lane blocks, outer seq blocks,
-# inner seq blocks) with the scratch accumulators carried over the
-# innermost axis
+# every grid here is (batch-row groups, steps of lane blocks, outer seq
+# blocks, inner seq blocks) with the scratch accumulators carried over
+# the innermost axis
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=('parallel', 'parallel', 'parallel', 'arbitrary'))
 
-# the causal grid is (batch-row groups, lane blocks, listed cells), the
-# accumulators carried over the cells of one row or column
+# the causal grid is (batch-row groups, steps of lane blocks, listed
+# cells), the accumulators carried over the cells of one row or column
 _CAUSAL_COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=('parallel', 'parallel', 'arbitrary'))
 
@@ -191,6 +206,62 @@ def _rows_per_step(N, G, hb):
     while N % Gn:
         Gn -= 1
     return Gn
+
+
+def _lane_blocks_per_step(G, Gn, hb, blocks):
+    """Adjacent lane blocks of one grid step: what of ``G`` heads the Gn
+    rows of one lane block (Gn * hb heads) do not supply, at least one,
+    clamped to a divisor of ``blocks``: the lane blocks of the array, or
+    with grouped-query heads those of one group, so that the step's
+    heads share a key/value block. Rows come first: a batch that fills G
+    by rows gets one lane block a step, the build it always had."""
+    lb = max(1, min(G // (Gn * hb), blocks))
+    while blocks % lb:
+        lb -= 1
+    return lb
+
+
+def _step_heads(N, C, D, G, rep=1):
+    """(Gn, lb): how the ``G`` heads of a grid step are made up over an
+    (N, T, C) array of D-wide heads -- rows first, then lane blocks of
+    the array, or of one group where ``rep`` query heads share a
+    key/value head."""
+    W, hb = _lane_block(C, D)
+    Gn = _rows_per_step(N, G, hb)
+    return Gn, _lane_blocks_per_step(G, Gn, hb, rep if rep > 1 else C // W)
+
+
+def _step_lanes(kind, lb, rep):
+    """(lq, lk): the lane blocks a grid step of ``kind`` spans on the
+    query side (q, o, dO, dq; lq * hb rows of lse and delta) and on the
+    key/value side (k, v, dk, dv). ``lb`` on both without groups. With
+    ``rep`` query heads to a key/value head the step's query heads share
+    one key/value lane block: ``lb`` of the group's in the forward and
+    dq, whose grid runs over the query heads, the whole group in dk/dv,
+    whose grid runs over the key/value heads. Query lane block j of a
+    step reads key/value lane block j * lk // lq."""
+    if rep == 1:
+        return lb, lb
+    return (rep, 1) if kind == 'bwd_dkv' else (lb, 1)
+
+
+def _cols(j, n, W):
+    """The static column slice of lane block ``j`` of the ``n`` a block
+    spans; the whole block where it spans one."""
+    return slice(None) if n == 1 else slice(j * W, (j + 1) * W)
+
+
+def _step_blocks(Gn, lq, lk, W):
+    """The (row, query lane block) pairs of one grid step, in the order
+    the kernels unroll them, as (g, j, query columns, key/value columns,
+    first, last): the column slices of lane block j and of the key/value
+    lane block it reads, j * lk // lq, and whether j is the first or the
+    last query lane block of that key/value block -- where a kernel
+    loads, and dk/dv stores, what the block's query heads share."""
+    for g in range(Gn):
+        for j in range(lq):
+            yield (g, j, _cols(j, lq, W), _cols(j * lk // lq, lk, W),
+                   j * lk % lq == 0, (j + 1) * lk % lq == 0)
 
 
 def _cell_live(qb, kb, bq, bk):
@@ -257,25 +328,26 @@ def _step_cell(cells_ref, q_axis):
             lambda: cells_ref[2, cell] == 1, lambda: cells_ref[3, cell] == 1)
 
 
-def _block_specs(kind, Gn, hb, bq, bk, W, causal, rep=1):
+def _block_specs(kind, Gn, hb, bq, bk, W, causal, lb=1, rep=1):
     """The BlockSpecs of one call of ``kind`` ('fwd', 'bwd_dq',
     'bwd_dkv'), by role, as (seq, row, mask): ``seq(side, off)`` a
-    (Gn, bq | bk, W) block of an (N, T, columns) array on the 'q' or the
-    'k' side, ``off`` lane blocks into the columns (where q, k and v are
-    ranges of one array); ``row`` the q-side (Gn, hb, 1, bq) block of an
-    (N, H, 1, Tq) array of row statistics (lse, delta), Tq on the lanes;
-    ``mask`` the (Gn, 1, bk) block of the (N, 1, Tk) key mask. The grid
-    is (row group b, lane block l, then the full plane, k-blocks
+    (Gn, bq | bk, lq * W | lk * W) block of an (N, T, columns) array on
+    the 'q' or the 'k' side (:func:`_step_lanes`), ``off`` such blocks
+    into the columns (where q, k and v are ranges of one array); ``row``
+    the q-side (Gn, lq * hb, 1, bq) block of an (N, H, 1, Tq) array of
+    row statistics (lse, delta), Tq on the lanes; ``mask`` the
+    (Gn, 1, bk) block of the (N, 1, Tk) key mask. The grid is (row group
+    b, step l of ``lb`` lane blocks, then the full plane, k-blocks
     outermost in dk/dv and q-blocks elsewhere, or the listed cell with
     the cell table as scalar-prefetch operand, ``causal``). No index map
     computes anything, but for an ``off``. Counts the build in
     ``row_stat_blocks``.
 
     Grouped-query heads (``rep`` query heads to a key/value head, a head
-    a lane block): where the lane blocks of the grid are the query
-    heads' (forward, dq), the 'k' side reads lane block ``l // rep``;
-    where they are the key/value heads' (dk/dv), the 'q' side and
-    ``row`` are ``rep`` heads wide, the whole group of head ``l``."""
+    a lane block): where the steps of the grid are over the query heads
+    (forward, dq), ``rep // lb`` of them read one key/value lane block,
+    ``l // (rep // lb)``; where they are over the key/value heads
+    (dk/dv), the 'q' side and ``row`` are the whole group of head ``l``."""
     if causal:
         def qi(c, cells): return cells[0, c]
         def ki(c, cells): return cells[1, c]
@@ -285,21 +357,20 @@ def _block_specs(kind, Gn, hb, bq, bk, W, causal, rep=1):
     else:
         def qi(j, i): return i
         def ki(j, i): return j
-    q_wide = rep if kind == 'bwd_dkv' else 1
+    lq, lk = _step_lanes(kind, lb, rep)
+    # steps of the grid to one key/value lane block
+    shared = rep // lq if rep > 1 and kind != 'bwd_dkv' else 1
 
     def seq(side, off=0):
-        rows, at = (bq, qi) if side == 'q' else (bk, ki)
-        if side == 'q' and q_wide > 1:
-            return pl.BlockSpec((Gn, rows, q_wide * W),
-                                lambda b, l, *s: (b, at(*s), l))
-        if side == 'k' and rep > 1 and kind != 'bwd_dkv':
-            return pl.BlockSpec((Gn, rows, W),
-                                lambda b, l, *s: (b, at(*s), l // rep))
+        rows, at, lanes = (bq, qi, lq) if side == 'q' else (bk, ki, lk)
+        block = (Gn, rows, lanes * W)
+        if side == 'k' and shared > 1:
+            return pl.BlockSpec(block,
+                                lambda b, l, *s: (b, at(*s), l // shared))
         if off:
-            return pl.BlockSpec((Gn, rows, W),
-                                lambda b, l, *s: (b, at(*s), l + off))
-        return pl.BlockSpec((Gn, rows, W), lambda b, l, *s: (b, at(*s), l))
-    row = pl.BlockSpec((Gn, q_wide * hb, 1, bq),
+            return pl.BlockSpec(block, lambda b, l, *s: (b, at(*s), l + off))
+        return pl.BlockSpec(block, lambda b, l, *s: (b, at(*s), l))
+    row = pl.BlockSpec((Gn, lq * hb, 1, bq),
                        lambda b, l, *s: (b, l, 0, qi(*s)))
     mask = pl.BlockSpec((Gn, 1, bk), lambda b, l, *s: (b, 0, ki(*s)))
     key = (kind, row.block_shape)
@@ -307,24 +378,42 @@ def _block_specs(kind, Gn, hb, bq, bk, W, causal, rep=1):
     return seq, row, mask
 
 
-def _call(kernel, cells, grid, in_specs, out_specs, scratch_shapes, **call):
-    """``pallas_call`` of ``kernel``: over ``grid`` as it is (``cells``
-    None: it ends in the full plane's two axes), or over ``grid`` + the
-    listed cells of a causal build, already applied to the cell table."""
+@functools.lru_cache(maxsize=None)
+def _body(kernel, tabled, **static):
+    """``kernel`` with its static arguments bound, as the function
+    ``pallas_call`` traces: of the refs, the cell table's first where the
+    build has one (``tabled``). A kernel's body follows from these
+    arguments and the shapes of its refs alone, so it is traced once for
+    them and not once a call: ``jit(inline=True)`` keeps the jaxpr and
+    writes its equations into the ``pallas_call``'s own, the same
+    equations. A step of twelve layers, or of 32 applications of eight
+    blocks, traces three bodies; the wider a grid step (G heads unrolled),
+    the more each costs."""
+    def body(*refs):
+        if tabled:
+            return kernel(*refs[1:], cells_ref=refs[0], **static)
+        return kernel(*refs, **static)
+    return jax.jit(body, inline=True)
+
+
+def _call(kernel, static, cells, grid, in_specs, out_specs, scratch_shapes,
+          **call):
+    """``pallas_call`` of ``kernel`` with its ``static`` arguments: over
+    ``grid`` as it is (``cells`` None: it ends in the full plane's two
+    axes), or over ``grid`` + the listed cells of a causal build, already
+    applied to the cell table."""
+    body = _body(kernel, cells is not None, **static)
     if cells is None:
         return pl.pallas_call(
-            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            body, grid=grid, in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch_shapes,
             compiler_params=_COMPILER_PARAMS, **call)
-
-    def with_table(cells_ref, *refs):
-        return kernel(*refs, cells_ref=cells_ref)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=grid + (cells.shape[1],),
         in_specs=in_specs, out_specs=out_specs,
         scratch_shapes=scratch_shapes)
     return functools.partial(
-        pl.pallas_call(with_table, grid_spec=grid_spec,
+        pl.pallas_call(body, grid_spec=grid_spec,
                        compiler_params=_CAUSAL_COMPILER_PARAMS, **call),
         jnp.asarray(cells))
 
@@ -342,12 +431,14 @@ def default_interpret() -> bool:
     return jax.default_backend() == 'cpu'
 
 
-def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd'):
+def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd', N=None, rep=1):
     """(G, bq, bk): heads a grid step and MXU/VPU-aligned seq blocks, for
     BH = N*H batch·head slices. Sublane minimum is 8 (f32) / 16 (bf16);
-    lanes are 128. G amortises the per-invocation kernel overhead over
-    several batch·head slices; the kernels take them as G // hb batch
-    rows of one lane block of hb heads (:func:`_rows_per_step`).
+    lanes are 128. G amortises a grid step's fixed cost over several
+    batch·head slices; the kernels take them as G // hb batch rows of
+    one lane block of hb heads (:func:`_rows_per_step`) and, what the
+    rows cannot supply, as adjacent lane blocks
+    (:func:`_lane_blocks_per_step`).
 
     kind='bwd' sizes the backward kernels, whose per-cell stack holds
     ~6 live (bq, bk) f32 temporaries (s, p, dp, ds, keep, pv) vs the
@@ -360,6 +451,20 @@ def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd'):
     padding mask, dropout) and at GPT-2's causal T=1024 (BH=288).
     Wider backward blocks on this installation: not measured.
 
+    G follows from the shapes. Where the ``N`` rows of the batch fill
+    four heads a step (N * hb >= 4; ``N`` unknown: taken to), 4: measured
+    best on v5e at BERT-base shape, and the build those shapes have
+    always had. Where they cannot (one sequence a chip), the heads come
+    as lane blocks and 8 is asked for, which the VMEM estimate takes
+    down to 4 in the forward at D = 128 (chip runs, PR 40, one row of
+    16 heads of 128, T = 4096, ms a step of 32 calls at 1 / 2 / 4 / 8
+    heads a step: dq 47.0 / 33.8 / 26.6 / 23.1, dk/dv 67.3 / 47.4 /
+    36.5 / 31.8, the forward 44.4 / 40.7 / 38.9 at 1 / 2 / 4); with
+    ``rep`` grouped query heads to a key/value head the group, ``rep``,
+    so that its one key/value block is fetched once (28 query heads over
+    4, T = 8192, 4 calls, 1 / 7 heads a step: dq 39.1 / 16.7, the
+    forward 32.0 / 26.2; dk/dv took the group before).
+
     The defaults computed here are only the LAST rung of the ISSUE 18
     precedence ladder, applied by ops/autotune.resolve: explicit env
     override (registered MXTPU_FA_{G,BQ,BK} / MXTPU_FA_BWD_* knobs) >
@@ -371,14 +476,15 @@ def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd'):
     cap = 512 if kind == 'fwd' else 256
     bq = max(min_sub, min(cap, Tq))
     bk = max(min_sub, min(cap, Tk))
-    G = 1
-    for cand in (4, 8, 2):    # 4 measured best on v5e at BERT-base shape
-        if BH % cand == 0:
-            G = cand
-            break
+    if N is None or N * max(1, _LANES // D) >= 4:
+        wanted = (4, 8, 2)
+    else:
+        wanted = (rep,) if rep > 1 else (8, 4, 2)
+    G = next((g for g in wanted if BH % g == 0), 1)
     from . import autotune
     return autotune.resolve(autotune.KERNEL_FA, BH, Tq, Tk, D,
-                            jnp.dtype(dtype), kind, default=(G, bq, bk))
+                            jnp.dtype(dtype), kind, default=(G, bq, bk),
+                            rep=rep)
 
 
 # ---------------------------------------------------------------------------
@@ -425,7 +531,7 @@ def _counter_keep(seed, bh, rows, cols, rate):
 
 
 def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
-          k_len, dropout_p, h_all, window=None, rep=1):
+          k_len, dropout_p, h_all, window=None, lq=1):
     """What the heads of one grid step share, computed once a step:
     (own, is-first, is-last, scores, keep).
 
@@ -448,16 +554,15 @@ def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
     cells wholly above it are not in the causal grid (:func:`_cell_live`).
 
     ``keep(g, hh)``: the dropout multiplier of row g, head hh of the
-    step, shaped as the scores, or None without dropout. Its batch·head
+    step's ``lq`` lane blocks (hh = lane block * hb + head of the block),
+    shaped as the scores, or None without dropout. Its batch·head
     id is n * h_all + h from ``meta_ref[0, 1]``, the numbering the
     (N*H, T, D) layout had. A call that holds the whole (N, H) problem
     has ``h_all`` = H and ``meta_ref[0, 1]`` 0. A call mapped over a mesh
     (ops/attention.py) holds one shard: ``meta_ref[0, 1]`` is the global
     id of its first (row, head) and ``h_all`` the heads of the whole
     problem, its own being fewer when the heads are sharded too — so a
-    sharded run draws the same dropout bits as the unsharded one. Where a
-    lane block of the grid stands for ``rep`` query heads (the dk/dv
-    kernel of grouped-query heads), ``hh`` counts through all of them.
+    sharded run draws the same dropout bits as the unsharded one.
 
     ``window``: keep a score iff 0 <= i - j < window. The difference read
     as an unsigned number makes that one comparison, as the causal mask
@@ -494,7 +599,7 @@ def _cell(cells_ref, q_axis, meta_ref, Gn, W, D, bq, bk, scale, causal,
         jnp.uint32(kb * bk) + lax.broadcasted_iota(jnp.uint32, cell, k_dim))
     seed = meta_ref[0, 0]
     bh0 = meta_ref[0, 1] + (pl.program_id(0) * (Gn * h_all)
-                            + pl.program_id(1) * (len(own) * rep)
+                            + pl.program_id(1) * (len(own) * lq)
                             ).astype(jnp.uint32)
 
     def keep(g, hh):
@@ -536,23 +641,25 @@ def _as_columns(rows):
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
                    o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                   scale, causal, D, bq, bk, k_len, dropout_p, h_all,
+                   scale, causal, W, D, bq, bk, k_len, dropout_p, h_all,
                    window=None, cells_ref=None):
-    """One (row-group, lane-block, q-block, k-block) cell. Refs are VMEM
-    blocks: q (Gn, bq, W), k/v (Gn, bk, W), kmask (Gn, 1, bk) additive
-    f32, o (Gn, bq, W), lse (Gn, hb, 1, bq), hb = W // D heads side by
-    side in the W columns; meta (1, 2) uint32 in SMEM [dropout seed,
-    global batch*head base]; scratch acc (Gn, bq, W) f32, m/l
-    (Gn*hb, bq, 128) f32, every lane a row's value. A head's scores,
-    softmax, dropout and accumulation are what they were when it had a
-    block of its own. The last k-block writes m + log l as (1, bq) rows:
-    the step's heads side by side in the lanes of one (bq, 128) array,
-    transposed once."""
-    Gn, _, W = q_ref.shape
+    """One (row-group, step of lane blocks, q-block, k-block) cell. Refs
+    are VMEM blocks: q (Gn, bq, lq*W), k/v (Gn, bk, lk*W), kmask
+    (Gn, 1, bk) additive f32, o (Gn, bq, lq*W), lse (Gn, lq*hb, 1, bq),
+    lq lane blocks of hb = W // D heads side by side in the columns
+    (:func:`_step_lanes`); meta (1, 2) uint32 in SMEM [dropout seed,
+    global batch*head base]; scratch acc (Gn, bq, lq*W) f32, m/l
+    (Gn*lq*hb, bq, 128) f32, every lane a row's value. A lane block is a
+    static column slice of the refs; a head's scores, softmax, dropout
+    and accumulation are what they were when it had a block of its own.
+    The last k-block writes m + log l as (1, bq) rows: the step's heads
+    side by side in the lanes of one (bq, 128) array, transposed once."""
+    Gn, lq, lk = q_ref.shape[0], q_ref.shape[2] // W, k_ref.shape[2] // W
     own, first, last, scores, keep = _cell(
         cells_ref, 2, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
-        dropout_p, h_all, window)
+        dropout_p, h_all, window, lq)
     hb = len(own)
+    heads = lq * hb                               # of one row of the step
 
     @pl.when(first())
     def _init():
@@ -560,11 +667,14 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    for g in range(Gn):
-        q, k, v, kmask_row = q_ref[g], k_ref[g], v_ref[g], kmask_ref[g]
-        acc = acc_ref[g]
+    for g, j, qc, kc, shared, _ in _step_blocks(Gn, lq, lk, W):
+        q = q_ref[g, :, qc]
+        if shared:
+            k, v, kmask_row = k_ref[g, :, kc], v_ref[g, :, kc], kmask_ref[g]
+        acc = acc_ref[g, :, qc]
         for hh in range(hb):
-            slot = g * hb + hh
+            head = j * hb + hh
+            slot = g * heads + head
             s = scores(_own(q, own[hh]), k, kmask_row)
             m_prev = m_ref[slot, :, :1]                      # (bq, 1)
             l_prev = l_ref[slot, :, :1]
@@ -573,7 +683,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
             p = jnp.exp(s - m_new)                           # (bq, bk) f32
             alpha = jnp.exp(m_prev - m_new)                  # (bq, 1)
             l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            kept = keep(g, hh)
+            kept = keep(g, head)
             pv = p if kept is None else p * kept
             # p·v fills all W columns; this head's are kept
             new = acc * alpha + lax.dot_general(
@@ -582,15 +692,15 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
             acc = new if own[hh] is None else lax.select(own[hh], new, acc)
             m_ref[slot] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[slot] = jnp.broadcast_to(l_new, l_ref.shape[1:])
-        acc_ref[g] = acc
+        acc_ref[g, :, qc] = acc
 
     @pl.when(last())
     def _finalize():
         lane = lax.broadcasted_iota(jnp.int32, m_ref.shape[1:], 1)
-        for g in range(Gn):
-            acc = out = acc_ref[g]
+        for g, j, qc, *_ in _step_blocks(Gn, lq, lk, W):
+            acc = out = acc_ref[g, :, qc]
             for hh in range(hb):
-                slot = g * hb + hh
+                slot = g * heads + j * hb + hh
                 safe_l = jnp.maximum(l_ref[slot], 1e-30)      # (bq, 128)
                 out = acc / safe_l[:, :1] if own[hh] is None \
                     else lax.select(own[hh], acc / safe_l[:, :1], out)
@@ -599,18 +709,19 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
                 head = m_ref[slot] + jnp.log(safe_l)
                 lse = head if slot == 0 else jnp.where(lane == slot, head,
                                                        lse)
-            o_ref[g] = out.astype(o_ref.dtype)
+            o_ref[g, :, qc] = out.astype(o_ref.dtype)
         rows = lse.T                                          # (128, bq)
-        for slot in range(Gn * hb):
-            lse_ref[slot // hb, slot % hb] = rows[slot:slot + 1]
+        for slot in range(Gn * heads):
+            lse_ref[slot // heads, slot % heads] = rows[slot:slot + 1]
 
 
 def _addressing(arrays, H, kind, Hkv=None):
     """The static numbers of one build from its operands' shapes:
-    (N, Tq, Tk, C, D, W, hb, Gn, bq, bk). ``arrays`` is (q, k, v), each
-    (N, T, C = H*D), or (qkv,), their (N, T, 3C) fusion. With ``Hkv``
-    key/value heads to the H query heads, k and v are (N, T, Hkv*D) and a
-    head is a lane block."""
+    (N, Tq, Tk, C, D, W, hb, Gn, lb, bq, bk). ``arrays`` is (q, k, v),
+    each (N, T, C = H*D), or (qkv,), their (N, T, 3C) fusion. With
+    ``Hkv`` key/value heads to the H query heads, k and v are
+    (N, T, Hkv*D) and a head is a lane block. The G heads of a grid step
+    are Gn rows of ``lb`` lane blocks (:func:`_step_heads`)."""
     N, Tq = arrays[0].shape[:2]
     Tk = arrays[-1].shape[1]
     C = arrays[0].shape[2] // (3 if len(arrays) == 1 else 1)
@@ -628,15 +739,19 @@ def _addressing(arrays, H, kind, Hkv=None):
             f"of {D} columns: a group needs whole 128-lane heads, H a "
             f"multiple of Hkv and k, v of Hkv*D columns (flash_legal says "
             f"so)")
-    G, bq, bk = _block_sizes(N * H, Tq, Tk, D, arrays[0].dtype, kind)
-    return N, Tq, Tk, C, D, W, hb, _rows_per_step(N, G, hb), bq, bk
+    rep = H // (Hkv or H)
+    G, bq, bk = _block_sizes(N * H, Tq, Tk, D, arrays[0].dtype, kind,
+                             N=N, rep=rep)
+    Gn, lb = _step_heads(N, C, D, G, rep)
+    return N, Tq, Tk, C, D, W, hb, Gn, lb, bq, bk
 
 
 def _operands(arrays, C, W, pq, pk):
-    """((q, k, v), their offsets in lane blocks) as the kernels address
-    them: a fused (N, T, 3C) array three times, at 0, C/W and 2C/W, where
-    its lane blocks are whole 128s and no sequence needs padding to its
-    blocks (pq, pk rows); else three arrays, padded."""
+    """((q, k, v), their offsets in blocks of W columns, a grid step's
+    own) as the kernels address them: a fused (N, T, 3C) array three
+    times, at 0, C/W and 2C/W, where its lane blocks are whole 128s and
+    no sequence needs padding to its blocks (pq, pk rows); else three
+    arrays, padded."""
     if len(arrays) == 1:
         if W % _LANES == 0 and not pq and not pk:
             return arrays * 3, (0, C // W, 2 * C // W)
@@ -675,31 +790,33 @@ def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
     (N, H, 1, Tq), Tq on the lanes as the kernel wrote it), sliced back
     from the blocks' padding -- the backward re-pads them for its own
     (possibly different) tiling."""
-    N, Tq, Tk, C, D, W, hb, Gn, bq, bk = _addressing(arrays, H, 'fwd', Hkv)
+    N, Tq, Tk, C, D, W, hb, Gn, lb, bq, bk = _addressing(arrays, H, 'fwd',
+                                                          Hkv)
     rep = H // (Hkv or H)
+    lq, _ = _step_lanes('fwd', lb, rep)
     dtype = arrays[0].dtype
     nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
     pq, pk = nq * bq - Tq, nk * bk - Tk
-    (q, k, v), (qo, ko, vo) = _operands(arrays, C, W, pq, pk)
+    (q, k, v), (qo, ko, vo) = _operands(arrays, C, lb * W, pq, pk)
     _count_build('fwd', H, D, hb, (qo, ko, vo))
 
-    kernel = functools.partial(
-        _fa_fwd_kernel, scale=1.0 / math.sqrt(D), causal=causal, D=D, bq=bq,
-        bk=bk, k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
-        window=window)
-    seq, row, mask = _block_specs('fwd', Gn, hb, bq, bk, W, causal, rep)
+    kw = dict(scale=1.0 / math.sqrt(D), causal=causal, W=W, D=D, bq=bq,
+              bk=bk, k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
+              window=window)
+    seq, row, mask = _block_specs('fwd', Gn, hb, bq, bk, W, causal, lb, rep)
     cells = _causal_cell_table('fwd', nq, nk, bq, bk, by_row=True,
                                window=window) if causal else None
     out, lse = _call(
-        kernel, cells, (N // Gn, C // W) + (() if causal else (nq, nk)),
+        _fa_fwd_kernel, kw, cells,
+        (N // Gn, C // (lq * W)) + (() if causal else (nq, nk)),
         in_specs=[seq('q', qo), seq('k', ko), seq('k', vo), mask,
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[seq('q'), row],
         out_shape=[jax.ShapeDtypeStruct((N, nq * bq, C), dtype),
                    jax.ShapeDtypeStruct((N, H, 1, nq * bq), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((Gn, bq, W), jnp.float32),
-                        pltpu.VMEM((Gn * hb, bq, 128), jnp.float32),
-                        pltpu.VMEM((Gn * hb, bq, 128), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((Gn, bq, lq * W), jnp.float32),
+                        pltpu.VMEM((Gn * lq * hb, bq, 128), jnp.float32),
+                        pltpu.VMEM((Gn * lq * hb, bq, 128), jnp.float32)],
         interpret=interpret, name=_scopes.FLASH_FWD,
     )(q, k, v, _mask_operand(kmask, N, Tk, pk), meta)
     if pq:
@@ -714,50 +831,54 @@ def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
 
 def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                   lse_ref, delta_ref, dq_ref, dq_acc, stat_col, *,
-                  scale, causal, D, bq, bk, k_len, dropout_p, h_all,
+                  scale, causal, W, D, bq, bk, k_len, dropout_p, h_all,
                   window=None, cells_ref=None):
-    """dq for one q-block of one lane block, accumulated over k-blocks
-    (grid (N/Gn, C/W, nq, nk)), written in the operands' dtype. lse and
-    delta arrive as (Gn, hb, 1, bq) rows and are wanted down the
-    sublanes, beside the (bq, bk) scores: the first k-block of a q-block
-    lays them into ``stat_col`` (bq, 2*Gn*hb), a column a head and
-    statistic, with one transpose an outer block."""
-    Gn, _, W = q_ref.shape
+    """dq for one q-block of one step's lane blocks, accumulated over
+    k-blocks (grid (N/Gn, steps, nq, nk)), written in the operands'
+    dtype. lse and delta arrive as (Gn, lq*hb, 1, bq) rows and are wanted
+    down the sublanes, beside the (bq, bk) scores: the first k-block of a
+    q-block lays them into ``stat_col`` (bq, 2*Gn*lq*hb), a column a head
+    and statistic, with one transpose an outer block."""
+    Gn, lq, lk = q_ref.shape[0], q_ref.shape[2] // W, k_ref.shape[2] // W
     own, first, last, scores, keep = _cell(
         cells_ref, 2, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
-        dropout_p, h_all, window)
+        dropout_p, h_all, window, lq)
     hb = len(own)
-    heads = Gn * hb         # lse in columns [0, heads), delta in the next
+    heads = lq * hb                               # of one row of the step
+    all_heads = Gn * heads  # lse in columns [0, all_heads), delta the next
 
     @pl.when(first())
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
         stat_col[:] = _as_columns([ref[g, hh] for ref in (lse_ref, delta_ref)
-                                   for g in range(Gn) for hh in range(hb)])
+                                   for g in range(Gn) for hh in range(heads)])
 
-    for g in range(Gn):
-        q, k, kmask_row = q_ref[g], k_ref[g], kmask_ref[g]
-        k32 = k.astype(jnp.float32)                       # (bk, W)
-        v32 = v_ref[g].astype(jnp.float32)
-        do32 = do_ref[g].astype(jnp.float32)              # (bq, W)
-        dq = dq_acc[g]
+    for g, j, qc, kc, shared, _ in _step_blocks(Gn, lq, lk, W):
+        q = q_ref[g, :, qc]
+        if shared:
+            k, kmask_row = k_ref[g, :, kc], kmask_ref[g]
+            k32 = k.astype(jnp.float32)                   # (bk, W)
+            v32 = v_ref[g, :, kc].astype(jnp.float32)
+        do32 = do_ref[g, :, qc].astype(jnp.float32)       # (bq, W)
+        dq = dq_acc[g, :, qc]
         for hh in range(hb):
-            slot = g * hb + hh
+            head = j * hb + hh
+            slot = g * heads + head
             s = scores(_own(q, own[hh]), k, kmask_row)
             p = jnp.exp(s - stat_col[:, slot:slot + 1])   # (bq, bk)
             dp = lax.dot_general(
                 _own(do32, own[hh]), v32, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)       # (bq, bk)
-            kept = keep(g, hh)
+            kept = keep(g, head)
             if kept is not None:
                 dp = dp * kept
-            delta = stat_col[:, heads + slot:heads + slot + 1]
+            delta = stat_col[:, all_heads + slot:all_heads + slot + 1]
             ds = p * (dp - delta) * scale                 # (bq, bk)
             # ds·k fills all W columns; this head's are kept
             dq = dq + _own(lax.dot_general(
                 ds, k32, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32), own[hh])
-        dq_acc[g] = dq
+        dq_acc[g, :, qc] = dq
 
     @pl.when(last())
     def _finalize():
@@ -767,23 +888,23 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                    kmask_col, *,
-                   scale, causal, D, bq, bk, k_len, dropout_p, h_all,
-                   window=None, rep=1, cells_ref=None):
-    """dk/dv for one k-block of one lane block, accumulated over q-blocks
-    (grid (N/Gn, C/W, nk, nq): k-block is program 2, q-block program 3),
-    written in the operands' dtype. A cell is computed keys down the
-    sublanes, (bk, bq) (:func:`_cell`): lse and delta are the (1, bq)
-    rows they arrive as, and dv += p^T·dO, dk += ds^T·q are plain
+                   scale, causal, W, D, bq, bk, k_len, dropout_p, h_all,
+                   window=None, cells_ref=None):
+    """dk/dv for one k-block of one step's lane blocks, accumulated over
+    q-blocks (grid (N/Gn, steps, nk, nq): k-block is program 2, q-block
+    program 3), written in the operands' dtype. A cell is computed keys
+    down the sublanes, (bk, bq) (:func:`_cell`): lse and delta are the
+    (1, bq) rows they arrive as, and dv += p^T·dO, dk += ds^T·q are plain
     products. The additive key mask is wanted as a column here; the first
     q-block of a k-block lays it into ``kmask_col`` (bk, Gn), once an
-    outer block. With ``rep`` query heads to a key/value head the lane
-    blocks are the key/value heads', q, dO, lse and delta come ``rep``
-    heads wide, and the group's heads add into the one dk and dv here, a
-    head at a time."""
-    Gn, _, W = k_ref.shape
+    outer block. With grouped-query heads the steps are over the
+    key/value heads, q, dO, lse and delta come the whole group wide, and
+    the group's heads add into the one dk and dv here, a head at a
+    time."""
+    Gn, lq, lk = k_ref.shape[0], q_ref.shape[2] // W, k_ref.shape[2] // W
     own, first, last, scores, keep = _cell(
         cells_ref, 3, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
-        dropout_p, h_all, window, rep)
+        dropout_p, h_all, window, lq)
     hb = len(own)
 
     @pl.when(first())
@@ -792,37 +913,37 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
         kmask_col[:] = _as_columns([kmask_ref[g] for g in range(Gn)])
 
-    for g in range(Gn):
-        k, kmask = k_ref[g], kmask_col[:, g:g + 1]
-        v32 = v_ref[g].astype(jnp.float32)                # (bk, W)
-        dk, dv = dk_acc[g], dv_acc[g]
-        for r in range(rep):
-            cols = slice(None) if rep == 1 else slice(r * W, (r + 1) * W)
-            q = q_ref[g, :, cols]
-            do32 = do_ref[g, :, cols].astype(jnp.float32)     # (bq, W)
-            for hh in range(hb):
-                head = r * hb + hh
-                # q and dO with the other heads' columns zeroed: what they
-                # are contracted into lands in this head's columns alone
-                q_own, do_own = _own(q, own[hh]), _own(do32, own[hh])
-                s = scores(q_own, k, kmask)
-                p = jnp.exp(s - lse_ref[g, head])             # (bk, bq)
-                kept = keep(g, head)
-                pv = p if kept is None else p * kept
-                # dv_j += sum_i P_drop_ij dO_i
-                dv = dv + lax.dot_general(
-                    pv, do_own, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)       # (bk, W)
-                dp = lax.dot_general(
-                    v32, do_own, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)       # (bk, bq)
-                if kept is not None:
-                    dp = dp * kept
-                ds = p * (dp - delta_ref[g, head]) * scale    # (bk, bq)
-                dk = dk + lax.dot_general(
-                    ds, q_own.astype(jnp.float32), (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)       # (bk, W)
-        dk_acc[g], dv_acc[g] = dk, dv
+    for g, j, qc, kc, shared, done in _step_blocks(Gn, lq, lk, W):
+        if shared:
+            k, kmask = k_ref[g, :, kc], kmask_col[:, g:g + 1]
+            v32 = v_ref[g, :, kc].astype(jnp.float32)     # (bk, W)
+            dk, dv = dk_acc[g, :, kc], dv_acc[g, :, kc]
+        q = q_ref[g, :, qc]
+        do32 = do_ref[g, :, qc].astype(jnp.float32)       # (bq, W)
+        for hh in range(hb):
+            head = j * hb + hh
+            # q and dO with the other heads' columns zeroed: what they
+            # are contracted into lands in this head's columns alone
+            q_own, do_own = _own(q, own[hh]), _own(do32, own[hh])
+            s = scores(q_own, k, kmask)
+            p = jnp.exp(s - lse_ref[g, head])             # (bk, bq)
+            kept = keep(g, head)
+            pv = p if kept is None else p * kept
+            # dv_j += sum_i P_drop_ij dO_i
+            dv = dv + lax.dot_general(
+                pv, do_own, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (bk, W)
+            dp = lax.dot_general(
+                v32, do_own, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (bk, bq)
+            if kept is not None:
+                dp = dp * kept
+            ds = p * (dp - delta_ref[g, head]) * scale    # (bk, bq)
+            dk = dk + lax.dot_general(
+                ds, q_own.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (bk, W)
+        if done:
+            dk_acc[g, :, kc], dv_acc[g, :, kc] = dk, dv
 
     @pl.when(last())
     def _finalize():
@@ -835,12 +956,13 @@ def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
     """Pallas backward: recompute probability blocks from the saved LSE.
     Returns the cotangents of ``arrays``, in their dtype: (dq, dk, dv),
     or (dqkv,), the three side by side."""
-    N, Tq, Tk, C, D, W, hb, Gn, bq, bk = _addressing(arrays, H, 'bwd', Hkv)
+    N, Tq, Tk, C, D, W, hb, Gn, lb, bq, bk = _addressing(arrays, H, 'bwd',
+                                                          Hkv)
     rep = H // (Hkv or H)
     dtype = arrays[0].dtype
     nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
     pq, pk = nq * bq - Tq, nk * bk - Tk
-    (q, k, v), (qo, ko, vo) = _operands(arrays, C, W, pq, pk)
+    (q, k, v), (qo, ko, vo) = _operands(arrays, C, lb * W, pq, pk)
     if pq:
         # padded q rows contribute nothing: their dO is zero, so dv += p·0
         # and ds = p·(0 - 0) vanish; lse pads as 0 harmlessly
@@ -859,8 +981,8 @@ def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
         'ntc,ch->nht', do.astype(jnp.float32) * out.astype(jnp.float32),
         head_of, precision=lax.Precision.HIGHEST).reshape(lse.shape)
 
-    kw = dict(scale=1.0 / math.sqrt(D), causal=causal, D=D, bq=bq, bk=bk,
-              k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
+    kw = dict(scale=1.0 / math.sqrt(D), causal=causal, W=W, D=D, bq=bq,
+              bk=bk, k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
               window=window)
     operands = (q, k, v, _mask_operand(kmask, N, Tk, pk), meta, do, lse,
                 delta)
@@ -869,29 +991,30 @@ def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
             ('bwd_dq', _fa_dq_kernel, (nq, nk), 'q', 1),
             ('bwd_dkv', _fa_dkv_kernel, (nk, nq), 'k', 2)):
         _count_build(kind, H, D, hb, (qo, ko, vo))
-        # with grouped heads the dk/dv grid runs over the key/value heads,
-        # each step a whole group of query heads wide
-        wide = rep > 1 and side == 'k'
-        group = {'rep': rep} if wide else {}
-        seq, row, mask = _block_specs(kind, Gn, hb, bq, bk, W, causal, rep)
+        # the grid's steps are over the columns dq, or dk and dv, have:
+        # with grouped heads dk/dv's run over the key/value heads, each
+        # a whole group of query heads wide
+        lq, lk = _step_lanes(kind, lb, rep)
+        cols, lanes = (C, lq) if side == 'q' else (C // rep, lk)
+        seq, row, mask = _block_specs(kind, Gn, hb, bq, bk, W, causal, lb,
+                                      rep)
         rows, blk = (nq * bq, bq) if side == 'q' else (nk * bk, bk)
-        cols = C // rep if wide else C
         cells = _causal_cell_table(kind, nq, nk, bq, bk, by_row=side == 'q',
                                    window=window) if causal else None
         # what an outer block turns into columns once: the statistics in
         # dq, the key mask in dk/dv
-        columns = pltpu.VMEM((bq, 2 * Gn * hb) if side == 'q' else (bk, Gn),
-                             jnp.float32)
+        columns = pltpu.VMEM(
+            (bq, 2 * Gn * lq * hb) if side == 'q' else (bk, Gn), jnp.float32)
         calls.append(_call(
-            functools.partial(kernel, **kw, **group), cells,
-            (N // Gn, cols // W) + (() if causal else plane),
+            kernel, kw, cells,
+            (N // Gn, cols // (lanes * W)) + (() if causal else plane),
             in_specs=[seq('q', qo), seq('k', ko), seq('k', vo), mask,
                       pl.BlockSpec(memory_space=pltpu.SMEM), seq('q'),
                       row, row],
             out_specs=[seq(side)] * n_out,
-            out_shape=[jax.ShapeDtypeStruct(
-                (N, rows, C if side == 'q' else C // rep), dtype)] * n_out,
-            scratch_shapes=[pltpu.VMEM((Gn, blk, W), jnp.float32)] * n_out
+            out_shape=[jax.ShapeDtypeStruct((N, rows, cols), dtype)] * n_out,
+            scratch_shapes=[pltpu.VMEM((Gn, blk, lanes * W),
+                                       jnp.float32)] * n_out
             + [columns],
             interpret=interpret,
             name=_scopes.FLASH_BWD_DQ if side == 'q'
@@ -950,10 +1073,11 @@ def flash_legal(BH, Tq, Tk, D, dtype, num_heads=1, num_kv_heads=None) -> bool:
     if num_kv_heads not in (None, num_heads) and (
             D % _LANES or num_heads % num_kv_heads):
         return False
+    N, rep = BH // num_heads, num_heads // (num_kv_heads or num_heads)
     return _lane_block(num_heads * D, D) is not None and all(
         autotune.check_candidate(
             BH, Tq, Tk, D, jnp.dtype(dtype), kind,
-            *_block_sizes(BH, Tq, Tk, D, dtype, kind))[0]
+            *_block_sizes(BH, Tq, Tk, D, dtype, kind, N, rep), N, rep)[0]
         for kind in ('fwd', 'bwd'))
 
 

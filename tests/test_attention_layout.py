@@ -120,12 +120,114 @@ def test_the_lane_block_follows_from_the_head_width(C, D, want):
 
 def test_heads_a_step_are_rows_of_a_lane_block():
     """G of ``_block_sizes`` stays heads a grid step: G // hb batch rows,
-    at least one, clamped to a divisor of N."""
+    at least one, clamped to a divisor of N; and what the rows cannot
+    supply, adjacent lane blocks, clamped to a divisor of the array's (of
+    one group's, where heads are grouped). Rows first: a batch that fills
+    G by rows keeps one lane block a step."""
     assert pa._block_sizes(672, 512, 512, 64, jnp.bfloat16)[0] == 4
     assert pa._rows_per_step(56, 4, 2) == 2
     assert pa._rows_per_step(56, 4, 1) == 4
     assert pa._rows_per_step(3, 4, 2) == 1
     assert pa._rows_per_step(8, 1, 2) == 1
+    # (G, Gn, hb, lane blocks to divide) -> lane blocks a step
+    assert pa._lane_blocks_per_step(4, 2, 2, 6) == 1      # the BERT cells
+    assert pa._lane_blocks_per_step(4, 4, 1, 16) == 1
+    assert pa._lane_blocks_per_step(4, 1, 1, 16) == 4     # one row, D = 128
+    assert pa._lane_blocks_per_step(8, 1, 1, 16) == 8
+    assert pa._lane_blocks_per_step(4, 2, 1, 16) == 2     # two rows
+    assert pa._lane_blocks_per_step(4, 1, 2, 4) == 2      # N = 3, D = 64
+    assert pa._lane_blocks_per_step(4, 1, 2, 6) == 2
+    assert pa._lane_blocks_per_step(8, 1, 2, 6) == 3      # 4 does not divide
+    assert pa._lane_blocks_per_step(1, 1, 2, 6) == 1
+    assert pa._lane_blocks_per_step(4, 1, 1, 7) == 1      # a group of seven
+    assert pa._lane_blocks_per_step(7, 1, 1, 7) == 7
+    assert pa._lane_blocks_per_step(64, 1, 1, 16) == 16
+    # (N, C, D, G, rep) -> (rows, lane blocks) of a step: the six cells'
+    assert pa._step_heads(56, 768, 64, 4) == pa._step_heads(24, 768, 64, 4) \
+        == pa._step_heads(224, 768, 64, 4) == (2, 1)
+    assert pa._step_heads(1, 2048, 128, 8) == (1, 8)
+    assert pa._step_heads(1, 3584, 128, 7, rep=7) == (1, 7)
+    assert pa._step_heads(2, 2048, 128, 4) == (2, 2)      # the check's two
+    # (kind, lb, rep) -> lane blocks on the query and the key/value side
+    assert pa._step_lanes('fwd', 4, 1) == pa._step_lanes('bwd_dkv', 4, 1) \
+        == (4, 4)
+    assert pa._step_lanes('bwd_dq', 7, 7) == (7, 1)
+    assert pa._step_lanes('fwd', 1, 7) == (1, 1)
+    assert pa._step_lanes('bwd_dkv', 1, 7) == (7, 1)
+
+
+# heads a step by lane blocks: the same bits ----------------------------------
+
+# N, T, H, Hkv, D, causal, window, dropout, key mask, fused; T is two
+# backward blocks (32) and one forward block (64) long
+HEADS_A_STEP = {
+    'plain': (1, 64, 8, None, 128, False, None, 0.0, False, False),
+    'causal': (1, 64, 8, None, 128, True, None, 0.0, False, False),
+    # rep 2: G = 1 is one head a step, 2 and more the group (lb 1 and 2)
+    'window_grouped': (1, 64, 8, 4, 128, True, 24, 0.0, False, False),
+    'dropout_masked': (1, 64, 8, None, 128, False, None, 0.1, True, False),
+    'fused': (1, 64, 8, None, 128, True, None, 0.1, False, True),
+    # a lane block of two heads: G = 1 is one block a step, 4 two blocks,
+    # 8 three rows of one
+    'three_rows_d64': (3, 64, 8, None, 64, True, None, 0.1, True, False),
+}
+_one_head_a_step = {}
+
+
+def _forward_and_backward(case, G):
+    """(out, lse, dq, dk, dv) of the kernels at ``G`` heads a step, and
+    the statistics blocks they were built with."""
+    N, T, H, Hkv, D, causal, window, rate, masked, fused = HEADS_A_STEP[case]
+    rng = onp.random.default_rng(6)
+    q, k, v = (jnp.asarray(rng.standard_normal((N, T, h * D)), jnp.float32)
+               for h in (H, Hkv or H, Hkv or H))
+    do = jnp.asarray(rng.standard_normal((N, T, H * D)), jnp.float32)
+    arrays = (jnp.concatenate([q, k, v], -1),) if fused else (q, k, v)
+    km = _padding(N, T)[1] if masked else None
+    meta = jnp.asarray([[77, 0]], jnp.uint32)
+    before = dict(pa.row_stat_blocks)
+    with contextlib.ExitStack() as stack:
+        for kind, b in (('fwd', 64), ('bwd', 32)):
+            stack.enter_context(
+                autotune.forced(autotune.KERNEL_FA, kind, (G, b, b)))
+        out, lse = pa._fa_forward(arrays, km, meta, H, causal, rate, True, H,
+                                  Hkv, window)
+        grads = pa._fa_backward(arrays, km, meta, H, causal, rate, True, H,
+                                Hkv, window, out, lse, do)
+    blocks = {key: block for key, block in pa.row_stat_blocks
+              if pa.row_stat_blocks[key, block] != before.get((key, block), 0)}
+    return [onp.asarray(x) for x in (out, lse) + tuple(grads)], blocks
+
+
+@pytest.mark.parametrize('G', [2, 4, 8])
+@pytest.mark.parametrize('case', sorted(HEADS_A_STEP))
+def test_heads_a_step_by_lane_blocks_are_the_same_bits(case, G):
+    """One row a chip (and three rows of two-head lane blocks): out, lse,
+    dq, dk and dv with a grid step forced to one head against 2, 4 and
+    all 8 heads a step are equal bit for bit -- plain, causal, window +
+    grouped heads, dropout with a key mask (the bits are the old
+    numbering's), the fused projection addressed in place -- and the
+    statistics blocks say how many heads a step held."""
+    N, T, H, Hkv, D = HEADS_A_STEP[case][:5]
+    if case not in _one_head_a_step:
+        _one_head_a_step[case] = _forward_and_backward(case, 1)
+    want, narrow = _one_head_a_step[case]
+    got, wide = _forward_and_backward(case, G)
+    assert len(got) == (3 if HEADS_A_STEP[case][-1] else 5)
+    for name, a, b in zip(('out', 'lse', 'dq', 'dk', 'dv'), got, want):
+        assert onp.array_equal(a, b), name
+    hb = 128 // D
+    rep = H // (Hkv or H)
+    assert narrow == {'fwd': (1, hb, 1, 64), 'bwd_dq': (1, hb, 1, 32),
+                      'bwd_dkv': (1, hb * rep, 1, 32)}
+    if N == 1:
+        heads = min(G, rep) if rep > 1 else G
+        assert wide == {'fwd': (1, heads, 1, 64), 'bwd_dq': (1, heads, 1, 32),
+                        'bwd_dkv': (1, max(heads, rep), 1, 32)}
+    else:
+        rows, lanes = {2: (1, 1), 4: (1, 2), 8: (3, 1)}[G]
+        assert wide == {kind: (rows, lanes * hb, 1, b) for kind, b in (
+            ('fwd', 64), ('bwd_dq', 32), ('bwd_dkv', 32))}
 
 
 # the key mask is an operand, indexed by batch row --------------------------

@@ -115,6 +115,54 @@ def test_g_is_heads_a_step_in_lane_blocks():
                                     4, 256, 256) == (True, None)
 
 
+@pytest.mark.parametrize('shape', [
+    (1, 16, None, 128, 4096), (1, 28, 4, 128, 8192), (2, 16, None, 128, 512),
+    (3, 8, None, 64, 512), (56, 12, None, 64, 512)],
+    ids=['ouro', 'smallthinker', 'two_rows', 'three_rows_d64', 'bert'])
+@pytest.mark.parametrize('kind', ['fwd', 'bwd_dq', 'bwd_dkv'])
+@pytest.mark.parametrize('G', [1, 2, 4, 7, 8])
+def test_layouts_are_the_blocks_the_kernels_build(shape, kind, G):
+    """With N (and the group) known ``fa_block_layouts`` lists the blocks
+    ``_block_specs`` builds, G heads a step as rows first and then lane
+    blocks, and ``vmem_bytes`` counts the heads on either side of them:
+    so ``resolve``'s clamp and ``check_candidate`` judge the real build."""
+    from mxnet_tpu.ops import pallas_attention as pa
+    N, H, Hkv, D, T = shape
+    rep = H // (Hkv or H)
+    cap = 512 if kind == 'fwd' else 256
+    W, hb = pa._lane_block(H * D, D)
+    Gn, lb = pa._step_heads(N, H * D, D, G, rep)
+    seq, row, mask = pa._block_specs(kind, Gn, hb, cap, cap, W, True, lb, rep)
+    layouts = {name: (array, block) for name, array, block in
+               autotune.fa_block_layouts(N * H, T, T, D,
+                                         'fwd' if kind == 'fwd' else 'bwd',
+                                         G, cap, cap, N=N, rep=rep)}
+    if kind == 'bwd_dkv' and rep > 1:
+        # the listed blocks are dq's; dk/dv's query side is the group
+        assert seq('q').block_shape == (Gn, cap, rep * W)
+        assert row.block_shape == (Gn, rep, 1, cap)
+    else:
+        assert layouts['q'] == ((N, T, H * D), seq('q').block_shape)
+        assert layouts['lse'] == ((N, H, 1, T), row.block_shape)
+    assert layouts['k'] == layouts['v'] == ((N, T, H * D // rep),
+                                            seq('k').block_shape)
+    assert layouts['kmask'] == ((N, 1, T), mask.block_shape)
+    heads = Gn * layouts['lse'][1][1]
+    kv = Gn * layouts['k'][1][2] // D
+    assert heads == Gn * lb * hb and kv == max(1, heads // rep)
+    # the estimate of that step: operands at their size, the
+    # accumulators and statistics in float32, the cell's temporaries
+    bf16 = jnp.dtype(jnp.bfloat16)
+    assert autotune.vmem_bytes(heads, cap, cap, D, kind[:3], 2, kv) == (
+        2 * (heads + 2 * kv) * cap * D * 2
+        + (heads + kv) * cap * (D + 256) * 4
+        + (3 if kind == 'fwd' else 6) * cap * cap * 4)
+    ok, why = autotune.check_candidate(
+        N * H, T, T, D, bf16, kind[:3], heads, cap, cap, N=N, rep=rep)
+    assert ok == (autotune.vmem_bytes(heads, cap, cap, D, kind[:3], 2, kv)
+                  <= autotune.VMEM_BUDGET), why
+
+
 # ---------------------------------------------------------------------------
 # tuning DB: round trip, corruption, precedence
 # ---------------------------------------------------------------------------

@@ -6,8 +6,9 @@ tests/test_operator.py; that a skipped cell contributed nothing (the same
 bits with the skip patched out); the counts of live cells a build records;
 that a non-causal build is, equation for equation, the kernel it is known
 as; and that Mosaic takes the kernels at the benchmark cells' shapes, the
-fused projection addressed in place, grouped and windowed heads and the
-expert layer's grouped matmuls among them, compiled here for a described
+fused projection addressed in place, grouped and windowed heads, a step of
+several lane blocks and the expert layer's grouped matmuls among them,
+compiled here for a described
 v5e:2x2 with no chip (every such compile of the suite is in this file:
 only one test file of a run may load the TPU's library).
 """
@@ -345,6 +346,31 @@ def test_mosaic_compiles_grouped_and_windowed_heads_for_a_described_v5e(
     for name in ('mxtpu_flash_fwd', 'mxtpu_flash_bwd_dq',
                  'mxtpu_flash_bwd_dkv'):
         assert name in text
+
+
+def test_mosaic_compiles_a_step_of_lane_blocks_for_a_described_v5e(one_chip):
+    """ouro_2_6b.t4096's layer: one 4096-token sequence, 16 heads of 128,
+    bf16, causal, default blocks. With one row a chip a grid step's heads
+    are adjacent lane blocks (``_lane_blocks_per_step``): forward, dq and
+    dk/dv at the widths that ship compile, so a block Mosaic refuses or
+    one that overruns scoped VMEM fails here, at no chip time."""
+    x = jax.ShapeDtypeStruct((1, 4096, 16 * 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        out = pa.flash_mha((q, k, v), 16, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+    before = dict(pa.row_stat_blocks)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ('mxtpu_flash_fwd', 'mxtpu_flash_bwd_dq',
+                 'mxtpu_flash_bwd_dkv'):
+        assert name in text
+    built = {key for key, n in pa.row_stat_blocks.items()
+             if n != before.get(key, 0)}
+    assert len(built) == 3 and all(block[0] == 1 and block[1] > 1
+                                   for _, block in built), built
 
 
 def test_mosaic_compiles_the_grouped_matmuls_for_a_described_v5e(one_chip):

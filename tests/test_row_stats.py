@@ -115,11 +115,13 @@ def _new(counter, before):
 
 # the layout ------------------------------------------------------------------
 
-# the attention layers of the benchmark's five cells at the shapes they
+# the attention layers of the benchmark's six cells at the shapes they
 # train at (chipbench/traffic/*.json batches, the configurations' published
 # widths), and one whose sequence is under a lane tile: N, T, H, Hkv, D,
 # causal, window, dropout, then the statistics blocks of fwd, bwd_dq and
-# bwd_dkv as PERF.md section 3 lists them
+# bwd_dkv as PERF.md section 3 lists them. The four batch cells fill a
+# step's four heads by rows (two rows x the two heads of a lane block);
+# the two cells of one sequence a chip by lane blocks
 LAYERS = {
     'bert_base.t512': (56, 512, 12, None, 64, False, None, 0.1,
                        ((2, 2, 1, 512), (2, 2, 1, 256), (2, 2, 1, 256))),
@@ -130,12 +132,18 @@ LAYERS = {
     # four chips of 56 rows each: a shard's builds are bert_base.t512's
     'bert_base.dp4_t512': (224, 512, 12, None, 64, False, None, 0.1,
                            ((2, 2, 1, 512), (2, 2, 1, 256), (2, 2, 1, 256))),
+    # one row: a group of seven query heads a step, in every kernel
     'smallthinker_21b.t8192-window': (
         1, 8192, 28, 4, 128, True, 4096, 0.0,
-        ((1, 1, 1, 512), (1, 1, 1, 256), (1, 7, 1, 256))),
+        ((1, 7, 1, 512), (1, 7, 1, 256), (1, 7, 1, 256))),
     'smallthinker_21b.t8192-full': (
         1, 8192, 28, 4, 128, True, None, 0.0,
-        ((1, 1, 1, 512), (1, 1, 1, 256), (1, 7, 1, 256))),
+        ((1, 7, 1, 512), (1, 7, 1, 256), (1, 7, 1, 256))),
+    # one row of 16 heads of 128 (q, k and v three arrays): eight lane
+    # blocks a step in the backward, four in the forward, where the VMEM
+    # estimate stops eight
+    'ouro_2_6b.t4096': (1, 4096, 16, 16, 128, True, None, 0.0,
+                        ((1, 4, 1, 512), (1, 8, 1, 256), (1, 8, 1, 256))),
     # bq is the whole sequence where that is under 128 lanes
     'short_sequence': (4, 64, 4, None, 64, True, None, 0.1,
                        ((2, 2, 1, 64), (2, 2, 1, 64), (2, 2, 1, 64))),
