@@ -1,19 +1,27 @@
 """Decoder-only language model from one block configured by its norm
-placement, its positions, its head counts and its feed-forward kind
-(ROADMAP D8), run once or several times over: the blocks of today's open
-sparse-expert and looped decoders.
+placement, its positions, its head counts, its mixer and its feed-forward
+kind (ROADMAP D8), run once or several times over: the blocks of today's
+open sparse-expert, hybrid and looped decoders.
 
-A block is RMS norm, grouped-query causal attention (rotary positions or
-none, a sliding window or the whole prefix), residual, RMS norm, a
-feed-forward, residual. The feed-forward is either a sparse ReGLU expert
-layer (``experts=``: the router reads the block's normalised input,
-before attention; the layer is one share of an expert-parallel group,
-ops/moe.py: it is told which experts it holds and computes their part)
-or a dense gated one (``ffn=``: act(x Wg) * (x Wu), then Wd).
+A block is RMS norm, a sequence mixer, residual, RMS norm, a
+feed-forward, residual. The mixer is grouped-query causal attention
+(rotary positions or none, a sliding window or the whole prefix, and
+optionally a per-head RMS norm of q and k before the positions), or a
+gated short convolution (``mixer='conv'``: in-projection to [B | C | u],
+``C * causal_dwconv(B * u)``, out-projection; ops/shortconv.py). The
+feed-forward is either a sparse gated expert layer (``experts=``: the
+layer is one share of an expert-parallel group, ops/moe.py: it is told
+which experts it holds and computes their part; the router reads the
+block's normalised input before the mixer, ``router='pre_mixer'``, or the
+feed-forward's after it, ``'pre_ffn'``; softmax or sigmoid scoring,
+the latter with a balancing bias the forward moves under training) or a
+dense gated one (``ffn=``: act(x Wg) * (x Wu), then Wd).
 ``post_norms`` adds an RMS norm on each sub-layer's output before it
-joins the residual (the sandwich form). Which layers are windowed and
-which carry rotary positions is a per-layer pattern. The output head is a
-matrix of its own (untied), over the rows of the vocabulary held here.
+joins the residual (the sandwich form). Which layers are windowed, which
+carry rotary positions, which mix by convolution and which feed forward
+densely is a per-layer pattern. The output head is a matrix of its own,
+or the embedding's rows read again (``tie_head``), over the rows of the
+vocabulary held here.
 
 A model runs its stack once, or ``passes`` times on one set of weights
 (a looped decoder: each pass reads its predecessor's normalised output),
@@ -40,6 +48,7 @@ from .. import scopes as _scopes
 from ..ops import attention as attn_ops
 from ..ops import moe as moe_ops
 from ..ops import nn as nn_ops
+from ..ops import shortconv as shortconv_ops
 from ..ndarray.ndarray import NDArray, _invoke
 from ..base import state as _state
 from .. import random as _random
@@ -64,13 +73,24 @@ class SparseExperts(HybridBlock):
     """The router over all ``experts`` of the model and the ``held``
     experts of this share, ``first_expert`` on: gate and up projections
     side by side as (held, hidden, 2 * width), down as (held, width,
-    hidden), ReGLU between them, ``top_k`` experts a token."""
+    hidden), a ReGLU or SiLU ``gate`` between them, ``top_k`` experts a
+    token chosen by ``scoring`` (ops/moe.py:route). ``bias_rate``: the
+    sigmoid router's balancing bias, a float32 (experts,) parameter of
+    zeros with no gradient that enters the choice alone; a forward under
+    training moves it by ``bias_rate`` against each expert's load
+    (moe_ops.next_bias) and writes it back, as BatchNorm writes its
+    running statistics."""
 
     def __init__(self, hidden, width, experts, top_k, held=None,
-                 first_expert=0, **kwargs):
+                 first_expert=0, scoring='softmax', gate='reglu',
+                 bias_rate=None, **kwargs):
         super().__init__(**kwargs)
         held = experts if held is None else held
+        if bias_rate is not None and scoring != 'sigmoid':
+            raise ValueError("a balancing bias is the sigmoid router's")
         self._top_k, self._first = top_k, first_expert
+        self._scoring, self._gate, self._rate = scoring, gate, bias_rate
+        self.expert_bias = None
         with self.name_scope():
             self.router_weight = self.params.get(
                 'router_weight', shape=(experts, hidden))
@@ -78,6 +98,17 @@ class SparseExperts(HybridBlock):
                 'gate_up_weight', shape=(held, hidden, 2 * width))
             self.down_weight = self.params.get(
                 'down_weight', shape=(held, width, hidden))
+            if bias_rate is not None:
+                self.expert_bias = self.params.get(
+                    'expert_bias', shape=(experts,), init='zeros',
+                    dtype='float32', grad_req='null', differentiable=False)
+
+    def cast(self, dtype):
+        """The bias stays float32: a step moves it by ``bias_rate``, which
+        bf16 rounds away once the bias is a few hundred times that."""
+        super().cast(dtype)
+        if self.expert_bias is not None:
+            self.expert_bias.cast('float32')
 
     def route(self, x):
         """The router's logits of ``x``, (N, T, experts), float32."""
@@ -86,9 +117,32 @@ class SparseExperts(HybridBlock):
                            self.router_weight.data())
 
     def forward(self, x, router_logits):
-        return _invoke(moe_ops.expert_layer, x, router_logits,
-                       self.gate_up_weight.data(), self.down_weight.data(),
-                       first_expert=self._first, top_k=self._top_k)
+        args = (x, router_logits, self.gate_up_weight.data(),
+                self.down_weight.data())
+        kw = dict(first_expert=self._first, top_k=self._top_k,
+                  scoring=self._scoring, gate=self._gate)
+        if self.expert_bias is None:
+            return _invoke(moe_ops.expert_layer, *args, **kw)
+        bias = self.expert_bias.data()
+        if not _state.is_training:
+            return _invoke(moe_ops.expert_layer, *args, bias=bias, **kw)
+        out, new_bias = _invoke(_experts_moving_bias, *args, bias,
+                                rate=self._rate, **kw)
+        bias._data = new_bias._data     # threaded out as the step's aux
+        return out
+
+
+def _experts_moving_bias(x, router_logits, w_gate_up, w_down, bias, rate,
+                         **kw):
+    """(the expert layer's output, the bias after this step): the load is
+    every expert's assignments of these tokens (moe_ops.assignment_counts);
+    in a deployment the counts of the chips that share the layer would be
+    summed first, and on one chip nothing stands in for that sum."""
+    out, ids = moe_ops.expert_layer(x, router_logits, w_gate_up, w_down,
+                                    bias=bias, with_ids=True, **kw)
+    with jax.named_scope(_scopes.MOE_ROUTE):
+        counts = moe_ops.assignment_counts(ids, router_logits.shape[-1])
+        return out, moe_ops.next_bias(bias, counts, rate)
 
 
 _GATES = {'silu': jax.nn.silu, 'relu': jax.nn.relu, 'gelu': jax.nn.gelu}
@@ -122,32 +176,81 @@ class GatedFFN(HybridBlock):
                                      activation=self._activation))
 
 
+class ShortConv(HybridBlock):
+    """The gated short convolution mixer: in-projection to [B | C | u]
+    (3 * hidden), ``C * causal_dwconv(B * u; w)`` with ``taps`` taps a
+    channel (ops/shortconv.py), out-projection; no bias. Its ops lie
+    under ``short_conv`` (scopes.SHORT_CONV)."""
+
+    def __init__(self, hidden, taps=3, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.in_proj = nn.Dense(3 * hidden, use_bias=False, flatten=False,
+                                    in_units=hidden, prefix='in_proj_')
+            self.conv_weight = self.params.get('conv_weight',
+                                               shape=(taps, hidden))
+            self.out_proj = nn.Dense(hidden, use_bias=False, flatten=False,
+                                     in_units=hidden, prefix='out_proj_')
+
+    def forward(self, x):
+        with jax.named_scope(_scopes.SHORT_CONV):
+            return self.out_proj(_invoke(shortconv_ops.gated_short_conv,
+                                         self.in_proj(x),
+                                         self.conv_weight.data()))
+
+
+def _head_rms_norm(x, gamma, heads, eps):
+    """``nn_ops.rms_norm`` over each head's columns of (N, T, heads * D)."""
+    N, T, C = x.shape
+    return nn_ops.rms_norm(x.reshape(N, T, heads, C // heads), gamma,
+                           eps=eps).reshape(N, T, C)
+
+
 class DecoderBlock(HybridBlock):
-    """One block. ``window`` None: attention over the whole prefix;
-    ``rope_theta`` None: no positional encoding. The feed-forward is
-    ``experts`` (SparseExperts' keyword arguments) or ``ffn`` (GatedFFN's:
-    width, activation), one of the two. ``post_norms``: an RMS norm of
-    its own on the attention's and on the feed-forward's output, before
-    the residual add."""
+    """One block. ``mixer`` 'attention' or 'conv' (:class:`ShortConv` of
+    ``conv_taps``); for attention ``window`` None: over the whole prefix;
+    ``rope_theta`` None: no positional encoding; ``qk_norm``: a per-head
+    RMS norm of q and k (a gain of ``head_dim``), before the positions.
+    The feed-forward is ``experts`` (SparseExperts' keyword arguments) or
+    ``ffn`` (GatedFFN's: width, activation), one of the two; the router
+    reads the normalised input of the mixer (``router='pre_mixer'``) or
+    of the feed-forward (``'pre_ffn'``). ``post_norms``: an RMS norm of
+    its own on the mixer's and on the feed-forward's output, before the
+    residual add."""
 
     def __init__(self, hidden, heads, kv_heads, head_dim, experts=None,
                  window=None, rope_theta=None, epsilon=1e-6, ffn=None,
-                 post_norms=False, **kwargs):
+                 post_norms=False, mixer='attention', conv_taps=3,
+                 qk_norm=False, router='pre_mixer', **kwargs):
         super().__init__(**kwargs)
         if (experts is None) == (ffn is None):
             raise ValueError("a block has one feed-forward: experts= or ffn=")
+        if mixer not in ('attention', 'conv') \
+                or router not in ('pre_mixer', 'pre_ffn'):
+            raise ValueError(f"mixer {mixer!r}: 'attention' or 'conv'; "
+                             f"router {router!r}: 'pre_mixer' or 'pre_ffn'")
         self._heads, self._kv_heads = heads, kv_heads
         self._window, self._theta = window, rope_theta
+        self._epsilon, self._router = epsilon, router
         self.experts = self.ffn = self.post_norm1 = self.post_norm2 = None
+        self.conv = self.q_norm = self.k_norm = None
         with self.name_scope():
             self.norm1 = RMSNorm(hidden, epsilon, prefix='norm1_')
-            self.q, self.k, self.v = (
-                nn.Dense(n * head_dim, use_bias=False, flatten=False,
-                         in_units=hidden, prefix=name)
-                for n, name in ((heads, 'q_'), (kv_heads, 'k_'),
-                                (kv_heads, 'v_')))
-            self.o = nn.Dense(hidden, use_bias=False, flatten=False,
-                              in_units=heads * head_dim, prefix='o_')
+            if mixer == 'conv':
+                self.conv = ShortConv(hidden, conv_taps, prefix='conv_')
+            else:
+                self.q, self.k, self.v = (
+                    nn.Dense(n * head_dim, use_bias=False, flatten=False,
+                             in_units=hidden, prefix=name)
+                    for n, name in ((heads, 'q_'), (kv_heads, 'k_'),
+                                    (kv_heads, 'v_')))
+                self.o = nn.Dense(hidden, use_bias=False, flatten=False,
+                                  in_units=heads * head_dim, prefix='o_')
+                if qk_norm:
+                    self.q_norm, self.k_norm = (
+                        self.params.get(name, shape=(head_dim,),
+                                        init='ones')
+                        for name in ('q_norm_gamma', 'k_norm_gamma'))
             self.norm2 = RMSNorm(hidden, epsilon, prefix='norm2_')
             if experts is not None:
                 self.experts = SparseExperts(hidden, prefix='experts_',
@@ -162,9 +265,29 @@ class DecoderBlock(HybridBlock):
 
     def forward(self, x):
         a = self.norm1(x)
-        if self.experts is not None:
+        if self.experts is not None and self._router == 'pre_mixer':
             router_logits = self.experts.route(a)   # read before attention
+        attn = self.conv(a) if self.conv is not None else self._attention(a)
+        if self.post_norm1 is not None:
+            attn = self.post_norm1(attn)
+        x = x + attn
+        b = self.norm2(x)
+        if self.experts is not None and self._router == 'pre_ffn':
+            router_logits = self.experts.route(b)
+        out = self.ffn(b) if self.experts is None \
+            else self.experts(b, router_logits)
+        if self.post_norm2 is not None:
+            out = self.post_norm2(out)
+        return x + out
+
+    def _attention(self, a):
         q, k, v = self.q(a), self.k(a), self.v(a)
+        if self.q_norm is not None:
+            with jax.named_scope(_scopes.QK_NORM):
+                q = _invoke(_head_rms_norm, q, self.q_norm.data(),
+                            heads=self._heads, eps=self._epsilon)
+                k = _invoke(_head_rms_norm, k, self.k_norm.data(),
+                            heads=self._kv_heads, eps=self._epsilon)
         if self._theta is not None:
             with jax.named_scope(_scopes.ROPE):
                 q = _invoke(nn_ops.rotary_embedding, q,
@@ -176,16 +299,7 @@ class DecoderBlock(HybridBlock):
             attn = _invoke(attn_ops.multi_head_attention, q, k, v, None,
                            num_heads=self._heads, causal=True,
                            num_kv_heads=self._kv_heads, window=self._window)
-        attn = self.o(attn)
-        if self.post_norm1 is not None:
-            attn = self.post_norm1(attn)
-        x = x + attn
-        b = self.norm2(x)
-        out = self.ffn(b) if self.experts is None \
-            else self.experts(b, router_logits)
-        if self.post_norm2 is not None:
-            out = self.post_norm2(out)
-        return x + out
+        return self.o(attn)
 
 
 class ExitGate(HybridBlock):
@@ -259,8 +373,14 @@ class DecoderModel(HybridBlock):
     ``windows`` and ``rope_thetas`` give each layer's window and rotary
     base (None: the whole prefix, no positions); ``experts`` is
     SparseExperts' keyword arguments (width, experts, top_k, held,
-    first_expert) or ``ffn`` GatedFFN's (width, activation);
-    ``post_norms`` as DecoderBlock's.
+    first_expert, scoring, gate, bias_rate) or ``ffn`` GatedFFN's (width,
+    activation); ``post_norms``, ``qk_norm``, ``router`` and
+    ``conv_taps`` as DecoderBlock's. Per layer: ``mixers`` 'attention'
+    (the default) or 'conv', whose window and rotary base are not read;
+    ``ffn_kinds`` 'experts' or 'dense', with both ``experts`` and ``ffn``
+    given (the default: the one given, every layer). ``tie_head``: the
+    logits are the final state against the embedding's rows, which are
+    the vocabulary rows held here; no head matrix of its own.
 
     ``passes`` > 1 or ``exit_gate``: a looped model. The stack and the
     final norm are applied ``passes`` times, each pass to its
@@ -276,24 +396,38 @@ class DecoderModel(HybridBlock):
 
     def __init__(self, vocab_size, hidden, heads, kv_heads, head_dim,
                  windows, rope_thetas, experts=None, epsilon=1e-6, ffn=None,
-                 post_norms=False, passes=1, exit_gate=False, **kwargs):
+                 post_norms=False, passes=1, exit_gate=False, mixers=None,
+                 ffn_kinds=None, qk_norm=False, router='pre_mixer',
+                 conv_taps=3, tie_head=False, **kwargs):
         super().__init__(**kwargs)
-        if len(windows) != len(rope_thetas):
-            raise ValueError("one window and one rotary base a layer")
+        layers = len(windows)
+        mixers = mixers or ['attention'] * layers
+        if ffn_kinds is None:
+            ffn_kinds = ['dense' if experts is None else 'experts'] * layers
+        if not len(rope_thetas) == len(mixers) == len(ffn_kinds) == layers:
+            raise ValueError("one window, rotary base, mixer and "
+                             "feed-forward kind a layer")
         self._passes = passes
-        self.exit_gate = None
+        self.exit_gate = self.head = None
         with self.name_scope():
             self.embed = nn.Embedding(vocab_size, hidden, prefix='embed_')
             self.blocks = nn.HybridSequential(prefix='blocks_')
             with self.blocks.name_scope():
-                for window, theta in zip(windows, rope_thetas):
+                for window, theta, mixer, kind in zip(
+                        windows, rope_thetas, mixers, ffn_kinds):
+                    dense = kind == 'dense'
                     self.blocks.add(DecoderBlock(
-                        hidden, heads, kv_heads, head_dim, experts,
-                        window=window, rope_theta=theta, epsilon=epsilon,
-                        ffn=ffn, post_norms=post_norms))
+                        hidden, heads, kv_heads, head_dim,
+                        None if dense else experts, window=window,
+                        rope_theta=theta, epsilon=epsilon,
+                        ffn=ffn if dense else None, post_norms=post_norms,
+                        mixer=mixer, conv_taps=conv_taps, qk_norm=qk_norm,
+                        router=router))
             self.norm = RMSNorm(hidden, epsilon, prefix='norm_')
-            self.head = nn.Dense(vocab_size, use_bias=False, flatten=False,
-                                 in_units=hidden, prefix='head_')
+            if not tie_head:
+                self.head = nn.Dense(vocab_size, use_bias=False,
+                                     flatten=False, in_units=hidden,
+                                     prefix='head_')
             if exit_gate:
                 self.exit_gate = ExitGate(
                     hidden, prefix=_scopes.EXIT_GATE + '_')
@@ -306,6 +440,9 @@ class DecoderModel(HybridBlock):
             for blk in self.blocks:
                 x = blk(x)
         with jax.named_scope(_scopes.LM_HEAD):
+            if self.head is None:
+                return _invoke(_tied_head, self.norm(x),
+                               self.embed.weight.data())
             return self.head(self.norm(x))
 
     def _looped(self, x):
@@ -328,9 +465,23 @@ class DecoderModel(HybridBlock):
             else _invoke(lambda s: jnp.zeros(s.shape[:-1], jnp.float32),
                          states)
         if _state.is_training:
-            return states, gates, self.head.weight.data()
+            return states, gates, self._head_weight()
         with jax.named_scope(_scopes.LM_HEAD):
+            if self.head is None:
+                return _invoke(_tied_head, states,
+                               self.embed.weight.data()), gates
             return self.head(states), gates
+
+    def _head_weight(self):
+        """(vocab, hidden): the head's rows, or the embedding's tied."""
+        return (self.embed if self.head is None else self.head).weight.data()
+
+
+def _tied_head(x, embedding):
+    """x (..., hidden) against the (vocab, hidden) embedding rows held
+    here: the logits in x's dtype, accumulated in float32."""
+    return jnp.einsum('...h,vh->...v', x, embedding,
+                      preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 # positions the loss takes at a time

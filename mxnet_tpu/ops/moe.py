@@ -2,9 +2,12 @@
 
 The layer is told which experts it holds (``first_expert`` and the leading
 dimension of its weights), routes every token over all the experts of the
-model (softmax over the router's logits, top-k, the k weights
-renormalised), and computes the part of the result that its own experts
-give: ``sum over the held experts among a token's k of w * expert(x)``.
+model by one of two scoring rules (:func:`route`: ``softmax`` over the
+router's logits, or ``sigmoid`` of each logit with a balancing bias that
+enters the choice alone; top-k, the k scores renormalised), and computes
+the part of the result that its own experts give: ``sum over the held
+experts among a token's k of w * expert(x)``, an expert being a gated
+feed-forward with a ReGLU or a SiLU gate (``GATES``).
 What the absent experts would add is their chips' to add; nothing here
 stands in for them or for their traffic.
 
@@ -61,9 +64,16 @@ ROW_TILE = 256
 _BLOCK_BYTES = 4 << 20
 _VMEM_LIMIT = 48 << 20
 
+# the scoring rules of :func:`route` and the gates between the grouped
+# matmuls
+SCORINGS = ('softmax', 'sigmoid')
+GATES = ('reglu', 'silu')
+
 # trace-time telemetry, beside ops.attention.route_counts: how the
-# grouped matmuls were computed, and {(experts, held, top-k, rows, tile):
-# layers built}
+# grouped matmuls were computed, the scoring rules and gates the layers
+# were built with, and {(experts, held, top-k, rows, tile): layers built}
+# -- with ``(scoring, gate)`` appended to the key where they are not
+# softmax and ReGLU
 route_counts = {'pallas': 0, 'xla': 0}
 builds = {}
 
@@ -87,13 +97,43 @@ def router_logits(x, weight):
                       preferred_element_type=jnp.float32)
 
 
-def route(logits, top_k):
-    """(expert ids (T, k) int32, weights (T, k) float32) of each token:
-    softmax over all the experts, the k largest, renormalised to sum 1."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, ids = lax.top_k(probs, top_k)
+def route(logits, top_k, scoring='softmax', bias=None):
+    """(expert ids (T, k) int32, weights (T, k) float32) of each token.
+    ``softmax``: softmax over all the experts, the k largest, renormalised
+    to sum 1. ``sigmoid``: s = sigmoid(logit) of each expert, the k
+    largest of s + ``bias`` (E,) chosen, and their s -- not s + bias --
+    renormalised to sum 1: the bias moves the choice alone (DeepSeek-V3's
+    auxiliary-loss-free balancing, arXiv:2412.19437 section 2.1.2)."""
+    logits = logits.astype(jnp.float32)
+    if scoring == 'softmax':
+        weights, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    elif scoring == 'sigmoid':
+        scores = jax.nn.sigmoid(logits)
+        chosen = scores if bias is None \
+            else scores + lax.stop_gradient(bias.astype(jnp.float32))
+        _, ids = lax.top_k(chosen, top_k)
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
+    else:
+        raise ValueError(f"scoring {scoring!r}: one of {SCORINGS}")
     return ids.astype(jnp.int32), \
         weights / jnp.sum(weights, axis=-1, keepdims=True)
+
+
+def assignment_counts(ids, experts):
+    """(E,) int32: the assignments of ``ids`` (any shape, expert ids) to
+    each of the model's ``experts``: what a step's balancing bias moves
+    by, and the load a step puts on each expert."""
+    return jnp.sum(ids.reshape(-1, 1) == jnp.arange(experts, dtype=ids.dtype),
+                   axis=0, dtype=jnp.int32)
+
+
+def next_bias(bias, counts, rate):
+    """The balancing bias after a step: b + rate * sign(mean(n) - n) over
+    the ``counts`` n of every expert of the model (float32): an expert
+    that took more than its share is chosen a little less often."""
+    counts = counts.astype(jnp.float32)
+    return bias.astype(jnp.float32) \
+        + rate * jnp.sign(jnp.mean(counts) - counts)
 
 
 # ---------------------------------------------------------------------------
@@ -174,9 +214,20 @@ def _gather_sum(rows, pos, weights=None):
     return total.astype(rows.dtype)
 
 
-def _reglu(gate_up):
+def _act(gate, kind):
+    """The gate's activation, float32 or the operands' dtype."""
+    return jnp.maximum(gate, 0) if kind == 'reglu' else jax.nn.silu(gate)
+
+
+def _silu_grad(gate):
+    """d silu / d gate = s (1 + g (1 - s)), s = sigmoid(g)."""
+    s = jax.nn.sigmoid(gate)
+    return s * (1 + gate * (1 - s))
+
+
+def _glu(gate_up, kind):
     gate, up = jnp.split(gate_up, 2, axis=-1)
-    return jnp.maximum(gate, 0) * up
+    return _act(gate, kind) * up
 
 
 # ---------------------------------------------------------------------------
@@ -321,40 +372,42 @@ def grouped_matmul_dw(x, dy, tile_expert, tile, experts, interpret=None):
 # the layer
 # ---------------------------------------------------------------------------
 
-def _forward(x, weights, w_gate_up, w_down, slots, tile, interpret):
+def _forward(x, weights, w_gate_up, w_down, slots, tile, interpret, gate):
     """(y, gate|up): gather the rows into the layout, gate and up in one
-    grouped matmul, ReGLU, down, and each token's k slots weighted and
-    added back. x (T, h), weights (T, k) float32, ``slots`` what
-    :func:`_slots` gives."""
+    grouped matmul, the gate (``GATES``), down, and each token's k slots
+    weighted and added back. x (T, h), weights (T, k) float32, ``slots``
+    what :func:`_slots` gives."""
     tile_expert, src, _live, pos = slots
     with jax.named_scope(_scopes.MOE_ROUTE):
         xs = jnp.take(x, src, axis=0, mode='clip')
     with jax.named_scope(_scopes.MOE_EXPERTS):
         gate_up = grouped_matmul(xs, w_gate_up, tile_expert, tile, False,
                                  interpret)
-        ys = grouped_matmul(_reglu(gate_up), w_down, tile_expert, tile,
+        ys = grouped_matmul(_glu(gate_up, gate), w_down, tile_expert, tile,
                             False, interpret)
     with jax.named_scope(_scopes.MOE_ROUTE):
         return _gather_sum(ys, pos.reshape(weights.shape), weights), gate_up
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _experts(x, weights, w_gate_up, w_down, slots, tile, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _experts(x, weights, w_gate_up, w_down, slots, tile, interpret, gate):
     """The held experts' part of the result, (T, h). Its backward is
     written out, because every transpose of a gather is here a gather
     through the inverse indices (autodiff would scatter-add), and so
-    that only gate|up has to be kept: the gathered rows and ReGLU are
+    that only gate|up has to be kept: the gathered rows and the gate are
     computed again, the down projection's result is not needed."""
-    return _forward(x, weights, w_gate_up, w_down, slots, tile, interpret)[0]
+    return _forward(x, weights, w_gate_up, w_down, slots, tile, interpret,
+                    gate)[0]
 
 
-def _experts_fwd(x, weights, w_gate_up, w_down, slots, tile, interpret):
+def _experts_fwd(x, weights, w_gate_up, w_down, slots, tile, interpret,
+                 gate):
     y, gate_up = _forward(x, weights, w_gate_up, w_down, slots, tile,
-                          interpret)
+                          interpret, gate)
     return y, (x, weights, w_gate_up, w_down, slots, gate_up)
 
 
-def _experts_bwd(tile, interpret, res, dy):
+def _experts_bwd(tile, interpret, gate_kind, res, dy):
     x, weights, w_gate_up, w_down, slots, gate_up = res
     tile_expert, src, live, pos = slots
     pos = pos.reshape(weights.shape)
@@ -369,7 +422,7 @@ def _experts_bwd(tile, interpret, res, dy):
         dy_slots = jnp.take(dy, src, axis=0, mode='clip')
     with jax.named_scope(_scopes.MOE_EXPERTS):
         gate, up = (t.astype(f32) for t in jnp.split(gate_up, 2, axis=-1))
-        hidden = jnp.maximum(gate, 0) * up
+        hidden = _act(gate, gate_kind) * up
         # y = sum_c w_c * (hidden @ down): the weight scales a row, so it
         # is put on the narrow side of both products
         d_hidden = grouped_matmul(dy_slots, w_down, tile_expert, tile, True,
@@ -379,9 +432,11 @@ def _experts_bwd(tile, interpret, res, dy):
             (hidden * slot_w).astype(x.dtype), dy_slots, tile_expert, tile,
             held, interpret)
         d_hidden = d_hidden * slot_w
+        d_gate = jnp.where(gate > 0, d_hidden * up, 0.0) \
+            if gate_kind == 'reglu' else d_hidden * up * _silu_grad(gate)
         d_gate_up = jnp.concatenate(
-            [jnp.where(gate > 0, d_hidden * up, 0.0),
-             d_hidden * jnp.maximum(gate, 0)], axis=-1).astype(x.dtype)
+            [d_gate, d_hidden * _act(gate, gate_kind)],
+            axis=-1).astype(x.dtype)
         d_xs = grouped_matmul(d_gate_up, w_gate_up, tile_expert, tile, True,
                               interpret)
         d_gate_up_w = grouped_matmul_dw(xs, d_gate_up, tile_expert, tile,
@@ -398,8 +453,9 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def expert_layer(x, router_logits, w_gate_up, w_down, first_expert=0,
-                 top_k=1, interpret=None):
-    """This share's part of a sparse ReGLU expert layer.
+                 top_k=1, interpret=None, scoring='softmax', gate='reglu',
+                 bias=None, with_ids=False):
+    """This share's part of a sparse gated expert layer.
 
     x (N, T, h): the tokens; router_logits (N, T, E) over all E experts
     of the model; w_gate_up (held, h, 2f): the held experts' gate and up
@@ -407,21 +463,30 @@ def expert_layer(x, router_logits, w_gate_up, w_down, first_expert=0,
     ``first_expert`` to ``first_expert + held - 1``. Returns (N, T, h):
 
         sum over c of k, expert e_c held here:
-            w_c * (relu(x @ gate_e) * (x @ up_e)) @ down_e
+            w_c * (act(x @ gate_e) * (x @ up_e)) @ down_e
 
-    with e_1..e_k the ``top_k`` largest of softmax(router_logits) and w
-    their probabilities renormalised to sum 1."""
+    with act relu (``gate`` 'reglu') or silu ('silu'), e_1..e_k and w
+    the choice and the renormalised weights of :func:`route` by
+    ``scoring`` ('softmax', or 'sigmoid' with the balancing ``bias``
+    (E,)). ``with_ids``: the tokens' (N, T, k) expert ids too, as the
+    second of a pair."""
+    if gate not in GATES:
+        raise ValueError(f"gate {gate!r}: one of {GATES}")
     N, T, h = x.shape
     experts, held = router_logits.shape[-1], w_gate_up.shape[0]
     tokens = N * T
     rows, tile, tiles = plan(tokens, held, top_k)
     key_ = (experts, held, top_k, rows, tile)
+    if (scoring, gate) != ('softmax', 'reglu'):
+        key_ += (scoring, gate)
     builds[key_] = builds.get(key_, 0) + 1
     route_counts['xla' if _kernel_mode(interpret) is None else 'pallas'] += 1
     with jax.named_scope(_scopes.MOE_ROUTE):
-        ids, weights = route(router_logits.reshape(tokens, experts), top_k)
+        ids, weights = route(router_logits.reshape(tokens, experts), top_k,
+                             scoring, bias)
         slots = _slots(*_sorted_assignments(ids, first_expert, held), top_k,
                        tile, tiles)
     out = _experts(x.reshape(tokens, h), weights.astype(jnp.float32),
-                   w_gate_up, w_down, slots, tile, interpret)
-    return out.reshape(N, T, h)
+                   w_gate_up, w_down, slots, tile, interpret, gate)
+    out = out.reshape(N, T, h)
+    return (out, ids.reshape(N, T, top_k)) if with_ids else out

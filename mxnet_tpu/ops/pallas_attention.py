@@ -83,14 +83,21 @@ A ``window`` (a causal layer that sees the ``window`` keys up to its own)
 is a second inequality beside ``_cell_live`` (:func:`_cell_in_window`):
 the table lists the band's cells only, and inside a cell the two masks are
 one unsigned comparison of i - j. Grouped-query heads (``num_kv_heads``
-fewer than the query heads, a head a whole lane block): the forward and dq
-grids run over the query heads, ``lb`` of one group a step (``lb`` divides
-``rep``), and read the group's one key/value lane block through the index
-map; the dk/dv grid runs over the key/value heads, its q, dO, lse and
-delta blocks a whole group of ``rep`` query heads wide, and adds the
-group's heads into the one dk and dv inside the kernel: the same walk over
-a step's lane blocks, ``rep`` of them on the query side to one on the
-key/value side (:func:`_step_lanes`). Each windowed build counts its cells
+fewer than the query heads): the forward and dq grids run over the query
+heads, ``lb`` lane blocks a step, and read the key/value lane block their
+heads share through the index map; the dk/dv grid runs over the key/value
+lane blocks, its q, dO, lse and delta blocks the query heads of their
+groups wide, and adds a group's heads into its dk and dv inside the
+kernel: the same walk over a step's lane blocks, ``rep`` of them on the
+query side to one on the key/value side (:func:`_step_lanes`). With a
+head a whole lane block, ``lb`` divides ``rep``. With 64-wide heads a
+key/value lane block holds two heads and is read by the ``rep`` query lane
+blocks of their two groups, and a step takes all of them, so that which
+key/value head a query head reads is static: where that head lies in the
+other half of the lanes, the kernels turn the lanes by 64
+(:func:`_kv_shift`, ``pltpu.roll``) -- k and v in the forward and dq, a
+query head's q and dO in dk/dv -- and the contraction and the mask are
+then those of any head. Each windowed build counts its cells
 in ``window_cells``. Without either, the builds are the ones
 tests/test_causal_skip.py pins, equation for equation.
 
@@ -175,6 +182,9 @@ window_cells = {}
 # one (N, T, 3*H*D) array. Static numbers, counted at build.
 head_blocks = {}
 
+# of a grouped-query build: {(kind, H, Hkv, D): kernel builds}
+group_builds = {}
+
 # and of the layout the softmax row statistics (lse, delta) cross HBM in:
 # {(kind, statistics block shape): kernel builds}, counted where the
 # specs are built. Static numbers.
@@ -227,8 +237,23 @@ def _step_heads(N, C, D, G, rep=1):
     the array, or of one group where ``rep`` query heads share a
     key/value head."""
     W, hb = _lane_block(C, D)
+    if rep > 1 and hb > 1:
+        # a key/value lane block holds hb heads, read by the rep lane
+        # blocks of their groups' query heads: a step takes them all
+        return _rows_per_step(N, G // rep, hb), rep
     Gn = _rows_per_step(N, G, hb)
     return Gn, _lane_blocks_per_step(G, Gn, hb, rep if rep > 1 else C // W)
+
+
+def _kv_shift(head, hb, rep, D):
+    """Lanes to turn a key/value lane block by so that the head that query
+    head ``head`` of a step reads lies in that query head's own columns:
+    0 without groups, where query and key/value head share a position,
+    and with one head a lane block. A step's query heads read key/value
+    lane block 0 of the step (:func:`_step_heads`)."""
+    if rep == 1 or hb == 1:
+        return 0
+    return (head % hb - head // rep % hb) % hb * D
 
 
 def _step_lanes(kind, lb, rep):
@@ -463,7 +488,9 @@ def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd', N=None, rep=1):
     ``rep`` grouped query heads to a key/value head the group, ``rep``,
     so that its one key/value block is fetched once (28 query heads over
     4, T = 8192, 4 calls, 1 / 7 heads a step: dq 39.1 / 16.7, the
-    forward 32.0 / 26.2; dk/dv took the group before).
+    forward 32.0 / 26.2; dk/dv took the group before). With 64-wide
+    grouped heads the ``rep * 2`` query heads that read one key/value lane
+    block (:func:`_step_heads`).
 
     The defaults computed here are only the LAST rung of the ISSUE 18
     precedence ladder, applied by ops/autotune.resolve: explicit env
@@ -476,7 +503,10 @@ def _block_sizes(BH, Tq, Tk, D, dtype, kind='fwd', N=None, rep=1):
     cap = 512 if kind == 'fwd' else 256
     bq = max(min_sub, min(cap, Tq))
     bk = max(min_sub, min(cap, Tk))
-    if N is None or N * max(1, _LANES // D) >= 4:
+    hb = max(1, _LANES // D)
+    if rep > 1 and hb > 1:
+        wanted = (rep * hb,)        # the query heads of one key/value block
+    elif N is None or N * hb >= 4:
         wanted = (4, 8, 2)
     else:
         wanted = (rep,) if rep > 1 else (8, 4, 2)
@@ -627,6 +657,15 @@ def _own(x, mask):
     return x if mask is None else lax.select(mask, x, lax.full_like(x, 0))
 
 
+def _turn(x, lanes):
+    """``x`` (rows, W) with its lanes turned by ``lanes``: column c moves to
+    c + lanes, mod W. The identity at 0. Mosaic turns 32-bit data only, so
+    a bf16 block goes through float32, which loses nothing."""
+    if lanes == 0:
+        return x
+    return pltpu.roll(x.astype(jnp.float32), lanes, 1).astype(x.dtype)
+
+
 def _as_columns(rows):
     """(1, n) rows, each along the lanes, as the columns of one (n, rows)
     array, row i down the sublanes at lane i: one small transpose for all
@@ -642,7 +681,7 @@ def _as_columns(rows):
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
                    o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                    scale, causal, W, D, bq, bk, k_len, dropout_p, h_all,
-                   window=None, cells_ref=None):
+                   window=None, rep=1, cells_ref=None):
     """One (row-group, step of lane blocks, q-block, k-block) cell. Refs
     are VMEM blocks: q (Gn, bq, lq*W), k/v (Gn, bk, lk*W), kmask
     (Gn, 1, bk) additive f32, o (Gn, bq, lq*W), lse (Gn, lq*hb, 1, bq),
@@ -671,11 +710,16 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
         q = q_ref[g, :, qc]
         if shared:
             k, v, kmask_row = k_ref[g, :, kc], v_ref[g, :, kc], kmask_ref[g]
+            turned = {0: (k, v)}    # k and v by the lanes they are turned
         acc = acc_ref[g, :, qc]
         for hh in range(hb):
             head = j * hb + hh
             slot = g * heads + head
-            s = scores(_own(q, own[hh]), k, kmask_row)
+            lanes = _kv_shift(head, hb, rep, D)
+            if lanes not in turned:
+                turned[lanes] = (_turn(k, lanes), _turn(v, lanes))
+            k_h, v_h = turned[lanes]
+            s = scores(_own(q, own[hh]), k_h, kmask_row)
             m_prev = m_ref[slot, :, :1]                      # (bq, 1)
             l_prev = l_ref[slot, :, :1]
             m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -687,7 +731,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref,
             pv = p if kept is None else p * kept
             # p·v fills all W columns; this head's are kept
             new = acc * alpha + lax.dot_general(
-                pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                pv.astype(v.dtype), v_h, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             acc = new if own[hh] is None else lax.select(own[hh], new, acc)
             m_ref[slot] = jnp.broadcast_to(m_new, m_ref.shape[1:])
@@ -732,16 +776,22 @@ def _addressing(arrays, H, kind, Hkv=None):
             f"into 128-lane blocks of whole heads (flash_legal says so)")
     W, hb = _lane_block(C, D)
     if Hkv not in (None, H) and (
-            hb != 1 or H % Hkv or len(arrays) != 3
+            not _groups_legal(H, Hkv, D) or len(arrays) != 3
             or arrays[-1].shape[2] != Hkv * D):
         raise ValueError(
             f"flash attention: {H} query heads over {Hkv} key/value heads "
-            f"of {D} columns: a group needs whole 128-lane heads, H a "
-            f"multiple of Hkv and k, v of Hkv*D columns (flash_legal says "
-            f"so)")
+            f"of {D} columns: a group needs H a multiple of Hkv, k and v "
+            f"of Hkv*D columns that are whole lane blocks of the query "
+            f"side's width (128: two heads of 64, or one of 128 or more) "
+            f"(flash_legal says so)")
     rep = H // (Hkv or H)
     G, bq, bk = _block_sizes(N * H, Tq, Tk, D, arrays[0].dtype, kind,
                              N=N, rep=rep)
+    if rep > 1 and hb > 1 and G % (rep * hb):
+        raise ValueError(
+            f"flash attention: {G} heads a grid step, where a step of "
+            f"{D}-wide grouped heads holds the {rep * hb} that read one "
+            f"key/value lane block (flash_legal says so)")
     Gn, lb = _step_heads(N, C, D, G, rep)
     return N, Tq, Tk, C, D, W, hb, Gn, lb, bq, bk
 
@@ -773,9 +823,12 @@ def _mask_operand(kmask, N, Tk, pk):
     return jnp.pad(kmask.astype(jnp.float32), ((0, 0), (0, pk)))[:, None, :]
 
 
-def _count_build(kind, H, D, hb, offs):
+def _count_build(kind, H, D, hb, offs, rep=1):
     key = (kind, H, D, hb, offs != (0, 0, 0))
     head_blocks[key] = head_blocks.get(key, 0) + 1
+    if rep > 1:
+        key = (kind, H, H // rep, D)
+        group_builds[key] = group_builds.get(key, 0) + 1
 
 
 def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
@@ -798,11 +851,11 @@ def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
     nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
     pq, pk = nq * bq - Tq, nk * bk - Tk
     (q, k, v), (qo, ko, vo) = _operands(arrays, C, lb * W, pq, pk)
-    _count_build('fwd', H, D, hb, (qo, ko, vo))
+    _count_build('fwd', H, D, hb, (qo, ko, vo), rep)
 
     kw = dict(scale=1.0 / math.sqrt(D), causal=causal, W=W, D=D, bq=bq,
               bk=bk, k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
-              window=window)
+              window=window, rep=rep)
     seq, row, mask = _block_specs('fwd', Gn, hb, bq, bk, W, causal, lb, rep)
     cells = _causal_cell_table('fwd', nq, nk, bq, bk, by_row=True,
                                window=window) if causal else None
@@ -832,7 +885,7 @@ def _fa_forward(arrays, kmask, meta, H, causal, dropout_p, interpret, h_all,
 def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                   lse_ref, delta_ref, dq_ref, dq_acc, stat_col, *,
                   scale, causal, W, D, bq, bk, k_len, dropout_p, h_all,
-                  window=None, cells_ref=None):
+                  window=None, rep=1, cells_ref=None):
     """dq for one q-block of one step's lane blocks, accumulated over
     k-blocks (grid (N/Gn, steps, nq, nk)), written in the operands'
     dtype. lse and delta arrive as (Gn, lq*hb, 1, bq) rows and are wanted
@@ -859,15 +912,21 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
             k, kmask_row = k_ref[g, :, kc], kmask_ref[g]
             k32 = k.astype(jnp.float32)                   # (bk, W)
             v32 = v_ref[g, :, kc].astype(jnp.float32)
+            turned = {0: (k, k32, v32)}
         do32 = do_ref[g, :, qc].astype(jnp.float32)       # (bq, W)
         dq = dq_acc[g, :, qc]
         for hh in range(hb):
             head = j * hb + hh
             slot = g * heads + head
-            s = scores(_own(q, own[hh]), k, kmask_row)
+            lanes = _kv_shift(head, hb, rep, D)
+            if lanes not in turned:
+                k32_h, v32_h = _turn(k32, lanes), _turn(v32, lanes)
+                turned[lanes] = (k32_h.astype(k.dtype), k32_h, v32_h)
+            k_h, k32_h, v32_h = turned[lanes]
+            s = scores(_own(q, own[hh]), k_h, kmask_row)
             p = jnp.exp(s - stat_col[:, slot:slot + 1])   # (bq, bk)
             dp = lax.dot_general(
-                _own(do32, own[hh]), v32, (((1,), (1,)), ((), ())),
+                _own(do32, own[hh]), v32_h, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)       # (bq, bk)
             kept = keep(g, head)
             if kept is not None:
@@ -876,7 +935,7 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
             ds = p * (dp - delta) * scale                 # (bq, bk)
             # ds·k fills all W columns; this head's are kept
             dq = dq + _own(lax.dot_general(
-                ds, k32, (((1,), (0,)), ((), ())),
+                ds, k32_h, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32), own[hh])
         dq_acc[g, :, qc] = dq
 
@@ -889,7 +948,7 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                    kmask_col, *,
                    scale, causal, W, D, bq, bk, k_len, dropout_p, h_all,
-                   window=None, cells_ref=None):
+                   window=None, rep=1, cells_ref=None):
     """dk/dv for one k-block of one step's lane blocks, accumulated over
     q-blocks (grid (N/Gn, steps, nk, nq): k-block is program 2, q-block
     program 3), written in the operands' dtype. A cell is computed keys
@@ -900,7 +959,8 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
     outer block. With grouped-query heads the steps are over the
     key/value heads, q, dO, lse and delta come the whole group wide, and
     the group's heads add into the one dk and dv here, a head at a
-    time."""
+    time: a query head whose key/value head lies in the other lanes of the
+    block has its q and dO turned there (:func:`_kv_shift`)."""
     Gn, lq, lk = k_ref.shape[0], q_ref.shape[2] // W, k_ref.shape[2] // W
     own, first, last, scores, keep = _cell(
         cells_ref, 3, meta_ref, Gn, W, D, bq, bk, scale, causal, k_len,
@@ -922,9 +982,12 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, kmask_ref, meta_ref, do_ref,
         do32 = do_ref[g, :, qc].astype(jnp.float32)       # (bq, W)
         for hh in range(hb):
             head = j * hb + hh
-            # q and dO with the other heads' columns zeroed: what they
-            # are contracted into lands in this head's columns alone
-            q_own, do_own = _own(q, own[hh]), _own(do32, own[hh])
+            # q and dO with the other heads' columns zeroed, in the
+            # columns of the key/value head they read: what they are
+            # contracted into lands in that head's columns alone
+            lanes = -_kv_shift(head, hb, rep, D) % W
+            q_own, do_own = (_turn(_own(x, own[hh]), lanes)
+                             for x in (q, do32))
             s = scores(q_own, k, kmask)
             p = jnp.exp(s - lse_ref[g, head])             # (bk, bq)
             kept = keep(g, head)
@@ -983,14 +1046,14 @@ def _fa_backward(arrays, kmask, meta, H, causal, dropout_p, interpret,
 
     kw = dict(scale=1.0 / math.sqrt(D), causal=causal, W=W, D=D, bq=bq,
               bk=bk, k_len=Tk, dropout_p=float(dropout_p), h_all=h_all,
-              window=window)
+              window=window, rep=rep)
     operands = (q, k, v, _mask_operand(kmask, N, Tk, pk), meta, do, lse,
                 delta)
     calls = []
     for kind, kernel, plane, side, n_out in (
             ('bwd_dq', _fa_dq_kernel, (nq, nk), 'q', 1),
             ('bwd_dkv', _fa_dkv_kernel, (nk, nq), 'k', 2)):
-        _count_build(kind, H, D, hb, (qo, ko, vo))
+        _count_build(kind, H, D, hb, (qo, ko, vo), rep)
         # the grid's steps are over the columns dq, or dk and dv, have:
         # with grouped heads dk/dv's run over the key/value heads, each
         # a whole group of query heads wide
@@ -1061,24 +1124,41 @@ def _flash_bwd(H, causal, dropout_p, interpret, h_all, Hkv, window, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _groups_legal(H, Hkv, D):
+    """Can ``H`` query heads of ``D`` columns read ``Hkv`` key/value heads
+    in the kernels' lane blocks: H a multiple of Hkv, and the key/value
+    heads' columns whole lane blocks of the query side's width -- a head
+    of a multiple of 128 columns, or 64-wide heads two to a block, of
+    which there are then an even number."""
+    blocks = _lane_block(H * D, D)
+    return not H % Hkv and blocks is not None and Hkv * D % blocks[0] == 0
+
+
 def flash_legal(BH, Tq, Tk, D, dtype, num_heads=1, num_kv_heads=None) -> bool:
     """Can the forward AND backward kernels be built for this shape at
     the block sizes they would use? The heads' columns have to come
-    apart into lane blocks (:func:`_lane_block`), grouped-query heads
-    (``num_kv_heads`` fewer than ``num_heads``) have to be whole lane
-    blocks each, and the static Mosaic rules of
+    apart into lane blocks (:func:`_lane_block`); grouped-query heads
+    (``num_kv_heads`` fewer than ``num_heads``) need H a multiple of Hkv
+    and the key/value heads in whole lane blocks (:func:`_groups_legal`:
+    one head of 128 or more columns a block, or two of 64), and with
+    64-wide heads a grid step of all the query heads that read one
+    key/value block; and the static Mosaic rules of
     ops/autotune.check_candidate hold for what :func:`_block_sizes`
     resolves — the verdict ``multi_head_attention`` routes on."""
     from . import autotune
-    if num_kv_heads not in (None, num_heads) and (
-            D % _LANES or num_heads % num_kv_heads):
+    lanes = _lane_block(num_heads * D, D)
+    if lanes is None or num_kv_heads not in (None, num_heads) and \
+            not _groups_legal(num_heads, num_kv_heads, D):
         return False
     N, rep = BH // num_heads, num_heads // (num_kv_heads or num_heads)
-    return _lane_block(num_heads * D, D) is not None and all(
-        autotune.check_candidate(
-            BH, Tq, Tk, D, jnp.dtype(dtype), kind,
-            *_block_sizes(BH, Tq, Tk, D, dtype, kind, N, rep), N, rep)[0]
-        for kind in ('fwd', 'bwd'))
+    # 64-wide grouped heads: a step holds every query head of its block
+    step = rep * lanes[1] if rep > 1 and lanes[1] > 1 else 1
+    for kind in ('fwd', 'bwd'):
+        blocks = _block_sizes(BH, Tq, Tk, D, dtype, kind, N, rep)
+        if blocks[0] % step or not autotune.check_candidate(
+                BH, Tq, Tk, D, jnp.dtype(dtype), kind, *blocks, N, rep)[0]:
+            return False
+    return True
 
 
 def flash_mha(arrays, num_heads, key_mask=None, causal=False, dropout_p=0.0,
@@ -1094,8 +1174,9 @@ def flash_mha(arrays, num_heads, key_mask=None, causal=False, dropout_p=0.0,
     when dropout_p > 0). Returns (N, Tq, H*D).
 
     num_kv_heads: grouped-query attention, k and v being (N, T, Hkv*D)
-    and query head h reading key/value head h // (H // Hkv); a head has
-    to be a multiple of 128 columns. window: with ``causal``, a query
+    and query head h reading key/value head h // (H // Hkv); a head of a
+    multiple of 128 columns, or of 64 with Hkv even
+    (:func:`_groups_legal`). window: with ``causal``, a query
     sees the ``window`` keys up to its own (score (i, j) kept iff
     0 <= i - j < window); the kernels then visit the band's cells only.
 

@@ -64,6 +64,11 @@ UT_LOOP = 'ut_loop'             # looped decoder: every pass of the stack and
 UT_PASS = 'ut_pass'             # one pass of it, numbered: ut_pass0 ...
 FFN_GLU = 'ffn_glu'             # decoder: the dense gated feed-forward
 EXIT_GATE = 'exit_gate'         # looped decoder: the exit gate's logit
+SHORT_CONV = 'short_conv'       # decoder: a convolution layer's mixer, its
+                                # in-projection, core and out-projection
+SHORT_CONV_CORE = 'short_conv_core'     # ops/shortconv.py: the gates and the
+                                        # causal depthwise convolution
+QK_NORM = 'qk_norm'             # decoder: the per-head RMS norm of q and k
 
 # Pallas kernels
 FLASH_FWD = 'mxtpu_flash_fwd'
@@ -73,6 +78,8 @@ FFN_GELU = 'mxtpu_ffn_gelu'
 ADD_LAYERNORM = 'mxtpu_add_layernorm'
 GROUPED_MATMUL = 'mxtpu_grouped_matmul'     # ops/moe.py: forward and both
                                             # backward products
+SHORT_CONV_FWD = 'mxtpu_short_conv'         # ops/shortconv.py: the core,
+SHORT_CONV_BWD = 'mxtpu_short_conv_bwd'     # forward and backward
 
 # jax.ad_checkpoint.checkpoint_name: what the flash forward hands its
 # backward, named where the custom_vjp's forward rule returns it

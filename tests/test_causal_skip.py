@@ -348,6 +348,32 @@ def test_mosaic_compiles_grouped_and_windowed_heads_for_a_described_v5e(
         assert name in text
 
 
+@pytest.mark.parametrize('N', [1, 2])
+def test_mosaic_compiles_grouped_64_wide_heads_for_a_described_v5e(one_chip,
+                                                                  N):
+    """lfm2_24b_a2b.t8192_ep8's attention layer (tests/test_gqa64.py holds
+    its results): 8192-token sequences, 32 query heads over 8 key/value
+    heads of 64, bf16, causal, default blocks. A grid step holds a
+    key/value lane block of two heads and the eight query heads that read
+    it, whose kernels turn lanes by 64 (``pltpu.roll``): forward, dq and
+    dk/dv compile, as one call each."""
+    q = jax.ShapeDtypeStruct((N, 8192, 32 * 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((N, 8192, 8 * 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        out = pa.flash_mha((q, k, v), 32, causal=True, num_kv_heads=8,
+                           interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ('mxtpu_flash_fwd', 'mxtpu_flash_bwd_dq',
+                 'mxtpu_flash_bwd_dkv'):
+        assert name in text
+
+
 def test_mosaic_compiles_a_step_of_lane_blocks_for_a_described_v5e(one_chip):
     """ouro_2_6b.t4096's layer: one 4096-token sequence, 16 heads of 128,
     bf16, causal, default blocks. With one row a chip a grid step's heads
@@ -387,8 +413,8 @@ def test_mosaic_compiles_the_grouped_matmuls_for_a_described_v5e(one_chip):
     def products(x, w_gate_up, w_down, tile_expert):
         gate_up = moe.grouped_matmul(x, w_gate_up, tile_expert, tile, False,
                                      False)
-        y = moe.grouped_matmul(moe._reglu(gate_up), w_down, tile_expert, tile,
-                               False, False)
+        y = moe.grouped_matmul(moe._glu(gate_up, 'reglu'), w_down,
+                               tile_expert, tile, False, False)
         dx = moe.grouped_matmul(gate_up, w_gate_up, tile_expert, tile, True,
                                 False)
         dw = moe.grouped_matmul_dw(x, gate_up, tile_expert, tile, 16, False)

@@ -199,9 +199,18 @@ def test_the_xla_route_takes_the_same_arguments(window):
 
 
 def test_groups_need_whole_lane_heads():
+    """A group's key/value heads are whole lane blocks: one head of 128
+    columns, or two of 64 (since PR 41; tests/test_gqa64.py holds their
+    results); one 64-wide key/value head, or 96-wide heads, are not."""
     assert pa.flash_legal(28, 8192, 8192, 128, jnp.bfloat16, num_heads=28,
                           num_kv_heads=4)
-    assert not pa.flash_legal(8, 128, 128, 64, jnp.bfloat16, num_heads=8,
+    assert pa.flash_legal(8, 128, 128, 64, jnp.bfloat16, num_heads=8,
+                          num_kv_heads=2)
+    assert pa.flash_legal(32, 8192, 8192, 64, jnp.bfloat16, num_heads=32,
+                          num_kv_heads=8)
+    assert not pa.flash_legal(4, 128, 128, 64, jnp.bfloat16, num_heads=4,
+                              num_kv_heads=1)
+    assert not pa.flash_legal(8, 128, 128, 96, jnp.bfloat16, num_heads=8,
                               num_kv_heads=2)
     assert not pa.flash_legal(6, 128, 128, 128, jnp.bfloat16, num_heads=6,
                               num_kv_heads=4)
