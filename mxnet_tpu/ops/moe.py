@@ -11,16 +11,16 @@ feed-forward with a ReGLU or a SiLU gate (``GATES``).
 What the absent experts would add is their chips' to add; nothing here
 stands in for them or for their traffic.
 
-No assignment to a held expert is ever dropped, and the device does the
-same work a step whatever the router decides. The two together leave one
-size: a token's k assignments may all land here, so the layer computes
-``tokens * k`` rows, always (:func:`plan`). A smaller layout with a
-``lax.cond`` for what overflows it was built first and measured on the
-chip (PERF.md section 6, PR 34): at 1.5 times the balanced share the
-overflow ran in the traced run and two other seeds' steps read 6 % apart,
-because a randomly initialised router sends most tokens of a deep layer
-to the same few experts (395 to 31 931 rows here where balance is
-12 288), and training without a balancing loss moves it further.
+No assignment to a held expert is ever dropped, and the program has one
+shape whatever the router decides: a token's k assignments may all land
+here, so the layout holds ``tokens * k`` rows, always (:func:`plan`). A
+smaller layout with a ``lax.cond`` for what overflows it was built first
+and measured on the chip (PERF.md section 6, SmallThinker's entry): at
+1.5 times the balanced share the overflow ran in the traced run and two
+other seeds' steps read 6 % apart, because a randomly initialised router
+sends most tokens of a deep layer to the same few experts (395 to 31 931
+rows here where balance is 12 288), and training without a balancing
+loss moves it further.
 
 * the ``tokens * k`` assignments are sorted by a key that puts the held
   experts first, in expert order (one stable sort, and its inverse);
@@ -29,9 +29,17 @@ to the same few experts (395 to 31 931 rows here where balance is
   least one tile (:func:`_layout`): ``tokens * k / tile + held`` tiles
   hold any routing. Slots past an expert's rows compute on some token's
   row and are read by nobody;
+* the tiles that hold some expert's rows come first, ``n_live`` of them
+  (``held <= n_live <= tiles``, :func:`tiles_computed`), and the grouped
+  matmuls stop there: a grid step past them issues no copy and computes
+  nothing, so the device's time follows the rows that landed and not the
+  layout's size. The rows of those dead tiles are undefined in every
+  product, and nothing reads them;
 * the rows are gathered into that layout, and the result is combined by
-  gathering each token's k slots back (a miss reads zero): no scatter,
-  forward or backward.
+  gathering each token's k slots back: no scatter, forward or backward.
+  A miss is a select of zero, not a zero weight on a clamped index, since
+  the clamped index is the last slot, which lies in a dead tile unless
+  the layout is full, and 0 * NaN is NaN.
 
 The grouped matmul is a Pallas kernel, ``mxtpu_grouped_matmul``: one row
 tile a grid step against the weights of the tile's expert, read from a
@@ -73,16 +81,21 @@ GATES = ('reglu', 'silu')
 # grouped matmuls were computed, the scoring rules and gates the layers
 # were built with, and {(experts, held, top-k, rows, tile): layers built}
 # -- with ``(scoring, gate)`` appended to the key where they are not
-# softmax and ReGLU
+# softmax and ReGLU; and {(kernel kind, tiles a layer, tile): builds} of
+# the grouped-matmul kernels bounded by the live tile count, the kind
+# ``gmm`` (forward, dx, d_hidden) or ``tgmm`` (a weight gradient)
 route_counts = {'pallas': 0, 'xla': 0}
 builds = {}
+live_tile_builds = {}
 
 
 def plan(tokens, held, top_k):
-    """(rows, tile, tiles): the rows the grouped matmuls are sized for --
-    every assignment, since all of them may land here --, the rows of a
-    tile, and the tiles of the layout: the rows' and one more an expert,
-    for the padding to tile boundaries."""
+    """(rows, tile, tiles): the rows the layout is sized for -- every
+    assignment, since all of them may land here --, the rows of a tile,
+    and the tiles of the layout: the rows' and one more an expert, for
+    the padding to tile boundaries. One size, nothing dropped; the
+    grouped matmuls compute only the leading tiles that hold rows
+    (:func:`tiles_computed`), and the rows of the others are undefined."""
     rows = tokens * top_k
     tile = min(ROW_TILE, max(16, 16 * math.ceil(rows / held / 64)))
     return rows, tile, math.ceil(rows / tile) + held
@@ -158,25 +171,39 @@ def _sorted_assignments(ids, first, held):
     return key, order, rank, sizes
 
 
+def _expert_tiles(sizes, tile):
+    """The tiles each expert owns: its rows' and at least one."""
+    return jnp.maximum(1, -(-sizes // tile))
+
+
+def tiles_computed(sizes, tile):
+    """The tiles of a layer that the grouped matmuls compute when the
+    held experts take ``sizes`` rows (one number an expert): each
+    expert's rows on whole tiles, and at least one tile an expert."""
+    return int(jnp.sum(_expert_tiles(jnp.asarray(sizes, jnp.int32), tile)))
+
+
 def _layout(sizes, tile, tiles):
-    """(tile_expert (tiles,), pad_start (held,)): the experts in order,
-    each on whole tiles and each on at least one (so that a weight
-    gradient is written for every expert); the tiles after the last
-    expert's are its too. sum(sizes) <= (tiles - held) * tile."""
+    """(tile_expert (tiles,), pad_start (held,), n_live (1,)): the experts
+    in order, each on whole tiles and each on at least one (so that a
+    weight gradient is written for every expert); the tiles after the
+    last expert's are its too, and the first ``n_live`` tiles are the
+    ones that hold an expert's rows. sum(sizes) <= (tiles - held) * tile."""
     held = sizes.shape[0]
-    end = jnp.cumsum(jnp.maximum(1, -(-sizes // tile)))
+    end = jnp.cumsum(_expert_tiles(sizes, tile))
     tile_expert = jnp.minimum(
         jnp.sum(jnp.arange(tiles, dtype=jnp.int32)[:, None] >= end,
                 axis=-1, dtype=jnp.int32), held - 1)
-    return tile_expert, (end - jnp.diff(end, prepend=0)) * tile
+    return tile_expert, (end - jnp.diff(end, prepend=0)) * tile, end[-1:]
 
 
 def _slots(key, order, rank, sizes, top_k, tile, tiles):
-    """The gather indices: (tile_expert, src (slots,) the token each slot
-    reads, live (slots,) the assignment whose row it is or -1, pos (A,)
-    each assignment's slot or ``slots`` for one to an expert elsewhere)."""
+    """The gather indices: (tile_expert, n_live, src (slots,) the token
+    each slot reads, live (slots,) the assignment whose row it is or -1,
+    pos (A,) each assignment's slot or ``slots`` for one to an expert
+    elsewhere)."""
     slots = tile * tiles
-    tile_expert, pad_start = _layout(sizes, tile, tiles)
+    tile_expert, pad_start, n_live = _layout(sizes, tile, tiles)
     start = jnp.cumsum(sizes) - sizes
     g = jnp.repeat(tile_expert, tile)
     j = jnp.arange(slots, dtype=jnp.int32) - pad_start[g]
@@ -186,7 +213,7 @@ def _slots(key, order, rank, sizes, top_k, tile, tiles):
     # an assignment elsewhere has key == held: it gets no slot
     pos = jnp.where(key < sizes.shape[0],
                     jnp.append(pad_start, 0)[key] + rank, slots)
-    return tile_expert, src, live, pos.astype(jnp.int32)
+    return tile_expert, n_live, src, live, pos.astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -201,16 +228,20 @@ def _gather_sum(rows, pos, weights=None):
     """sum_c weights[t, c] * rows[pos[t, c]] (weights None: 1), a miss
     (pos == len(rows)) reading zero; added up in float32 a column of
     ``pos`` at a time, so that no (T, k, h) array exists. The miss is a
-    zero weight on a clamped index: ``take(mode='fill')`` becomes a gather
-    that XLA:TPU strips of its op_name, and a trace then cannot say whose
-    it is."""
+    select on a clamped index: ``take(mode='fill')`` becomes a gather that
+    XLA:TPU strips of its op_name, and a trace then cannot say whose it
+    is; and the clamped row is the last slot, undefined where its tile is
+    past the live ones, so a zero weight on it could read 0 * NaN."""
     hit = pos < rows.shape[0]
-    weights = hit if weights is None else jnp.where(hit, weights, 0.0)
     total = 0.0
     for c in range(pos.shape[1]):
-        picked = jnp.take(rows, pos[:, c], axis=0, mode='clip')
-        total = total + weights[:, c, None].astype(jnp.float32) \
-            * picked.astype(jnp.float32)
+        picked = jnp.where(
+            hit[:, c, None],
+            jnp.take(rows, pos[:, c], axis=0, mode='clip').astype(jnp.float32),
+            0.0)
+        if weights is not None:
+            picked = weights[:, c, None].astype(jnp.float32) * picked
+        total = total + picked
     return total.astype(rows.dtype)
 
 
@@ -243,83 +274,109 @@ def _col_tile(n, rows, itemsize):
     return max(fits) if fits else n
 
 
-def _gmm_kernel(te_ref, x_ref, w_ref, o_ref, *, transposed):
+def _live_tile(i, nl):
+    """The row tile grid step ``i`` maps its blocks to: its own up to the
+    last live tile, that one after it, so that a dead step's blocks are
+    the ones already in VMEM and the pipeline issues no copy."""
+    return jnp.minimum(i, nl[0] - 1)
+
+
+def _count_build(kind, tiles, tile):
+    key = (kind, tiles, tile)
+    live_tile_builds[key] = live_tile_builds.get(key, 0) + 1
+
+
+def _gmm_kernel(te_ref, nl_ref, x_ref, w_ref, o_ref, *, transposed):
     """One row tile against its expert's weight block: o = x @ w, or
-    x @ w^T where the weights are stored (out, in) for this product."""
+    x @ w^T where the weights are stored (out, in) for this product; a
+    tile past the live ones is skipped, and its rows stay undefined."""
     contract = (((1,), (1,)), ((), ())) if transposed \
         else (((1,), (0,)), ((), ()))
-    o_ref[...] = lax.dot_general(
-        x_ref[...], w_ref[0], contract,
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    @pl.when(pl.program_id(1) < nl_ref[0])
+    def _compute():
+        o_ref[...] = lax.dot_general(
+            x_ref[...], w_ref[0], contract,
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def _gmm_pallas(x, w, tile_expert, tile, transposed, interpret):
-    """(M, K) rows in tiles of ``tile``, tile i against w[tile_expert[i]]:
-    (E, K, N), or (E, N, K) ``transposed``. Grid (column tiles, row
-    tiles), the row tiles inner: consecutive tiles of one expert find its
-    weight block already in VMEM."""
+def _gmm_pallas(x, w, tile_expert, n_live, tile, transposed, interpret):
+    """(M, K) rows in tiles of ``tile``, tile i < n_live against
+    w[tile_expert[i]]: (E, K, N), or (E, N, K) ``transposed``. Grid
+    (column tiles, row tiles), the row tiles inner: consecutive tiles of
+    one expert find its weight block already in VMEM, and the steps past
+    the live tiles find every block there."""
     M, K = x.shape
     N = w.shape[1] if transposed else w.shape[2]
     tn = _col_tile(N, K, w.dtype.itemsize)
-    w_spec = pl.BlockSpec((1, tn, K), lambda n, i, te: (te[i], n, 0)) \
+    _count_build('gmm', M // tile, tile)
+    w_spec = pl.BlockSpec((1, tn, K), lambda n, i, te, nl: (te[i], n, 0)) \
         if transposed else \
-        pl.BlockSpec((1, K, tn), lambda n, i, te: (te[i], 0, n))
+        pl.BlockSpec((1, K, tn), lambda n, i, te, nl: (te[i], 0, n))
     return pl.pallas_call(
         functools.partial(_gmm_kernel, transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(N // tn, M // tile),
-            in_specs=[pl.BlockSpec((tile, K), lambda n, i, te: (i, 0)),
-                      w_spec],
-            out_specs=pl.BlockSpec((tile, tn), lambda n, i, te: (i, n))),
+            num_scalar_prefetch=2, grid=(N // tn, M // tile),
+            in_specs=[pl.BlockSpec(
+                (tile, K), lambda n, i, te, nl: (_live_tile(i, nl), 0)),
+                w_spec],
+            out_specs=pl.BlockSpec(
+                (tile, tn), lambda n, i, te, nl: (_live_tile(i, nl), n))),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary'),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name=_scopes.GROUPED_MATMUL,
-    )(tile_expert, x, w)
+    )(tile_expert, n_live, x, w)
 
 
-def _tgmm_kernel(te_ref, x_ref, dy_ref, o_ref, acc_ref):
-    """dw[e] = sum over the tiles of expert e of x^T @ dy: the float32
-    accumulator is cleared at a group's first tile and written at its
-    last. The groups are runs of the table, and every expert has one."""
-    i, last = pl.program_id(1), pl.num_programs(1) - 1
+def _tgmm_kernel(te_ref, nl_ref, x_ref, dy_ref, o_ref, acc_ref):
+    """dw[e] = sum over the live tiles of expert e of x^T @ dy: the
+    float32 accumulator is cleared at a group's first tile and written at
+    its last, the last expert's at the last live tile. The groups are
+    runs of the table, and every expert has one."""
+    i, last = pl.program_id(1), nl_ref[0] - 1
     e = te_ref[i]
 
-    @pl.when((i == 0) | (te_ref[jnp.maximum(i - 1, 0)] != e))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    @pl.when(i <= last)
+    def _compute():
+        @pl.when((i == 0) | (te_ref[jnp.maximum(i - 1, 0)] != e))
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += lax.dot_general(
-        x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        acc_ref[...] += lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when((i == last) | (te_ref[jnp.minimum(i + 1, last)] != e))
-    def _write():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+        @pl.when((i == last) | (te_ref[jnp.minimum(i + 1, last)] != e))
+        def _write():
+            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _tgmm_pallas(x, dy, tile_expert, tile, experts, interpret):
+def _tgmm_pallas(x, dy, tile_expert, n_live, tile, experts, interpret):
     """The weight gradient of :func:`_gmm_pallas`: (E, K, N) from x (M, K)
-    and dy (M, N)."""
+    and dy (M, N), over the live tiles."""
     M, K = x.shape
     N = dy.shape[1]
     tn = _col_tile(N, K, 4)
+    _count_build('tgmm', M // tile, tile)
     return pl.pallas_call(
         _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(N // tn, M // tile),
-            in_specs=[pl.BlockSpec((tile, K), lambda n, i, te: (i, 0)),
-                      pl.BlockSpec((tile, tn), lambda n, i, te: (i, n))],
+            num_scalar_prefetch=2, grid=(N // tn, M // tile),
+            in_specs=[pl.BlockSpec(
+                (tile, K), lambda n, i, te, nl: (_live_tile(i, nl), 0)),
+                pl.BlockSpec(
+                (tile, tn), lambda n, i, te, nl: (_live_tile(i, nl), n))],
             out_specs=pl.BlockSpec((1, K, tn),
-                                   lambda n, i, te: (te[i], 0, n)),
+                                   lambda n, i, te, nl: (te[i], 0, n)),
             scratch_shapes=[pltpu.VMEM((K, tn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((experts, K, N), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary'),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name=_scopes.GROUPED_MATMUL,
-    )(tile_expert, x, dy)
+    )(tile_expert, n_live, x, dy)
 
 
 def _gmm_xla(x, w, tile_expert, tile, transposed):
@@ -345,27 +402,32 @@ def _kernel_mode(interpret):
     return bool(interpret)
 
 
-def grouped_matmul(x, w, tile_expert, tile, transposed=False, interpret=None):
+def grouped_matmul(x, w, tile_expert, n_live, tile, transposed=False,
+                   interpret=None):
     """out[i-th row tile] = x[i-th row tile] @ w[tile_expert[i]], the
     weights (E, K, N), or @ w[...]^T with them (E, N, K) ``transposed``.
-    ``tile_expert`` is non-decreasing. ``interpret``: None compiles the
-    kernel on a TPU and takes einsums elsewhere; True runs the kernel
-    through the Pallas interpreter. No gradient of its own: the layer's
-    backward (:func:`_experts_bwd`) is written out."""
+    ``tile_expert`` is non-decreasing; the kernel computes the first
+    ``n_live`` (1,) row tiles and leaves the others' rows undefined, the
+    einsums compute them all. ``interpret``: None compiles the kernel on a
+    TPU and takes einsums elsewhere; True runs the kernel through the
+    Pallas interpreter. No gradient of its own: the layer's backward
+    (:func:`_experts_bwd`) is written out."""
     mode = _kernel_mode(interpret)
     if mode is None:
         return _gmm_xla(x, w, tile_expert, tile, transposed)
-    return _gmm_pallas(x, w, tile_expert, tile, transposed, mode)
+    return _gmm_pallas(x, w, tile_expert, n_live, tile, transposed, mode)
 
 
-def grouped_matmul_dw(x, dy, tile_expert, tile, experts, interpret=None):
+def grouped_matmul_dw(x, dy, tile_expert, n_live, tile, experts,
+                      interpret=None):
     """The weight gradient of ``grouped_matmul(x, w)`` for the cotangent
     ``dy``: (experts, K, N), expert e's the sum of x^T @ dy over its
-    tiles. ``tile_expert`` names every expert at least once."""
+    tiles, of the first ``n_live`` in the kernel. ``tile_expert`` names
+    every expert at least once, within the first ``n_live`` tiles."""
     mode = _kernel_mode(interpret)
     if mode is None:
         return _tgmm_xla(x, dy, tile_expert, tile, experts)
-    return _tgmm_pallas(x, dy, tile_expert, tile, experts, mode)
+    return _tgmm_pallas(x, dy, tile_expert, n_live, tile, experts, mode)
 
 
 # ---------------------------------------------------------------------------
@@ -377,14 +439,14 @@ def _forward(x, weights, w_gate_up, w_down, slots, tile, interpret, gate):
     grouped matmul, the gate (``GATES``), down, and each token's k slots
     weighted and added back. x (T, h), weights (T, k) float32, ``slots``
     what :func:`_slots` gives."""
-    tile_expert, src, _live, pos = slots
+    tile_expert, n_live, src, _live, pos = slots
     with jax.named_scope(_scopes.MOE_ROUTE):
         xs = jnp.take(x, src, axis=0, mode='clip')
     with jax.named_scope(_scopes.MOE_EXPERTS):
-        gate_up = grouped_matmul(xs, w_gate_up, tile_expert, tile, False,
-                                 interpret)
-        ys = grouped_matmul(_glu(gate_up, gate), w_down, tile_expert, tile,
-                            False, interpret)
+        gate_up = grouped_matmul(xs, w_gate_up, tile_expert, n_live, tile,
+                                 False, interpret)
+        ys = grouped_matmul(_glu(gate_up, gate), w_down, tile_expert, n_live,
+                            tile, False, interpret)
     with jax.named_scope(_scopes.MOE_ROUTE):
         return _gather_sum(ys, pos.reshape(weights.shape), weights), gate_up
 
@@ -409,7 +471,7 @@ def _experts_fwd(x, weights, w_gate_up, w_down, slots, tile, interpret,
 
 def _experts_bwd(tile, interpret, gate_kind, res, dy):
     x, weights, w_gate_up, w_down, slots, gate_up = res
-    tile_expert, src, live, pos = slots
+    tile_expert, n_live, src, live, pos = slots
     pos = pos.reshape(weights.shape)
     held = w_down.shape[0]
     f32 = jnp.float32
@@ -425,22 +487,22 @@ def _experts_bwd(tile, interpret, gate_kind, res, dy):
         hidden = _act(gate, gate_kind) * up
         # y = sum_c w_c * (hidden @ down): the weight scales a row, so it
         # is put on the narrow side of both products
-        d_hidden = grouped_matmul(dy_slots, w_down, tile_expert, tile, True,
-                                  interpret).astype(f32)
+        d_hidden = grouped_matmul(dy_slots, w_down, tile_expert, n_live, tile,
+                                  True, interpret).astype(f32)
         d_slot_w = jnp.sum(d_hidden * hidden, axis=-1)
         d_down = grouped_matmul_dw(
-            (hidden * slot_w).astype(x.dtype), dy_slots, tile_expert, tile,
-            held, interpret)
+            (hidden * slot_w).astype(x.dtype), dy_slots, tile_expert, n_live,
+            tile, held, interpret)
         d_hidden = d_hidden * slot_w
         d_gate = jnp.where(gate > 0, d_hidden * up, 0.0) \
             if gate_kind == 'reglu' else d_hidden * up * _silu_grad(gate)
         d_gate_up = jnp.concatenate(
             [d_gate, d_hidden * _act(gate, gate_kind)],
             axis=-1).astype(x.dtype)
-        d_xs = grouped_matmul(d_gate_up, w_gate_up, tile_expert, tile, True,
-                              interpret)
-        d_gate_up_w = grouped_matmul_dw(xs, d_gate_up, tile_expert, tile,
-                                        held, interpret)
+        d_xs = grouped_matmul(d_gate_up, w_gate_up, tile_expert, n_live, tile,
+                              True, interpret)
+        d_gate_up_w = grouped_matmul_dw(xs, d_gate_up, tile_expert, n_live,
+                                        tile, held, interpret)
     with jax.named_scope(_scopes.MOE_ROUTE):
         d_weights = jnp.where(pos < d_slot_w.shape[0],
                               jnp.take(d_slot_w, pos, mode='clip'), 0.0)

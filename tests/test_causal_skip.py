@@ -403,26 +403,28 @@ def test_mosaic_compiles_the_grouped_matmuls_for_a_described_v5e(one_chip):
     """ops/moe.py's kernel at smallthinker_21b.t8192's shapes: 208 row
     tiles of 256 against 16 experts' (2560, 1536) and (768, 2560) bf16
     weights, the forward, its transposed-weights twin and the weight
-    gradient with its float32 accumulator."""
+    gradient with its float32 accumulator, each bounded by the live tile
+    count, a second scalar-prefetched operand."""
     from mxnet_tpu.ops import moe
     rows, tile, tiles = moe.plan(8192, 16, 6)
 
     def shaped(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def products(x, w_gate_up, w_down, tile_expert):
-        gate_up = moe.grouped_matmul(x, w_gate_up, tile_expert, tile, False,
-                                     False)
+    def products(x, w_gate_up, w_down, tile_expert, n_live):
+        gate_up = moe.grouped_matmul(x, w_gate_up, tile_expert, n_live, tile,
+                                     False, False)
         y = moe.grouped_matmul(moe._glu(gate_up, 'reglu'), w_down,
-                               tile_expert, tile, False, False)
-        dx = moe.grouped_matmul(gate_up, w_gate_up, tile_expert, tile, True,
-                                False)
-        dw = moe.grouped_matmul_dw(x, gate_up, tile_expert, tile, 16, False)
+                               tile_expert, n_live, tile, False, False)
+        dx = moe.grouped_matmul(gate_up, w_gate_up, tile_expert, n_live,
+                                tile, True, False)
+        dw = moe.grouped_matmul_dw(x, gate_up, tile_expert, n_live, tile, 16,
+                                   False)
         return y, dx, dw
     text = jax.jit(products).lower(
         shaped(tile * tiles, 2560), shaped(16, 2560, 1536),
-        shaped(16, 768, 2560), shaped(tiles, dtype=jnp.int32)
-    ).compile().as_text()
+        shaped(16, 768, 2560), shaped(tiles, dtype=jnp.int32),
+        shaped(1, dtype=jnp.int32)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert 'mxtpu_grouped_matmul' in text
 

@@ -319,6 +319,10 @@ def test_the_tiny_model_is_the_one_pr_34_built():
     text = jax.jit(forward_loss).lower(
         [program.payload(params[n].data()) for n in order], tokens,
         labels).as_text()
-    assert len(text.splitlines()) == 1883
+    # the expert layer's part of the text moved once since, when its
+    # grouped matmuls came to stop at the live tiles: the combine's miss
+    # became a select and the layout carries the live tile count; the
+    # rest of the text and the loss above are as they were
+    assert len(text.splitlines()) == 1928
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        '04962777562bacfa8d5ece509490184cb4ae8eec6efedb23f1a8edcabb779628')
+        '6a119521807704c21ba727ba6ab47aeeae285b7a32fdd47b307bda8650b7dae5')

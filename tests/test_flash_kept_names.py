@@ -69,9 +69,12 @@ STEPS = {
     'gpt2': ('gpt2_small.t1024', {}, (
         13414,
         '50ca8ae4cbca1db50457564a004f7edfda600ef585373f22707c8dc720118c94')),
+    # the expert layer's lines moved when its grouped matmuls came to
+    # stop at the live tiles (the combine's miss a select, the live tile
+    # count); every other line is as read on 988a3f5
     'smallthinker': ('smallthinker_21b.t8192', {'head_dim': 128}, (
-        23706,
-        '48ae311989f4c63dfe9cc0302887803ab1c68734dd284e26db2bcebcd0997515')),
+        23746,
+        'a20cea75458417cac8a6d5e7426d28fa6217e2e266fa8a2dd3b079e4602cab9b')),
 }
 
 

@@ -5,9 +5,11 @@ the held experts in float32, forward and gradients, the einsum path and
 the Pallas kernels through the interpreter; every assignment sent to the
 held experts, and every token to one of them (nothing is dropped); none
 routed here; the share test of the model-configs guide (the four shares'
-parts add up to the uncut layer); the layout's promises; and that the
-device work does not depend on the routing (equal jaxprs and grids for two
-routings, and no control flow that reads the routing).
+parts add up to the uncut layer); the layout's promises; that the program
+does not depend on the routing (equal jaxprs and grids for two routings,
+and no control flow that reads the routing); and that the kernels stop at
+the live tiles: with every row of the tiles past them NaN before the
+kernels run, the layer and its gradients are finite and still right.
 """
 import jax
 import jax.numpy as jnp
@@ -50,6 +52,47 @@ def _layer(x, w_router, w_gate_up, w_down, first=0, bias=0.0, interpret=None):
                             first_expert=first, top_k=K, interpret=interpret)
 
 
+def _nan_past_live(a, n_live, tile):
+    """``a`` with every row of the tiles at or past ``n_live`` NaN."""
+    dead = jnp.arange(a.shape[0]) >= n_live[0] * tile
+    return jnp.where(dead[:, None], jnp.nan, a)
+
+
+def poison_dead_rows(monkeypatch):
+    """The kernels with the row operands' dead tiles NaN before they run.
+    The interpreter leaves an output block no grid step computes NaN too,
+    so a dead row that reached a result would show there."""
+    gmm, tgmm = moe._gmm_pallas, moe._tgmm_pallas
+
+    def poisoned_gmm(x, w, tile_expert, n_live, tile, *rest):
+        return gmm(_nan_past_live(x, n_live, tile), w, tile_expert, n_live,
+                   tile, *rest)
+
+    def poisoned_tgmm(x, dy, tile_expert, n_live, tile, *rest):
+        return tgmm(_nan_past_live(x, n_live, tile),
+                    _nan_past_live(dy, n_live, tile), tile_expert, n_live,
+                    tile, *rest)
+    monkeypatch.setattr(moe, '_gmm_pallas', poisoned_gmm)
+    monkeypatch.setattr(moe, '_tgmm_pallas', poisoned_tgmm)
+
+
+def interpret_for(mode, monkeypatch):
+    """The layer's ``interpret`` for a test's mode: ``einsum``, ``kernel``
+    (the interpreter) or ``poisoned`` (the interpreter, dead rows NaN)."""
+    if mode == 'poisoned':
+        poison_dead_rows(monkeypatch)
+    return None if mode == 'einsum' else True
+
+
+def live_tiles(ids, first, held, tile):
+    """The tiles the grouped matmuls compute for a routing."""
+    return moe.tiles_computed(moe._sorted_assignments(ids, first, held)[3],
+                              tile)
+
+
+MODES = ['einsum', 'kernel', 'poisoned']
+
+
 def _rows_here(x, w_router, first, bias, held=HELD):
     ids, _ = moe.route((x @ w_router + bias).reshape(-1, E), K)
     return int(jnp.sum((ids >= first) & (ids < first + held)))
@@ -60,25 +103,35 @@ ALL_ELSEWHERE = jnp.array([0.0] * 4 + [30.0] * 4)
 ALL_HERE = jnp.array([30.0] * 2 + [0.0] * 6)
 
 
-@pytest.mark.parametrize('interpret', [None, True], ids=['einsum', 'kernel'])
+@pytest.mark.parametrize('mode', MODES)
 @pytest.mark.parametrize('first,bias', [
     (0, 0.0), (2, 0.0), (0, ALL_TO_EXPERT_0), (0, ALL_HERE),
     (0, ALL_ELSEWHERE)],
     ids=['share0', 'share1', 'one_expert', 'all_here', 'none_here'])
-def test_layer_matches_the_dense_loop(first, bias, interpret):
+def test_layer_matches_the_dense_loop(first, bias, mode, monkeypatch):
     """Forward and the gradients of x, the router and both expert
     weights. ``all_here`` sends every one of the 256 assignments to the
-    two held experts, four times the balanced share; ``one_expert`` every
-    token to expert 0. The layout holds them all: nothing is dropped."""
+    two held experts, four times the balanced share, and the live tiles
+    are as many as they can be; ``one_expert`` every token to expert 0;
+    ``none_here`` leaves one live tile an expert. The layout holds them
+    all: nothing is dropped. ``poisoned``: no row of a dead tile reaches
+    a result, forward or backward."""
+    interpret = interpret_for(mode, monkeypatch)
     args = _weights()
-    assert moe.plan(N * T, HELD, K) == (256, 32, 10)
+    rows, tile, tiles = moe.plan(N * T, HELD, K)
+    assert (rows, tile, tiles) == (256, 32, 10)
     here = _rows_here(args[0], args[1], first, bias)
+    ids, _ = moe.route((args[0] @ args[1] + bias).reshape(-1, E), K)
+    n_live = live_tiles(ids, first, HELD, tile)
+    assert HELD <= n_live < tiles
     if bias is ALL_HERE:
         assert here == N * T * K
+        assert n_live >= rows // tile
     if bias is ALL_TO_EXPERT_0:
         assert here >= N * T
     if bias is ALL_ELSEWHERE:
         assert here == 0
+        assert n_live == HELD
 
     def loss(fn):
         def f(*a):
@@ -86,11 +139,13 @@ def test_layer_matches_the_dense_loop(first, bias, interpret):
         return f
     got = _layer(*args, first, bias, interpret)
     want = _dense(*args, first, bias)
+    assert onp.isfinite(got).all()
     onp.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     g_got = jax.grad(loss(lambda *a: _layer(*a, interpret=interpret)),
                      argnums=(0, 1, 2, 3))(*args)
     g_want = jax.grad(loss(_dense), argnums=(0, 1, 2, 3))(*args)
     for name, a, b in zip(('x', 'router', 'gate_up', 'down'), g_got, g_want):
+        assert onp.isfinite(a).all(), name
         onp.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
 
 
@@ -116,16 +171,23 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 def test_the_layout(sizes):
     """Experts in order on whole tiles, every expert at least one tile
     (its weight gradient is written), each expert's tiles enough for its
-    rows, whatever the sizes add up to within rows = tiles - held tiles."""
+    rows, whatever the sizes add up to within rows = tiles - held tiles;
+    and all of them among the first n_live tiles, the ones computed."""
     tile, tiles = 32, 10
-    tile_expert, pad_start = (onp.asarray(a) for a in moe._layout(
+    tile_expert, pad_start, n_live = (onp.asarray(a) for a in moe._layout(
         jnp.asarray(sizes, jnp.int32), tile, tiles))
     assert tile_expert.shape == (tiles,)
     assert (onp.diff(tile_expert) >= 0).all()
-    assert set(tile_expert) == {0, 1, 2, 3}
     assert pad_start.tolist() == [int(onp.argmax(tile_expert == e)) * tile
                                   for e in range(4)]
-    owned = onp.bincount(tile_expert, minlength=4) * tile
+    n_live = int(n_live[0])
+    assert n_live == moe.tiles_computed(sizes, tile)
+    assert n_live == sum(max(1, -(-s // tile)) for s in sizes)
+    assert 4 <= n_live <= tiles
+    assert (tile_expert[n_live - 1:] == 3).all()
+    live = tile_expert[:n_live]
+    assert set(live) == {0, 1, 2, 3}
+    owned = onp.bincount(live, minlength=4) * tile
     assert (owned >= onp.asarray(sizes)).all()
 
 
@@ -138,10 +200,12 @@ def test_every_assignment_to_a_held_expert_has_one_slot(bias):
     ids, _ = moe.route((x @ w_router + bias).reshape(-1, E), K)
     rows, tile, tiles = moe.plan(N * T, HELD, K)
     key, order, rank, sizes = moe._sorted_assignments(ids, 0, HELD)
-    tile_expert, src, live, pos = (onp.asarray(a) for a in moe._slots(
+    tile_expert, n_live, src, live, pos = (onp.asarray(a) for a in moe._slots(
         key, order, rank, sizes, K, tile, tiles))
     key = onp.asarray(key)
     hit = pos < tile * tiles
+    # no assignment lies in a tile the kernels skip
+    assert (pos[hit] < int(n_live[0]) * tile).all()
     assert (hit == (key < HELD)).all()
     assert len(set(pos[hit])) == hit.sum()
     assert (live[pos[hit]] == onp.flatnonzero(hit)).all()
@@ -174,11 +238,12 @@ def _kernel_calls(jaxpr, outside=None):
     return found
 
 
-def test_device_work_does_not_depend_on_the_routing():
+def test_the_program_does_not_depend_on_the_routing():
     """The program is traced from shapes alone and has no control flow:
     two routings run the same instructions -- the same jaxpr, the same
-    kernels on the same grids (rows / tile + held row tiles) -- so a
-    step's device time cannot follow how many assignments land here."""
+    kernels on the same grids (rows / tile + held row tiles). What
+    follows the routing is data: the live tile count, which bounds the
+    grid steps that do any work."""
     x, w_router, w_gate_up, w_down = _weights()
 
     def step(x, logits):
@@ -197,39 +262,66 @@ def test_device_work_does_not_depend_on_the_routing():
     assert not outside & {'cond', 'while', 'scan'}
 
 
+@pytest.mark.parametrize('n_live', [6, 4], ids=['full', 'two_dead'])
 @pytest.mark.parametrize('transposed', [False, True])
-def test_the_kernels_match_the_einsums(transposed):
+def test_the_kernels_match_the_einsums(transposed, n_live):
     """mxtpu_grouped_matmul through the interpreter against the einsum
-    path, and its weight gradient; bf16 operands, float32 accumulation."""
+    path, and its weight gradient; bf16 operands, float32 accumulation.
+    With tiles 4 and 5 dead, their rows NaN, the live rows are the
+    einsums', the dead ones are never written (the interpreter's NaN),
+    and the last expert's gradient is its one live tile's, written at
+    the live end."""
     rng = onp.random.default_rng(3)
     tile, experts = 16, 3
     tile_expert = jnp.asarray([0, 0, 1, 2, 2, 2], jnp.int32)
+    live = jnp.asarray([n_live], jnp.int32)
     M, K_, N_ = tile * 6, 128, 256
     x = jnp.asarray(rng.standard_normal((M, K_)), jnp.bfloat16)
     w = jnp.asarray(rng.standard_normal(
         (experts, N_, K_) if transposed else (experts, K_, N_)), jnp.bfloat16)
-    got = moe.grouped_matmul(x, w, tile_expert, tile, transposed, True)
-    want = moe.grouped_matmul(x, w, tile_expert, tile, transposed, None)
-    onp.testing.assert_array_equal(onp.asarray(got, onp.float32),
-                                   onp.asarray(want, onp.float32))
+    got = moe.grouped_matmul(_nan_past_live(x, live, tile), w, tile_expert,
+                             live, tile, transposed, True)
+    want = moe.grouped_matmul(x, w, tile_expert, live, tile, transposed, None)
+    rows = n_live * tile
+    onp.testing.assert_array_equal(onp.asarray(got[:rows], onp.float32),
+                                   onp.asarray(want[:rows], onp.float32))
+    assert onp.isnan(onp.asarray(got[rows:], onp.float32)).all()
     dy = jnp.asarray(rng.standard_normal((M, N_)), jnp.bfloat16)
-    onp.testing.assert_allclose(
-        onp.asarray(moe.grouped_matmul_dw(x, dy, tile_expert, tile, experts,
-                                          True), onp.float32),
-        onp.asarray(moe.grouped_matmul_dw(x, dy, tile_expert, tile, experts,
-                                          None), onp.float32),
-        rtol=1e-2, atol=1e-2)
+    got = moe.grouped_matmul_dw(_nan_past_live(x, live, tile),
+                                _nan_past_live(dy, live, tile), tile_expert,
+                                live, tile, experts, True)
+    # the einsums over the live rows alone
+    keep = (jnp.arange(M) < rows)[:, None]
+    want = moe.grouped_matmul_dw(jnp.where(keep, x, 0), dy, tile_expert,
+                                 live, tile, experts, None)
+    onp.testing.assert_allclose(onp.asarray(got, onp.float32),
+                                onp.asarray(want, onp.float32),
+                                rtol=1e-2, atol=1e-2)
+    last = onp.asarray(x[3 * tile:4 * tile], onp.float32).T \
+        @ onp.asarray(dy[3 * tile:4 * tile], onp.float32)
+    if n_live == 4:
+        onp.testing.assert_allclose(onp.asarray(got[2], onp.float32), last,
+                                    rtol=2e-2, atol=2e-1)
 
 
 def test_builds_and_routes_are_counted():
+    """A layer and its gradient through the kernels build the grouped
+    matmul four times and its weight gradient twice, each bounded by the
+    live tiles; the einsums build none."""
     before, routes = dict(moe.builds), dict(moe.route_counts)
+    live_before = dict(moe.live_tile_builds)
     x, w_router, w_gate_up, w_down = _weights()
     _layer(x, w_router, w_gate_up, w_down)
-    _layer(x, w_router, w_gate_up, w_down, interpret=True)
+    jax.grad(lambda x: jnp.sum(_layer(x, w_router, w_gate_up, w_down,
+                                      interpret=True)))(x)
     key = (E, HELD, K, 256, 32)
     assert moe.builds[key] - before.get(key, 0) == 2
     assert moe.route_counts['xla'] == routes['xla'] + 1
     assert moe.route_counts['pallas'] == routes['pallas'] + 1
+    added = {k: v - live_before.get(k, 0)
+             for k, v in moe.live_tile_builds.items()
+             if v != live_before.get(k, 0)}
+    assert added == {('gmm', 10, 32): 4, ('tgmm', 10, 32): 2}
 
 
 def test_the_cells_plan():

@@ -2,7 +2,9 @@
 balancing bias that enters the choice alone, and SiLU between the grouped
 matmuls. The routing against a plain top-k; the layer against a dense
 loop of einsums, forward and gradients, through the einsums and through
-the Pallas kernels in the interpreter; the share test of the
+the Pallas kernels in the interpreter, with the rows of the tiles they
+skip NaN too, at three routings besides a mixed one (every assignment
+here, none here, one expert taking every token); the share test of the
 model-configs guide at LFM2's router (64 experts, 4 a token, eight shares
 of 8); the assignment counts and the bias's update rule; and that the
 new rule and gate are counted apart from softmax and ReGLU."""
@@ -12,6 +14,7 @@ import numpy as onp
 import pytest
 
 from mxnet_tpu.ops import moe
+from test_moe import MODES, interpret_for, live_tiles
 
 N, T, H, F = 2, 32, 32, 16
 
@@ -103,19 +106,47 @@ def _layer(x, w_router, w_gate_up, w_down, first, k, bias, interpret=None):
                             gate='silu', bias=bias)
 
 
-@pytest.mark.parametrize('interpret', [None, True], ids=['einsum', 'kernel'])
-@pytest.mark.parametrize('first', [0, 4])
-def test_the_silu_layer_matches_the_einsums(first, interpret):
+def _bias(routing, first):
+    """A balancing bias that routes as named: ``mixed`` a small random
+    one; ``all_here`` every token to the four held experts; ``none_here``
+    every token elsewhere; ``one_expert`` every token to the first held."""
+    if routing == 'mixed':
+        return onp.random.default_rng(5).standard_normal(16) * 0.1
+    bias = onp.zeros(16)
+    bias[{'all_here': slice(first, first + 4),
+          'none_here': slice(8, 16) if first < 8 else slice(0, 8),
+          'one_expert': slice(first, first + 1)}[routing]] = 30.0
+    return bias
+
+
+@pytest.mark.parametrize('mode', MODES)
+@pytest.mark.parametrize('first,routing', [
+    (0, 'mixed'), (4, 'mixed'), (0, 'all_here'), (0, 'none_here'),
+    (4, 'one_expert')])
+def test_the_silu_layer_matches_the_einsums(first, routing, mode,
+                                           monkeypatch):
     """Forward and the gradients of x, the router and both expert
     weights, 16 experts of which 4 are held, 2 a token, a nonzero
-    bias."""
+    bias; finite with the kernels' dead rows NaN (``poisoned``)."""
+    interpret = interpret_for(mode, monkeypatch)
     x, w_r, w_gu, w_d = _weights(16, 4)
-    bias = jnp.asarray(onp.random.default_rng(5).standard_normal(16) * 0.1,
-                       jnp.float32)
+    bias = jnp.asarray(_bias(routing, first), jnp.float32)
     args = (x, w_r, w_gu, w_d)
-    onp.testing.assert_allclose(
-        _layer(*args, first, 2, bias, interpret),
-        _dense(*args, first, 2, bias), rtol=1e-5, atol=1e-5)
+    rows, tile, tiles = moe.plan(N * T, 4, 2)
+    ids, _ = moe.route(x.reshape(-1, H) @ w_r, 2, 'sigmoid', bias)
+    here = int(jnp.sum((ids >= first) & (ids < first + 4)))
+    n_live = live_tiles(ids, first, 4, tile)
+    assert 4 <= n_live < tiles
+    if routing == 'all_here':
+        assert here == rows and n_live >= rows // tile
+    if routing == 'none_here':
+        assert here == 0 and n_live == 4
+    if routing == 'one_expert':
+        assert int(jnp.sum(ids == first)) == N * T
+    got = _layer(*args, first, 2, bias, interpret)
+    assert onp.isfinite(got).all()
+    onp.testing.assert_allclose(got, _dense(*args, first, 2, bias),
+                                rtol=1e-5, atol=1e-5)
 
     def loss(fn, **kw):
         return lambda *a: jnp.sum(jnp.sin(fn(*a, first, 2, bias, **kw)))
@@ -123,6 +154,7 @@ def test_the_silu_layer_matches_the_einsums(first, interpret):
                    argnums=(0, 1, 2, 3))(*args)
     want = jax.grad(loss(_dense), argnums=(0, 1, 2, 3))(*args)
     for name, a, b in zip(('x', 'router', 'gate_up', 'down'), got, want):
+        assert onp.isfinite(a).all(), name
         onp.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
 
 
