@@ -48,7 +48,10 @@ tile a grid step against the weights of the tile's expert, read from a
 scalar-prefetched table; the two backward products are the same kernel on
 the transposed weights and a second one that adds a group's tiles into
 its weight gradient. Off the TPU the same products are plain einsums over
-the tiles.
+the tiles. An expert weight whose minor dimension is no multiple of 128
+lanes, and whose other matrix dimension is, is read through its swap of
+those two (:func:`_swapped`): the device stores such an array with the
+other dimension in the lanes, and the swap is a bitcast of that layout.
 """
 from __future__ import annotations
 
@@ -87,10 +90,13 @@ GATES = ('reglu', 'silu', 'relu2')
 # softmax and ReGLU, and the routed scaling factor where it is not 1;
 # and {(kernel kind, tiles a layer, tile): builds} of the grouped-matmul
 # kernels bounded by the live tile count, the kind
-# ``gmm`` (forward, dx, d_hidden) or ``tgmm`` (a weight gradient)
+# ``gmm`` (forward, dx, d_hidden) or ``tgmm`` (a weight gradient); and
+# {(weight, read): layers built}, the weight ``up`` (gate|up, or up alone)
+# or ``down``, read ``swapped`` or ``stored`` (:func:`_swapped`)
 route_counts = {'pallas': 0, 'xla': 0}
 builds = {}
 live_tile_builds = {}
+weight_reads = {}
 
 
 def plan(tokens, held, top_k):
@@ -363,12 +369,15 @@ def _tgmm_kernel(te_ref, nl_ref, x_ref, dy_ref, o_ref, acc_ref):
             o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _tgmm_pallas(x, dy, tile_expert, n_live, tile, experts, interpret):
+def _tgmm_pallas(x, dy, tile_expert, n_live, tile, experts, interpret,
+                 tn=None):
     """The weight gradient of :func:`_gmm_pallas`: (E, K, N) from x (M, K)
-    and dy (M, N), over the live tiles."""
+    and dy (M, N), over the live tiles, in column blocks of ``tn``
+    (default :func:`_col_tile`'s). Every column block sweeps the row
+    tiles again, the dead ones too."""
     M, K = x.shape
     N = dy.shape[1]
-    tn = _col_tile(N, K, 4)
+    tn = tn or _col_tile(N, K, 4)
     _count_build('tgmm', M // tile, tile)
     return pl.pallas_call(
         _tgmm_kernel,
@@ -429,15 +438,52 @@ def grouped_matmul(x, w, tile_expert, n_live, tile, transposed=False,
 
 
 def grouped_matmul_dw(x, dy, tile_expert, n_live, tile, experts,
-                      interpret=None):
+                      interpret=None, col_tile=None):
     """The weight gradient of ``grouped_matmul(x, w)`` for the cotangent
     ``dy``: (experts, K, N), expert e's the sum of x^T @ dy over its
     tiles, of the first ``n_live`` in the kernel. ``tile_expert`` names
-    every expert at least once, within the first ``n_live`` tiles."""
+    every expert at least once, within the first ``n_live`` tiles.
+    ``col_tile``: the kernel's column block, if not its default."""
     mode = _kernel_mode(interpret)
     if mode is None:
         return _tgmm_xla(x, dy, tile_expert, tile, experts)
-    return _tgmm_pallas(x, dy, tile_expert, n_live, tile, experts, mode)
+    return _tgmm_pallas(x, dy, tile_expert, n_live, tile, experts, mode,
+                        col_tile)
+
+
+def _swapped(w):
+    """Whether the products read an expert weight (held, K, N) as its
+    swap (held, N, K): where N, the minor dimension, is no multiple of 128
+    lanes and K is. The device then lays the array out with K in the lanes
+    (Nemotron-H's (8, 2688, 1856) up weights: ``{1,2,0}``), and the swap is
+    a bitcast of that layout. Read as stored, the kernels take it
+    row-major, and XLA lays the weight, its float32 master and both AdamW
+    moments out again round every update and back after it."""
+    return w.shape[2] % 128 != 0 and w.shape[1] % 128 == 0
+
+
+def _product(x, w, tile_expert, n_live, tile, transposed, interpret):
+    """:func:`grouped_matmul` of an expert weight (held, K, N) as the
+    layer holds it, read through its swap where :func:`_swapped` says."""
+    if _swapped(w):
+        return grouped_matmul(x, jnp.swapaxes(w, 1, 2), tile_expert, n_live,
+                              tile, not transposed, interpret)
+    return grouped_matmul(x, w, tile_expert, n_live, tile, transposed,
+                          interpret)
+
+
+def _product_dw(x, dy, w, tile_expert, n_live, tile, interpret):
+    """The gradient of w in ``_product(x, w)`` (not transposed) for the
+    cotangent ``dy``, in w's shape: computed as its swap's where w is read
+    swapped, so that it leaves in the layout w has. The swap keeps the
+    stored read's one column block (N, unaligned, has no lane-aligned
+    divisor), and so its grid of one sweep over the row tiles."""
+    if _swapped(w):
+        return jnp.swapaxes(grouped_matmul_dw(
+            dy, x, tile_expert, n_live, tile, w.shape[0], interpret,
+            col_tile=w.shape[1]), 1, 2)
+    return grouped_matmul_dw(x, dy, tile_expert, n_live, tile, w.shape[0],
+                             interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -453,10 +499,10 @@ def _forward(x, weights, w_gate_up, w_down, slots, tile, interpret, gate):
     with jax.named_scope(_scopes.MOE_ROUTE):
         xs = jnp.take(x, src, axis=0, mode='clip')
     with jax.named_scope(_scopes.MOE_EXPERTS):
-        gate_up = grouped_matmul(xs, w_gate_up, tile_expert, n_live, tile,
-                                 False, interpret)
-        ys = grouped_matmul(_glu(gate_up, gate), w_down, tile_expert, n_live,
-                            tile, False, interpret)
+        gate_up = _product(xs, w_gate_up, tile_expert, n_live, tile, False,
+                           interpret)
+        ys = _product(_glu(gate_up, gate), w_down, tile_expert, n_live, tile,
+                      False, interpret)
     with jax.named_scope(_scopes.MOE_ROUTE):
         return _gather_sum(ys, pos.reshape(weights.shape), weights), gate_up
 
@@ -483,7 +529,6 @@ def _experts_bwd(tile, interpret, gate_kind, res, dy):
     x, weights, w_gate_up, w_down, slots, gate_up = res
     tile_expert, n_live, src, live, pos = slots
     pos = pos.reshape(weights.shape)
-    held = w_down.shape[0]
     f32 = jnp.float32
     with jax.named_scope(_scopes.MOE_ROUTE):
         # a slot's weight is its assignment's; a slot nobody owns has none
@@ -501,12 +546,11 @@ def _experts_bwd(tile, interpret, gate_kind, res, dy):
             hidden = _act(gate, gate_kind) * up
         # y = sum_c w_c * (hidden @ down): the weight scales a row, so it
         # is put on the narrow side of both products
-        d_hidden = grouped_matmul(dy_slots, w_down, tile_expert, n_live, tile,
-                                  True, interpret).astype(f32)
+        d_hidden = _product(dy_slots, w_down, tile_expert, n_live, tile, True,
+                            interpret).astype(f32)
         d_slot_w = jnp.sum(d_hidden * hidden, axis=-1)
-        d_down = grouped_matmul_dw(
-            (hidden * slot_w).astype(x.dtype), dy_slots, tile_expert, n_live,
-            tile, held, interpret)
+        d_down = _product_dw((hidden * slot_w).astype(x.dtype), dy_slots,
+                             w_down, tile_expert, n_live, tile, interpret)
         d_hidden = d_hidden * slot_w
         if gate_kind == 'relu2':
             d_gate_up = (2 * relu * d_hidden).astype(x.dtype)
@@ -516,10 +560,10 @@ def _experts_bwd(tile, interpret, gate_kind, res, dy):
             d_gate_up = jnp.concatenate(
                 [d_gate, d_hidden * _act(gate, gate_kind)],
                 axis=-1).astype(x.dtype)
-        d_xs = grouped_matmul(d_gate_up, w_gate_up, tile_expert, n_live, tile,
-                              True, interpret)
-        d_gate_up_w = grouped_matmul_dw(xs, d_gate_up, tile_expert, n_live,
-                                        tile, held, interpret)
+        d_xs = _product(d_gate_up, w_gate_up, tile_expert, n_live, tile, True,
+                        interpret)
+        d_gate_up_w = _product_dw(xs, d_gate_up, w_gate_up, tile_expert,
+                                  n_live, tile, interpret)
     with jax.named_scope(_scopes.MOE_ROUTE):
         d_weights = jnp.where(pos < d_slot_w.shape[0],
                               jnp.take(d_slot_w, pos, mode='clip'), 0.0)
@@ -563,6 +607,9 @@ def expert_layer(x, router_logits, w_gate_up, w_down, first_expert=0,
     if scale != 1:
         key_ += (scale,)
     builds[key_] = builds.get(key_, 0) + 1
+    for which, w in (('up', w_gate_up), ('down', w_down)):
+        read = (which, 'swapped' if _swapped(w) else 'stored')
+        weight_reads[read] = weight_reads.get(read, 0) + 1
     route_counts['xla' if _kernel_mode(interpret) is None else 'pallas'] += 1
     with jax.named_scope(_scopes.MOE_ROUTE):
         ids, weights = route(router_logits.reshape(tokens, experts), top_k,

@@ -429,6 +429,80 @@ def test_mosaic_compiles_the_grouped_matmuls_for_a_described_v5e(one_chip):
     assert 'mxtpu_grouped_matmul' in text
 
 
+# the expert layers of the three expert cells, at their published widths
+EXPERT_LAYERS = {
+    'nemotron3_nano_30b_a3b': dict(
+        hidden=2688, width=1856, experts=128, top_k=6, held=8,
+        scoring='sigmoid', gate='relu2', bias_rate=1e-3, scale=2.5,
+        shared=3712),
+    'smallthinker_21b': dict(hidden=2560, width=768, experts=64, top_k=6,
+                             held=16),
+    'lfm2_24b_a2b': dict(hidden=2048, width=1536, experts=64, top_k=4,
+                         held=8, scoring='sigmoid', gate='silu',
+                         bias_rate=1e-3),
+}
+
+
+@pytest.mark.parametrize('cell', list(EXPERT_LAYERS))
+def test_an_expert_layers_train_step_copies_no_expert_weight(four_chips,
+                                                             cell,
+                                                             monkeypatch):
+    """One ``SparseExperts`` layer's AdamW ``ShardedTrainStep`` with the
+    grouped-matmul kernels, compiled for one chip of a described v5e at a
+    cell's widths (256 positions, for time). No ``copy`` has either
+    orientation of an expert weight's shape: the parameter, its float32
+    master and both moments stay in the layout the device gives them.
+    Nemotron's up weight (8, 2688, 1856), whose minor dimension is no
+    multiple of 128, is read through its swap (``ops.moe.weight_reads``);
+    at the lane-aligned widths nothing is swapped, and no bitcast or
+    transpose of an expert weight is there."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon import HybridBlock
+    from mxnet_tpu.models.decoder import SparseExperts
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.parallel import ShardedTrainStep, make_mesh
+    monkeypatch.setattr(moe, 'pallas_available', lambda: True)
+    kw = EXPERT_LAYERS[cell]
+
+    class Layer(HybridBlock):
+        def __init__(self):
+            super().__init__(prefix='layer_')
+            with self.name_scope():
+                self.experts = SparseExperts(prefix='experts_', **kw)
+
+        def forward(self, x):
+            return self.experts(x, self.experts.route(x))
+    mx.random.seed(5)
+    net = Layer()
+    net.initialize(mx.init.Normal(0.02))
+    net.cast('bfloat16')
+    step = ShardedTrainStep(
+        net, lambda out, label: (out.astype('float32') - label) ** 2,
+        'adamw', {'learning_rate': 1e-5},
+        mesh=make_mesh((1,), ('dp',), devices=four_chips[:1]))
+    h, f, held = kw['hidden'], kw['width'], kw['held']
+    up = (held, h, f if kw.get('gate') == 'relu2' else 2 * f)
+    before = dict(moe.weight_reads)
+    text = step.lower(jax.ShapeDtypeStruct((1, 256, h), jnp.bfloat16),
+                      jax.ShapeDtypeStruct((1, 256, h), jnp.float32)
+                      ).compile().as_text()
+    reads = {k: v - before.get(k, 0) for k, v in moe.weight_reads.items()
+             if v != before.get(k, 0)}
+    swapped = cell == 'nemotron3_nano_30b_a3b'
+    assert reads == {('up', 'swapped' if swapped else 'stored'): 1,
+                     ('down', 'stored'): 1}
+    assert 'mxtpu_grouped_matmul' in text
+    shapes = '|'.join(','.join(map(str, s)) for s in (
+        up, (held, up[2], h), (held, f, h), (held, h, f)))
+
+    def ops(opcode):
+        return re.findall(r'= \w+\[(?:%s)\]\{[^}]*\} %s\(' % (shapes, opcode),
+                          text)
+    assert ops('copy') == []
+    if not swapped:
+        assert ops('bitcast') == [] and ops('transpose') == []
+
+
 def test_a_train_step_lowers_and_plans_for_a_described_mesh(four_chips):
     """``ShardedTrainStep.lower()`` over shapes, for a dp=4 mesh of
     devices that are described and not attached: how a cell's batch is

@@ -432,3 +432,75 @@ def test_the_squared_relu_gate_and_the_scale_are_counted_apart():
     _relu2_layer(*args, 0, jnp.zeros(E))
     key = (E, HELD, K, 256, 32, 'sigmoid', 'relu2', SCALE)
     assert moe.builds[key] - before.get(key, 0) == 1
+
+
+# ---------------------------------------------------------------------------
+# an expert weight whose minor dimension is no multiple of 128 lanes is read
+# through its swap (ops/moe.py:_swapped): the same products, the same
+# results
+# ---------------------------------------------------------------------------
+
+WIDE_H = 256
+
+
+def _wide_weights(gate, width):
+    """x, logits and the held experts' weights at hidden 256: the up (or
+    gate|up) weight (HELD, 256, 192), whose minor dimension is no multiple
+    of 128 and whose other one is, for ``width`` 192 (96 gated); the down
+    weight (HELD, width, 256). x is half as wide as the other tests' so
+    that, over sums of 256 and 192 terms, the gradients stay of order one
+    as theirs do, where the tolerances below were set."""
+    rng = onp.random.default_rng(11)
+    up = width * (1 if gate == 'relu2' else 2)
+    x = jnp.asarray(rng.standard_normal((N, T, WIDE_H)) * 0.5, jnp.float32)
+    w_router = jnp.asarray(rng.standard_normal((WIDE_H, E)) * 0.05,
+                           jnp.float32)
+    return (x, x @ w_router,
+            jnp.asarray(rng.standard_normal((HELD, WIDE_H, up)) * 0.05,
+                        jnp.float32),
+            jnp.asarray(rng.standard_normal((HELD, width, WIDE_H)) * 0.05,
+                        jnp.float32))
+
+
+def _reads_added(before):
+    return {k: v - before.get(k, 0) for k, v in moe.weight_reads.items()
+            if v != before.get(k, 0)}
+
+
+@pytest.mark.parametrize('mode', ['einsum', 'kernel'])
+@pytest.mark.parametrize('gate,width', [('relu2', 192), ('silu', 96)])
+def test_the_swapped_read_matches_the_stored_one(gate, width, mode,
+                                                 monkeypatch):
+    """An up weight (2, 256, 192) is read through its swap, the down weight
+    (2, width, 256) as stored: the output and the gradients of x, the
+    router's logits and both weights equal those of the layer that reads
+    both as stored (``_swapped`` patched false), through the einsums and
+    through the kernels in the interpreter. The counter says which read
+    each weight took; an aligned up weight (2, 256, 128) is read stored."""
+    interpret = interpret_for(mode, monkeypatch)
+    args = _wide_weights(gate, width)
+    kw = dict(top_k=K, interpret=interpret, gate=gate)
+    if gate == 'relu2':
+        kw.update(scoring='sigmoid', scale=SCALE)
+
+    def loss(*a):
+        return jnp.sum(jnp.sin(moe.expert_layer(*a, **kw)))
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3))
+    before = dict(moe.weight_reads)
+    got = moe.expert_layer(*args, **kw)
+    got_g = grads(*args)
+    assert _reads_added(before) == {('up', 'swapped'): 2, ('down', 'stored'): 2}
+    with monkeypatch.context() as m:
+        m.setattr(moe, '_swapped', lambda w: False)
+        want = moe.expert_layer(*args, **kw)
+        want_g = grads(*args)
+    assert onp.isfinite(got).all()
+    onp.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for name, a, b in zip(('x', 'logits', 'up', 'down'), got_g, want_g):
+        assert a.shape == b.shape, name
+        onp.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+    aligned = _wide_weights('relu2', 128)
+    before = dict(moe.weight_reads)
+    jax.eval_shape(lambda *a: moe.expert_layer(*a, top_k=K, gate='relu2'),
+                   *aligned)
+    assert _reads_added(before) == {('up', 'stored'): 1, ('down', 'stored'): 1}
